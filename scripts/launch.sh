@@ -28,7 +28,11 @@ export JAX_PROCESS_ID="${PROC_ID:-${JAX_PROCESS_ID:-0}}"
 #  - keep compilation cache on (first Mosaic compile is slow)
 #  - un-filtered tracebacks for actionable crash reports
 export JAX_TRACEBACK_FILTERING="${JAX_TRACEBACK_FILTERING:-off}"
-export JAX_COMPILATION_CACHE_DIR="${JAX_COMPILATION_CACHE_DIR:-$HOME/.cache/jax_comp}"
+if [[ -z "${JAX_COMPILATION_CACHE_DIR:-}" ]]; then
+  # Same rule as triton_dist_tpu/runtime/compile_cache.py: a fixed path
+  # beside the code unless the caller placed the cache.
+  export JAX_COMPILATION_CACHE_DIR="$(cd "$(dirname "$0")/.." && pwd)/.jax_cache"
+fi
 export TDT_AUTOTUNE_CACHE="${TDT_AUTOTUNE_CACHE:-1}"
 
 if [[ -n "${JAX_COORDINATOR_ADDRESS}" ]]; then

@@ -248,8 +248,7 @@ def test_aggressive_blocks_reach_kernel_unclamped(mesh8, key, monkeypatch):
     soft-budget clamp silently rewrote every swept aggressive config
     back to the budget kernel, so the tier benchmarked duplicates).
     Blocks beyond the hard cap must still be clamped to an in-budget
-    config (BENCH_r02: an uncompilable config never reaches the
-    compiler). Budgets are shrunk so 'aggressive' stays tiny in
+    config (an uncompilable config never reaches the compiler). Budgets are shrunk so 'aggressive' stays tiny in
     interpret mode."""
     import triton_dist_tpu.ops.allgather_gemm as agm
 
@@ -318,7 +317,7 @@ def test_aggressive_blocks_reach_kernel_unclamped(mesh8, key, monkeypatch):
 def test_gemm_ar_infeasible_config_degrades(mesh8, key):
     """When no resident-B-panel config fits the VMEM budget, GEMM-AR must
     degrade to the XLA path rather than fall through to the
-    full-residency vmem kernel (code-review r3: BENCH_r02-class crash)."""
+    full-residency vmem kernel, whose scratch Mosaic would refuse."""
     from triton_dist_tpu.ops.gemm_reduce_scatter import (
         create_gemm_rs_context, gemm_ar)
     m, k, n = 64, 128, 256

@@ -1,0 +1,181 @@
+"""The main-path kernels compile for the real chip — without one.
+
+libtpu is installed here and compiles for a chip that is described, not
+attached (``jax.experimental.topologies``). Interpret mode, which every
+other test runs in, enforces neither VMEM limits nor tile alignment, and
+an export (tests/test_mosaic_export.py) only lowers: Mosaic's own compile
+is where "Slice shape along dimension 0 must be aligned to tiling (8)"
+comes from. These cases run it on the kernels of the serving path at the
+widths chip_smoke.py serves: Qwen3-0.6B on one chip, and the Qwen3-8B
+tensor-parallel slice on the four chips of a v5e 2x2.
+
+A compile that passes is not a chip run: nothing executes here.
+
+The topology is described inside a module-scoped fixture — never at
+import, in a skipif, in parametrize arguments or in conftest — because
+only one process may load libtpu at a time and every xdist worker imports
+every test file. Compiles run in this process, not a child, and stay in
+this ONE file so one worker owns the library.
+"""
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+
+BF16 = jnp.bfloat16
+
+
+@pytest.fixture(scope="module")
+def topo():
+    from jax.experimental import topologies
+    try:
+        return topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:  # noqa: BLE001 — any failure to describe: skip
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+
+
+@pytest.fixture(scope="module")
+def meshes(topo):
+    """{1: one-chip mesh, 4: the 2x2 as a tp ring} on described devices."""
+    from jax.experimental import mesh_utils
+    ring = np.asarray(mesh_utils.create_device_mesh(
+        (1, 4), devices=list(topo.devices))).reshape(4)
+    return {1: Mesh(np.array(topo.devices[:1]), ("tp",)),
+            4: Mesh(ring, ("tp",))}
+
+
+@pytest.fixture(autouse=True)
+def _fused_or_fail(monkeypatch):
+    """No routing away from the kernel under test, and no persistent
+    compile cache (an entry compiled here cannot be read back without a
+    chip, and warns)."""
+    monkeypatch.setenv("TDT_FORCE_FUSED", "1")
+    prev = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    yield
+    jax.config.update("jax_enable_compilation_cache", prev)
+
+
+def _sds(mesh, shape, spec, dtype=BF16):
+    return jax.ShapeDtypeStruct(shape, dtype,
+                                sharding=NamedSharding(mesh, spec))
+
+
+def _compile(fn, *args) -> int:
+    """Compile ``fn`` for the described chip(s); returns how many Mosaic
+    kernels the program holds. Raises what the chip's compiler raises."""
+    text = jax.jit(fn).lower(*args).compile().as_text()
+    return text.count("tpu_custom_call")
+
+
+# (world, M, K, N): 0.6B decode o-proj / down-proj on one chip; the 8B
+# TP4 decode at batch 8 — 2 rows per rank, refused before the row split
+# was padded (ops.common.ring_padded_rows) — and at a tile-aligned batch.
+@pytest.mark.parametrize("world,m,k,n", [
+    (1, 8, 2048, 1024), (1, 8, 3072, 1024), (1, 1, 3072, 1024),
+    (4, 8, 4096, 4096), (4, 8, 12288, 4096), (4, 128, 12288, 4096)])
+def test_gemm_ar_decode(meshes, world, m, k, n):
+    from triton_dist_tpu.ops.gemm_reduce_scatter import (
+        create_gemm_rs_context, gemm_ar)
+    mesh = meshes[world]
+    ctx = create_gemm_rs_context(mesh, "tp", interpret=False)
+    assert _compile(lambda a, b: gemm_ar(a, b, ctx, impl="pallas"),
+                    _sds(mesh, (m, k), P(None, "tp")),
+                    _sds(mesh, (k, n), P("tp"))) == 1
+
+
+# Prefill front half: 0.6B on one chip; 8B TP4 at the smallest admission
+# bucket the engine produces for a 4-way row split (32) and a long one.
+@pytest.mark.parametrize("world,m,k,n", [
+    (1, 512, 1024, 3072), (4, 32, 4096, 12288), (4, 512, 4096, 12288)])
+def test_ag_gemm_and_swiglu_prefill(meshes, world, m, k, n):
+    from triton_dist_tpu.ops.allgather_gemm import (
+        ag_gemm, ag_swiglu, create_ag_gemm_context)
+    mesh = meshes[world]
+    ctx = create_ag_gemm_context(mesh, "tp", interpret=False)
+    a = _sds(mesh, (m, k), P("tp"))
+    w = _sds(mesh, (k, n), P(None, "tp"))
+    assert _compile(lambda a, b: ag_gemm(a, b, ctx, impl="pallas"),
+                    a, w) == 1
+    assert _compile(lambda a, g, u: ag_swiglu(a, g, u, ctx, impl="pallas"),
+                    a, w, w) == 1
+
+
+@pytest.mark.parametrize("world,m,k,n", [
+    (1, 512, 3072, 1024), (4, 32, 4096, 4096), (4, 512, 12288, 4096)])
+def test_gemm_rs_prefill(meshes, world, m, k, n):
+    from triton_dist_tpu.ops.gemm_reduce_scatter import (
+        create_gemm_rs_context, gemm_rs)
+    mesh = meshes[world]
+    ctx = create_gemm_rs_context(mesh, "tp", interpret=False)
+    assert _compile(lambda a, b: gemm_rs(a, b, ctx, impl="pallas"),
+                    _sds(mesh, (m, k), P(None, "tp")),
+                    _sds(mesh, (k, n), P("tp"))) == 1
+
+
+# Decode attention: the tiled kernel the paged engine runs on one chip,
+# and the KV split over 4 with the cross-rank combine — refused before
+# the (l, m) partials were laid out on a 128-lane dimension.
+@pytest.mark.parametrize("world,hq,t", [(1, 16, 4096), (4, 32, 8192)])
+def test_flash_decode_tiled(meshes, world, hq, t):
+    from triton_dist_tpu.ops.flash_decode import (
+        create_flash_decode_context, gqa_fwd_batch_decode)
+    mesh = meshes[world]
+    ctx = create_flash_decode_context(mesh, "tp", interpret=False,
+                                      variant="tiled")
+    kv = _sds(mesh, (8, t, 8, 128), P(None, "tp"))
+    assert _compile(
+        lambda q, k, v, n: gqa_fwd_batch_decode(q, k, v, n, ctx,
+                                                impl="pallas"),
+        _sds(mesh, (8, hq, 128), P()), kv, kv,
+        _sds(mesh, (8,), P(), jnp.int32)) == 1
+
+
+def test_flash_decode_paged_gathered(meshes):
+    """The paged engine's decode call at its real pool geometry: 16-token
+    pages, batch 8 x 4096 positions on one chip."""
+    from triton_dist_tpu.ops.flash_decode import (
+        create_flash_decode_context, gqa_fwd_batch_decode_paged)
+    mesh = meshes[1]
+    ctx = create_flash_decode_context(mesh, "tp", interpret=False)
+    pool = _sds(mesh, (2049, 16, 8, 128), P("tp"))
+    assert _compile(
+        lambda q, pk, pv, tb, n: gqa_fwd_batch_decode_paged(
+            q, pk, pv, tb, n, ctx, impl="pallas"),
+        _sds(mesh, (8, 16, 128), P()), pool, pool,
+        _sds(mesh, (1, 8, 256), P("tp"), jnp.int32),
+        _sds(mesh, (8,), P(), jnp.int32)) == 1
+
+
+# World-4 collectives. all_reduce at M=8 is the decode batch: two-shot's
+# row split of 2 rows per rank was refused before it was padded.
+@pytest.mark.parametrize("method,m", [("one_shot", 8), ("two_shot", 8),
+                                      ("two_shot", 256)])
+def test_all_reduce_world4(meshes, method, m):
+    from triton_dist_tpu.ops.allreduce import (
+        AllReduceMethod, all_reduce, create_allreduce_context)
+    mesh = meshes[4]
+    ctx = create_allreduce_context(mesh, "tp", interpret=False,
+                                   method=AllReduceMethod(method))
+    assert _compile(lambda x: all_reduce(x, ctx, impl="pallas"),
+                    _sds(mesh, (4, m, 4096), P("tp"))) == 1
+
+
+def test_all_gather_and_reduce_scatter_ring_world4(meshes):
+    from triton_dist_tpu.ops.allgather import (
+        AllGatherMethod, all_gather, create_allgather_context)
+    from triton_dist_tpu.ops.reduce_scatter import (
+        create_reduce_scatter_context, reduce_scatter)
+    mesh = meshes[4]
+    for method in (AllGatherMethod.RING_1D, AllGatherMethod.RING_BIDIR,
+                   AllGatherMethod.FULL_MESH_PUSH):
+        ag = create_allgather_context(mesh, "tp", method=method,
+                                      interpret=False)
+        assert _compile(lambda x, ag=ag: all_gather(x, ag, impl="pallas"),
+                        _sds(mesh, (1024, 4096), P("tp"))) == 1
+    rs = create_reduce_scatter_context(mesh, "tp", interpret=False)
+    assert _compile(lambda x: reduce_scatter(x, rs, impl="pallas"),
+                    _sds(mesh, (4, 1024, 4096), P("tp"))) == 1

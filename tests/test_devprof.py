@@ -149,10 +149,8 @@ def test_unparseable_inputs_raise():
 def _capture_eager_op(tmp_path, mesh8):
     """One eager ag_gemm (@resilient-routed, so the router plants the
     device.ag_gemm.fused annotation) under a live jax.profiler
-    capture. world=1: the multi-device interpret ring cannot trace
-    ``get_barrier_semaphore`` on this jax (the pre-existing 0.4.37
-    gap, see tests/test_ring_bidir.py) — the label/attribution path
-    under test is identical."""
+    capture. world=1 keeps it cheap — the label/attribution path under
+    test is identical."""
     import numpy as np
     from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
     from triton_dist_tpu.ops.allgather_gemm import (ag_gemm,

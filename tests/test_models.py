@@ -373,19 +373,13 @@ def test_paged_kv_cache_matches_contiguous(mesh8, key, monkeypatch):
                                rtol=2e-3, atol=2e-3)
     # The DIRECT block-table-indirection Pallas kernel, now the opt-in
     # (default flipped to "gathered" until the direct kernel's on-chip
-    # Mosaic compile hang is root-caused — ADVICE r5): its
-    # interpret-mode numerics stay pinned where the interpreter
-    # supports barrier semaphores (jax 0.4.x does not — the supported
-    # paths above still fully validate there).
-    try:
-        got = gqa_fwd_batch_decode_paged(
-            q, pools[0][0], pools[0][1], mgr.block_table(), kv_len,
-            dc.replace(ctx, paged_variant="direct"))
-    except NotImplementedError:
-        got = None
-    if got is not None:
-        np.testing.assert_allclose(np.asarray(got), np.asarray(ref),
-                                   rtol=2e-3, atol=2e-3)
+    # Mosaic compile hang is root-caused): its interpret-mode numerics
+    # stay pinned.
+    got = gqa_fwd_batch_decode_paged(
+        q, pools[0][0], pools[0][1], mgr.block_table(), kv_len,
+        dc.replace(ctx, paged_variant="direct"))
+    np.testing.assert_allclose(np.asarray(got), np.asarray(ref),
+                               rtol=2e-3, atol=2e-3)
     # env override wins over the field: with an INVALID field value the
     # call only succeeds if the env value actually replaces it (the
     # validator rejects the resolved value otherwise), so this cannot

@@ -111,8 +111,8 @@ def test_flash_decode_partial_tail(mesh8, key):
 @pytest.mark.parametrize("method", ["one_shot", "two_shot",
                                     "recursive_doubling"])
 def test_allreduce_odd_partials(mesh8, key, method):
-    # (w, 136, 72): M=136 is not divisible by world=8, so TWO_SHOT must
-    # fall back rather than mis-slice; the others take it directly.
+    # (w, 136, 72): M=136 does not split into 8 chunks of whole row
+    # tiles, so TWO_SHOT pads its row split; the others take it directly.
     from triton_dist_tpu.ops.allreduce import (
         AllReduceMethod, create_allreduce_context, all_reduce)
     x = (jax.random.normal(key, (WORLD, 136, 72)) / 4).astype(jnp.float32)

@@ -3,10 +3,8 @@
 Quick tier, CPU-only: every breaker/fallback/retry transition is
 driven by deterministic fault injection (testing/faults.py), not wall
 clocks or real hardware misbehavior. Fused ops run on a 1-device mesh
-— world=1 compiles the kernels without the multi-device barrier
-semaphore the container's jax 0.4.x interpreter cannot trace
-(CHANGES.md PR 2 note), and the resilience machinery is world-size
-agnostic.
+— world=1 keeps the interpreted kernels cheap, and the resilience
+machinery is world-size agnostic.
 
 The acceptance scenario (ISSUE 3): a deterministically injected
 compile hang in one fused op (a) does not block other ops, (b) opens
@@ -37,9 +35,7 @@ from triton_dist_tpu.testing import faults
 
 @pytest.fixture()
 def mesh1(devices):
-    """1-device mesh: compiles fused kernels eagerly on this jax
-    (world=1 skips the barrier semaphore the 0.4.x interpreter cannot
-    trace on multi-device CPU meshes)."""
+    """1-device mesh: the fused kernels run interpreted, cheaply."""
     return Mesh(np.array(devices[:1]), ("tp",))
 
 

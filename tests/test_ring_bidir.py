@@ -15,35 +15,13 @@ import pytest
 
 import jax
 import jax.numpy as jnp
-from jax.experimental.pallas import tpu as pltpu
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-import triton_dist_tpu.language as dl
 from triton_dist_tpu.ops.common import (
     resolve_ring_dirs, ring_chunk_schedule, ring_hop_counts)
 
 #: Interpret-mode kernel numerics -> full tier (like test_ag_gemm.py).
 pytestmark = pytest.mark.slow
-
-
-@pytest.fixture(autouse=True)
-def _barrier_compat_04x():
-    """jax 0.4.x cannot lower ``get_barrier_semaphore`` for the cpu
-    (interpret) platform. The ring kernels under test order their data
-    through per-(direction, chunk) DMA semaphores — every remote write
-    targets a disjoint chunk slot and every read waits its recv
-    semaphore — so stubbing the barrier is sound FOR THESE KERNELS
-    (NOT in general: see the note on ``language.barrier_all``). On a
-    current jax the real barrier runs."""
-    if getattr(pltpu, "InterpretParams", None) is not None:
-        yield
-        return
-    orig = dl.barrier_all
-    dl.barrier_all = lambda *a, **k: None
-    try:
-        yield
-    finally:
-        dl.barrier_all = orig
 
 
 def _mesh(world):

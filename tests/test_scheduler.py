@@ -162,6 +162,21 @@ def test_scheduler_matches_solo_serve(tiny):
         sched.stop()
 
 
+def test_admission_buckets_follow_the_prefill_row_split(tiny):
+    """A row-sharded tp prefill (ag_rs) hands each rank bucket/world
+    rows as its ring chunk, which the fused kernels slice only in whole
+    8-row tiles (ops.common.ring_padded_rows): on 8 ranks the smallest
+    bucket is 64, and every bucket splits into whole tiles. The
+    replicated prefill (xla_ar) keeps the plain power-of-two buckets."""
+    model, params = tiny
+    sharded = Engine(model, batch=2, max_seq=256, prefill_mode="ag_rs",
+                     decode_mode="gemm_ar").stream_session(params)
+    assert [sharded._bucket(n) for n in (1, 5, 64, 65, 200)] == \
+        [64, 64, 64, 128, 256]
+    replicated = _engine(model).stream_session(params)
+    assert [replicated._bucket(n) for n in (1, 5, 9, 40)] == [8, 8, 16, 64]
+
+
 def test_scheduler_stop_tokens_exact_retire(tiny):
     """Per-request stop sets retire rows exactly at the stop token."""
     model, params = tiny

@@ -113,6 +113,27 @@ def test_autotune_disk_cache_failed_config_roundtrip(tmp_path, monkeypatch):
     assert r2.all_ms[0] == float("inf")
 
 
+def test_chip_spec_unknown_accelerator_is_an_error():
+    """Only a CPU device gets the simulator spec; an accelerator the
+    table does not know raises, naming its device_kind — a roofline
+    against made-up peaks is worse than none."""
+    import types
+
+    import pytest
+    from triton_dist_tpu.tools import perf_model as pm
+
+    def dev(platform, kind):
+        return types.SimpleNamespace(platform=platform, device_kind=kind)
+
+    assert pm.get_chip_spec(dev("cpu", "cpu")) is pm.CPU_SIM_SPEC
+    assert pm.get_chip_spec(dev("tpu", "TPU v5 lite")).name == "v5e"
+    assert pm.get_chip_spec(dev("tpu", "TPU v6 lite")).name == "v6e"
+    with pytest.raises(ValueError, match="TPU v9 hyper"):
+        pm.get_chip_spec(dev("tpu", "TPU v9 hyper"))
+    with pytest.raises(ValueError, match="NVIDIA H100"):
+        pm.get_chip_spec(dev("gpu", "NVIDIA H100"))
+
+
 def test_perf_model_monotonic():
     spec = get_chip_spec()
     t1 = estimate_gemm_sol_time_ms(1024, 1024, 1024, spec)

@@ -41,7 +41,9 @@ def golden(params, x, pos, rope, offset):
     v = (xf @ wv).reshape(b, s, NKV, D)
     q = np_rms(q, np.asarray(params["q_norm"], np.float64))
     k = np_rms(k, np.asarray(params["k_norm"], np.float64))
-    cos, sin = (np.asarray(r, np.float64) for r in rope)
+    # The reference's own (cos, sin) tables, from the rope formula.
+    freqs = np.outer(np.arange(T), np.asarray(rope.inv_freq, np.float64))
+    cos, sin = np.cos(freqs), np.sin(freqs)
     q, k = np_rope(q, cos, sin, pos), np_rope(k, cos, sin, pos)
     # causal over the fresh segment only (offset=0 prefill)
     assert offset == 0

@@ -1,11 +1,10 @@
-"""Bench-shape VMEM-budget checks (VERDICT r2 next 10).
+"""Bench-shape VMEM-budget checks.
 
-BENCH_r02's crash class — a default config whose declared VMEM scratch
-cannot compile on the chip — must fail HERE, in CI on any host, not on
-the chip. The gate asserts against HARD_FOOTPRINT_CAP (26 MB declared):
+A default config whose declared VMEM scratch the chip's compiler
+refuses must fail HERE, in CI on any host, not on the chip. The gate asserts against HARD_FOOTPRINT_CAP (26 MB declared):
 the library's comm kernels request a 64 MB Mosaic scoped-VMEM limit via
 ``comm_params`` and Mosaic's scoped accounting carries ~2.2x overhead
-over declared buffers (measured round 5; constants in ops/common.py).
+over declared buffers (constants in ops/common.py).
 A kernel built WITHOUT ``comm_params`` keeps Mosaic's 16 MB default and
 needs the tighter ``limit=`` argument. ``check_entry_vmem`` traces each
 op's ``impl="pallas"`` entry at the exact bench.py shapes with
@@ -107,8 +106,7 @@ def test_sp_attention_fused_prefill_shape_fits():
 def test_sp_attention_fused_bench_shape_fits():
     """THE bench.py sp_attn shape at world=1 (s_loc=4096, hq=16): q +
     state total ~50 MB — the q-group residency must bound what reaches
-    VMEM (BENCH_r02's class; this shape failed the chip in round-3
-    session 4)."""
+    VMEM."""
     from triton_dist_tpu.ops.sp_attention import (
         create_sp_attention_context, sp_ag_attention_fused)
     mesh = _mesh(1)
@@ -153,8 +151,8 @@ def test_train_step_bench_config_fits():
 
 
 def test_vmem_budget_catches_oversized_kernel():
-    """The helper itself must detect an oversized kernel — the BENCH_r02
-    config (16.5 MB of scratch on a 16 MB chip) reproduced in miniature."""
+    """The helper itself must detect an oversized kernel — 16.5 MB of
+    scratch against a 16 MB cap, reproduced in miniature."""
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 

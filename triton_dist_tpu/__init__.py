@@ -31,35 +31,6 @@ async remote DMA over ICI plus XLA collectives, composed under
 
 __version__ = "0.1.0"
 
-# jax-version compat: the library, tests, and examples target current
-# jax's ``jax.shard_map`` (kwarg ``check_vma``); jax 0.4.x spells it
-# ``jax.experimental.shard_map.shard_map`` with ``check_rep``. Install
-# a translating alias once, at package import, so every call site runs
-# on both (the container's baked-in toolchain pins 0.4.x).
-import jax as _jax
-
-if not hasattr(_jax, "shard_map"):
-    import functools as _functools
-
-    from jax.experimental.shard_map import shard_map as _shard_map_04
-
-    @_functools.wraps(_shard_map_04)
-    def _shard_map_compat(f, *args, check_vma=None, **kwargs):
-        if check_vma is not None:
-            kwargs["check_rep"] = check_vma
-        return _shard_map_04(f, *args, **kwargs)
-
-    _jax.shard_map = _shard_map_compat
-
-if not hasattr(_jax.lax, "axis_size"):
-    def _axis_size_compat(axis_name, *, _psum=_jax.lax.psum):
-        # 0.4.x: psum of a Python literal folds to the static size.
-        return _psum(1, axis_name)
-
-    _jax.lax.axis_size = _axis_size_compat
-
-del _jax
-
 from triton_dist_tpu.runtime.dist import (  # noqa: F401
     initialize_distributed,
     finalize_distributed,

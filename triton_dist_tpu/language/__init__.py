@@ -182,15 +182,6 @@ def barrier_all(axis: str = "tp",
     world-many signals (including self, keeping the count uniform).
     Requires ``collective_id`` in ``pltpu.CompilerParams``. Analog of
     ``barrier_all_intra_node_atomic_cas_block`` (common_ops.py).
-
-    NOTE (jax 0.4.x): ``get_barrier_semaphore`` has no cpu-platform
-    lowering there, so interpret-mode multi-device kernels cannot trace
-    this on that jax generation (the TPU lowering is fine).
-    ``tests/test_ring_bidir.py`` shows the test-side stub pattern for
-    kernels whose data ordering rides per-copy DMA semaphores; a
-    LIBRARY-level no-op is deliberately not provided — protocols like
-    fast_all_to_all rely on the barrier to keep all interpreted devices
-    live until every peer has arrived (a no-op deadlocks them).
     """
     sem = pltpu.get_barrier_semaphore()
     world = lax.axis_size(axis)

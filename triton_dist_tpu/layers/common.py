@@ -9,6 +9,8 @@ fusion.
 
 from __future__ import annotations
 
+from typing import NamedTuple
+
 import jax
 import jax.numpy as jnp
 from jax import lax
@@ -24,25 +26,39 @@ def rms_norm(x: jax.Array, w: jax.Array, eps: float = 1e-6) -> jax.Array:
     return (normed * w.astype(jnp.float32)).astype(x.dtype)
 
 
+class RopeCache(NamedTuple):
+    """Rotary-embedding frequencies (head_dim//2,), fp32. The angles are
+    computed from the positions at use, not read from a
+    (max_len, head_dim//2) table: a table a jitted step closes over is
+    embedded in every compiled program as a constant — two 10 MB arrays
+    at Qwen3's 40960 positions, in each of a server's ~20 programs,
+    which is most of what made one program a 20-57 MB compile-cache
+    entry. The values are the table's up to fp32 rounding: the same
+    product ``position * inv_freq`` through the same ``cos``/``sin``,
+    now fused into their consumer."""
+    inv_freq: jax.Array
+
+
 def precompute_rope_cache(head_dim: int, max_len: int,
-                          theta: float = 1e6) -> tuple[jax.Array, jax.Array]:
-    """(cos, sin) tables of shape (max_len, head_dim//2), fp32
+                          theta: float = 1e6) -> RopeCache:
+    """The rope state of a ``head_dim`` head, valid at every position
+    (``max_len`` is the model's range; nothing is sized by it)
     (reference ``_set_cos_sin_cache`` tp_attn.py:69-75)."""
-    inv_freq = 1.0 / (theta ** (jnp.arange(0, head_dim, 2,
-                                           dtype=jnp.float32) / head_dim))
-    t = jnp.arange(max_len, dtype=jnp.float32)
-    freqs = jnp.outer(t, inv_freq)
-    return jnp.cos(freqs), jnp.sin(freqs)
+    del max_len
+    return RopeCache(1.0 / (theta ** (jnp.arange(0, head_dim, 2,
+                                                 dtype=jnp.float32)
+                                      / head_dim)))
 
 
-def apply_rope(x: jax.Array, cos: jax.Array, sin: jax.Array,
+def apply_rope(x: jax.Array, rope: RopeCache,
                position_ids: jax.Array) -> jax.Array:
     """Neox-style (rotate-half) rotary embedding.
 
     x: (B, S, H, D); position_ids: (B, S). Matches HF Qwen3 /
     flashinfer.apply_rope_with_cos_sin_cache (reference tp_attn.py:166)."""
-    c = cos[position_ids][:, :, None, :]  # (B, S, 1, D/2)
-    s = sin[position_ids][:, :, None, :]
+    freqs = position_ids.astype(jnp.float32)[..., None] * rope.inv_freq
+    c = jnp.cos(freqs)[:, :, None, :]  # (B, S, 1, D/2)
+    s = jnp.sin(freqs)[:, :, None, :]
     x1, x2 = jnp.split(x.astype(jnp.float32), 2, axis=-1)
     out = jnp.concatenate([x1 * c - x2 * s, x2 * c + x1 * s], axis=-1)
     return out.astype(x.dtype)

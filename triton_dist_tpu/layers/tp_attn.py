@@ -23,7 +23,7 @@ from jax.sharding import Mesh, PartitionSpec as P
 from triton_dist_tpu.ops.common import nestable_shard_map
 
 from triton_dist_tpu.layers.common import (
-    apply_rope, col_parallel_matmul, rms_norm, shard_param)
+    RopeCache, apply_rope, col_parallel_matmul, rms_norm, shard_param)
 from triton_dist_tpu.ops.allgather_gemm import create_ag_gemm_context
 from triton_dist_tpu.ops.gemm_reduce_scatter import create_gemm_rs_context
 # Differentiable wrappers (forward-identical; ops/autodiff.py).
@@ -91,7 +91,7 @@ class TPAttn:
 
     # -- forward -----------------------------------------------------------
     def __call__(self, params: dict, x: jax.Array, position_ids: jax.Array,
-                 rope_cache: tuple[jax.Array, jax.Array],
+                 rope_cache: RopeCache,
                  kv_cache: tuple[jax.Array, jax.Array],
                  offset: jax.Array, mode: str | None = None,
                  kv_start: jax.Array | None = None):
@@ -101,7 +101,7 @@ class TPAttn:
           x: (M, H) activations, M = B*S. Row-sharded over tp for
             {xla, ag_rs}; replicated for {xla_ar, gemm_ar}.
           position_ids: (B, S) absolute positions.
-          rope_cache: (cos, sin) tables (T_max, D/2).
+          rope_cache: ``layers.precompute_rope_cache(...)``.
           kv_cache: (k, v) each (B, T, num_kv_heads, D), head-sharded.
           offset: int32 write position into the cache — scalar, or a
             (B,) per-row vector when S == 1 (continuous batching;
@@ -132,9 +132,8 @@ class TPAttn:
         if self.qk_norm:
             q = rms_norm(q, params["q_norm"], self.rms_eps)
             k = rms_norm(k, params["k_norm"], self.rms_eps)
-        cos, sin = rope_cache
-        q = apply_rope(q, cos, sin, position_ids)
-        k = apply_rope(k, cos, sin, position_ids)
+        q = apply_rope(q, rope_cache, position_ids)
+        k = apply_rope(k, rope_cache, position_ids)
 
         attn, new_cache = self._attention(q, k, v, kv_cache, offset,
                                           kv_start)

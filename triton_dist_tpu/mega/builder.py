@@ -142,15 +142,14 @@ class ModelBuilder:
         def fn(x, a, pos, rc, ck, cv, off, *rest):
             tb = rest[0] if rest else None
             b, s = pos.shape
-            cos, sin = rc
             q = constrain((x @ a["w_q"]).reshape(b, s, hq, d))
             k = constrain((x @ a["w_k"]).reshape(b, s, hkv, d))
             v = constrain((x @ a["w_v"]).reshape(b, s, hkv, d))
             if ap.qk_norm:
                 q = rms_norm(q, a["q_norm"], eps)
                 k = rms_norm(k, a["k_norm"], eps)
-            q = apply_rope(q, cos, sin, pos)
-            k = apply_rope(k, cos, sin, pos)
+            q = apply_rope(q, rc, pos)
+            k = apply_rope(k, rc, pos)
             kc = constrain(k).astype(ck.dtype)
             vc = constrain(v).astype(cv.dtype)
             if tb is None:

@@ -264,7 +264,7 @@ class DenseLLM:
 
         ap = self.attn  # head geometry + qk-norm config live there
         hq, hkv, d = ap.num_heads, ap.num_kv_heads, ap.head_dim
-        cos, sin = self.rope_cache
+        rope = self.rope_cache
         eps = c.rms_norm_eps
 
         def layer_body(x, lp, cache):
@@ -276,8 +276,8 @@ class DenseLLM:
             if ap.qk_norm:
                 q = rms_norm(q, a["q_norm"], eps)
                 k = rms_norm(k, a["k_norm"], eps)
-            q = apply_rope(q, cos, sin, pos)
-            k = apply_rope(k, cos, sin, pos)
+            q = apply_rope(q, rope, pos)
+            k = apply_rope(k, rope, pos)
             ck, cv = cache
             # Align to the cache layout (seq-sharded, head-replicated)
             # BEFORE the write: updating with head-sharded operands

@@ -946,12 +946,22 @@ class StreamSession:
         self.engine = engine
         self.params = params
         b = engine.kv.batch
-        # sp prefill shards S over the sp axis: buckets must divide.
-        # Keyed on EITHER mode being "sp" (init asserts they only come
-        # together, but the prefill is what shards S — advisor r3).
-        self._sp_world = (
-            engine.model.mesh.shape[engine.model.sp_axis]
-            if "sp" in (engine.prefill_mode, engine.decode_mode) else 1)
+        # Admission buckets must split the way the prefill shards them.
+        # sp prefill shards S over the sp axis: buckets must divide
+        # (keyed on EITHER mode being "sp" — init asserts they only
+        # come together, but the prefill is what shards S). The
+        # row-sharded tp prefills (xla / ag_rs) hand each rank M/world
+        # rows as its ring chunk, which the fused kernels can only
+        # slice in whole row tiles (ops.common.ring_padded_rows).
+        mesh = engine.model.mesh
+        if "sp" in (engine.prefill_mode, engine.decode_mode):
+            self._bucket_quantum = mesh.shape[engine.model.sp_axis]
+        elif engine.prefill_mode in ("xla", "ag_rs"):
+            from triton_dist_tpu.ops.common import ring_padded_rows
+            self._bucket_quantum = ring_padded_rows(
+                1, mesh.shape[engine.model.axis])
+        else:
+            self._bucket_quantum = 1
         engine.kv.reset()
         self.cur_table = None
         if engine.paged:
@@ -1057,10 +1067,10 @@ class StreamSession:
         return self._admit_whole(row, prompt, gen_budget=gen_budget)
 
     def _bucket(self, n: int) -> int:
-        """Power-of-two prompt bucket rounded up to an sp-world
-        multiple (sp prefill shards S over the sp axis)."""
+        """Power-of-two prompt bucket rounded up to a multiple of the
+        prefill's row split (``_bucket_quantum``)."""
         lb = self.engine._bucket_len(n)
-        return -(-lb // self._sp_world) * self._sp_world
+        return -(-lb // self._bucket_quantum) * self._bucket_quantum
 
     def _admit_whole(self, row: int, prompt: list,
                      gen_budget: int | None = None) -> int:
@@ -1410,6 +1420,7 @@ class StreamSession:
             if grew:
                 self.cur_table = eng.kv.block_table()
         done = jnp.asarray([not alive for alive in self.live])
+        obs.counter(f"engine.decode_path.{kind}").inc()
         with obs.span("engine.stream_step"):
             eng.key, sub = jax.random.split(eng.key)
             self.token, self.caches, self.offsets = step_fn(

@@ -466,11 +466,15 @@ def ring_schedule_events(op: str, *, world: int, dirs: int,
     dc = compute_ms / world * 1e3                    # us per chunk
     dh = comm_ms / max(n_hops, 1) * 1e3              # us per hop
     tid = current_trace_id()
+    import jax
     for s in range(world):
-        chunk, is_bwd, off = ring_chunk_schedule(0, s, world, dirs)
-        args = {"op": op, "step": s, "chunk": int(chunk),
-                "dir": "bwd" if bool(is_bwd) else "fwd",
-                "hop": int(off)}
+        # Called at dispatch, i.e. inside the caller's jit trace: the
+        # schedule math must evaluate now, not be staged into it.
+        with jax.ensure_compile_time_eval():
+            chunk, is_bwd, off = ring_chunk_schedule(0, s, world, dirs)
+            args = {"op": op, "step": s, "chunk": int(chunk),
+                    "dir": "bwd" if bool(is_bwd) else "fwd",
+                    "hop": int(off)}
         t.emit("X", f"chunk{int(chunk)}", "comms", ts_us=t0 + s * dc,
                dur_us=dc, args=args, track=f"comms.{op}.compute",
                trace_id=tid)

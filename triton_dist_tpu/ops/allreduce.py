@@ -43,6 +43,7 @@ from triton_dist_tpu.ops.common import (
     nestable_shard_map,
     record_comm,
     resolve_interpret,
+    ring_padded_rows,
     sync_interpret)
 
 
@@ -271,8 +272,11 @@ def all_reduce(x: jax.Array, ctx: AllReduceContext | None = None,
     method = ctx.method
     if method is AllReduceMethod.AUTO:
         method = get_auto_allreduce_method(world, m * n * x.dtype.itemsize)
-    if method is AllReduceMethod.TWO_SHOT and m % world != 0:
-        method = AllReduceMethod.ONE_SHOT
+    # TWO_SHOT slices the buffer into ``world`` row chunks; an M that
+    # does not split into whole row tiles is zero-padded for the kernel
+    # and sliced back (the method asked for is the method that runs).
+    m_pad = (ring_padded_rows(m, world)
+             if method is AllReduceMethod.TWO_SHOT else m)
     if (method is AllReduceMethod.RECURSIVE_DOUBLING
             and world & (world - 1)):
         method = AllReduceMethod.ONE_SHOT    # needs power-of-two world
@@ -306,7 +310,7 @@ def all_reduce(x: jax.Array, ctx: AllReduceContext | None = None,
                    pltpu.SemaphoreType.DMA((n_steps,)),
                    pltpu.SemaphoreType.DMA((n_steps,))]
     else:
-        rows = m // world
+        rows = m_pad // world
         kernel = functools.partial(_two_shot_ar_kernel, axis=axis,
                                    world=world, rows=rows,
                                    straggler_option=ctx.straggler_option)
@@ -318,15 +322,18 @@ def all_reduce(x: jax.Array, ctx: AllReduceContext | None = None,
                    pltpu.SemaphoreType.DMA((world,))]
 
     def body(xs):
+        part = xs[0]
+        if m_pad != m:
+            part = jnp.pad(part, ((0, m_pad - m), (0, 0)))
         r = pl.pallas_call(
             kernel,
-            out_shape=jax.ShapeDtypeStruct((m, n), x.dtype),
+            out_shape=jax.ShapeDtypeStruct((m_pad, n), x.dtype),
             in_specs=[pl.BlockSpec(memory_space=pltpu.VMEM)],
             out_specs=pl.BlockSpec(memory_space=pltpu.VMEM),
             scratch_shapes=scratch,
             compiler_params=comm_params(collective_id=3, world=world),
             interpret=interpret,
-        )(xs[0])
+        )(part)[:m]
         return r[None] if stacked else r
 
     f = nestable_shard_map(body, mesh=mesh, in_specs=P(axis),

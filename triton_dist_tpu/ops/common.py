@@ -15,40 +15,15 @@ from triton_dist_tpu.obs import record_comm  # noqa: F401  (op entries)
 from triton_dist_tpu.runtime.platform import default_interpret
 
 
-# -- jax version compat -----------------------------------------------------
-# The library targets the current jax API
-# (jax.sharding.get_abstract_mesh/AxisType, pltpu.CompilerParams /
-# InterpretParams); jax 0.4.x has no abstract-mesh tracking and spells
-# the params pltpu.TPUCompilerParams. These helpers keep one compat
-# site per concept instead of hasattr checks at each use. (The
-# jax.shard_map check_vma→check_rep alias lives in the package
-# __init__ — tests and examples call it directly too.)
-
 def _abstract_mesh():
-    """Current thread's AbstractMesh, or None when this jax either has
-    no tracking (0.4.x) or reports an empty context."""
-    gam = getattr(jax.sharding, "get_abstract_mesh", None)
-    if gam is None:
-        return None
-    am = gam()
-    if am is None or getattr(am, "empty", True):
-        return None
-    return am
+    """Current thread's AbstractMesh, or None outside any mesh context."""
+    am = jax.sharding.get_abstract_mesh()
+    return None if am.empty else am
 
 
 def _manual_axis_flags(am) -> list[bool]:
-    """Per-axis is-Manual flags of an AbstractMesh; [] when this jax
-    does not expose axis types."""
-    axis_types = getattr(am, "axis_types", None)
-    manual = getattr(jax.sharding, "AxisType", None)
-    if axis_types is None or manual is None:
-        return []
-    return [t == manual.Manual for t in axis_types]
-
-
-# NOTE: jax.shard_map itself always exists here — the package __init__
-# installs a check_vma→check_rep translating alias on jax 0.4.x before
-# this module can load — so call sites use jax.shard_map directly.
+    """Per-axis is-Manual flags of an AbstractMesh."""
+    return [t == jax.sharding.AxisType.Manual for t in am.axis_types]
 
 
 def resolve_interpret(interpret: bool | None):
@@ -86,12 +61,7 @@ def resolve_interpret(interpret: bool | None):
         from triton_dist_tpu.runtime.interpret_compat import (
             patch_interpreter_spin)
         patch_interpreter_spin()
-        interpret_params = getattr(pltpu, "InterpretParams", None)
-        if interpret_params is None:
-            # jax 0.4.x: no TPU-interpret parameter object (and no race
-            # detector) — plain interpret mode is the best available.
-            return True
-        return interpret_params(
+        return pltpu.InterpretParams(
             detect_races=bool(os.environ.get("TDT_DETECT_RACES")))
     return False
 
@@ -118,42 +88,41 @@ def sync_interpret(out, interpret) -> object:
 
 #: Mosaic scoped-VMEM limit requested for every comm kernel. Mosaic's
 #: default cap is 16 MB, but a v5e core has 128 MB of physical VMEM
-#: (public TPU flash kernels run with vmem_limit_bytes up to 128 MB);
-#: the round-5 on-chip compile of the fused SP kernel was rejected at
-#: 16.14 MB scoped for ~7.4 MB of declared scratch. 64 MB absorbs that
-#: overhead for every budget-sized shape while leaving headroom for
-#: XLA's own scoped uses.
+#: (public TPU flash kernels run with vmem_limit_bytes up to 128 MB).
+#: Scoped use exceeds the declared scratch (one compile of the fused SP
+#: kernel reported 16.14 MB scoped for ~7.4 MB declared); 64 MB absorbs
+#: that overhead for every budget-sized shape while leaving headroom
+#: for XLA's own scoped uses. tests/test_chip_compile.py compiles the
+#: main-path kernels for the v5e under this limit.
 VMEM_LIMIT_BYTES = 64 * 1024 * 1024
 
 #: Ceiling on a kernel's DECLARED scratch footprint. Mosaic's scoped
 #: accounting carries roughly 2.2x of window/staging overhead on top of
-#: the declared buffers (measured round-5: 16.14 MB scoped for ~7.4 MB
-#: declared), so declared footprints up to ~26 MB compile under
+#: the declared buffers (16.14 MB scoped for ~7.4 MB declared in the
+#: one compile on record), so declared footprints up to ~26 MB fit under
 #: :data:`VMEM_LIMIT_BYTES`. Config tables list over-soft-budget
 #: "aggressive tier" entries up to this cap for the autotuner; the
 #: per-op clamps reject anything beyond it so an uncompilable config
-#: never reaches Mosaic (BENCH_r02).
+#: never reaches Mosaic.
 HARD_FOOTPRINT_CAP = 26 * 1024 * 1024
 
 #: Soft VMEM budget the fused ops' "auto" tile choice and default-path
-#: clamps target. Back to the PROVEN 12 MB (ADVICE r5 medium 2): the
-#: round-5 default path compiled on chip under 12 MB, and the 24 MB
-#: raise that round introduced was never revalidated there — an
-#: unproven default is the BENCH_r02 crash class waiting to recur. The
-#: larger declared footprints the raise was after are still reachable,
-#: but only through paths with per-config compile-failure isolation:
+#: clamps target: 12 MB declared, the largest default footprint that
+#: has compiled for the chip — a default that Mosaic refuses (a GEMM
+#: config once declared 16.5 MB of scratch against the 16 MB default
+#: cap) takes the whole program down. Larger declared footprints are
+#: reachable only through paths with per-config compile-failure
+#: isolation:
 #: autotune sweeps and tuned winners run against
 #: :data:`TUNED_VMEM_BUDGET` / :data:`HARD_FOOTPRINT_CAP` (the sweep
 #: scores a config that fails to compile as inf instead of crashing).
 DEFAULT_VMEM_BUDGET = 12 * 1024 * 1024
 
-#: Budget-tier boundary for AUTOTUNE candidate tables (the round-5
-#: value DEFAULT_VMEM_BUDGET briefly held): 24 MB declared x the
-#: measured ~2.2x scoped overhead ~= 53 MB, under the 64 MB
+#: Budget-tier boundary for AUTOTUNE candidate tables: 24 MB declared x
+#: the ~2.2x scoped overhead ~= 53 MB, under the 64 MB
 #: :data:`VMEM_LIMIT_BYTES` with margin. Only swept / trust_blocks
 #: paths — which carry per-config failure isolation — use it; the
-#: default path keeps :data:`DEFAULT_VMEM_BUDGET` until
-#: ``smoke_revalidate`` passes these shapes on chip.
+#: default path keeps :data:`DEFAULT_VMEM_BUDGET`.
 TUNED_VMEM_BUDGET = 24 * 1024 * 1024
 assert DEFAULT_VMEM_BUDGET < TUNED_VMEM_BUDGET < HARD_FOOTPRINT_CAP
 
@@ -232,16 +201,7 @@ def comm_params(collective_id: int | None = 0,
         obs.gauge("vmem.scoped_limit_bytes").set(limit)
         obs.gauge("vmem.declared_budget_bytes").set(DEFAULT_VMEM_BUDGET)
         obs.gauge("vmem.declared_cap_bytes").set(HARD_FOOTPRINT_CAP)
-    params_cls = getattr(pltpu, "CompilerParams", None)
-    if params_cls is None:
-        # jax 0.4.x name; it also lacks some fields (has_side_effects)
-        # — drop what it cannot carry rather than TypeError the whole
-        # kernel build.
-        import dataclasses
-        params_cls = pltpu.TPUCompilerParams
-        known = {f.name for f in dataclasses.fields(params_cls)}
-        kwargs = {k: v for k, v in kwargs.items() if k in known}
-    return params_cls(**kwargs)
+    return pltpu.CompilerParams(**kwargs)
 
 
 def maybe_straggle(straggler_option, axis: str, interpret=False) -> None:
@@ -292,10 +252,9 @@ def maybe_noise(for_correctness: bool, axis: str, world: int,
 def resolve_ring_dirs(ring_dirs: int = 0) -> int:
     """Ring direction count for the fused comm-GEMM schedules.
 
-    ``2`` = bidirectional (default), ``1`` = the unidirectional
-    proven-on-chip fallback. ``0`` consults ``TDT_RING_DIRS`` (so the
-    round-5-measured schedule stays selectable without code changes)
-    and falls back to 2.
+    ``2`` = bidirectional (default), ``1`` = unidirectional. ``0``
+    consults ``TDT_RING_DIRS`` (so either schedule stays selectable
+    without code changes) and falls back to 2.
     """
     if ring_dirs not in (0, 1, 2):
         raise ValueError(f"ring_dirs must be 0 (auto), 1 or 2: {ring_dirs}")
@@ -348,6 +307,21 @@ def ring_chunk_schedule(me, s, world: int, dirs: int):
                     s - n_bwd)
     chunk = lax.rem(jnp.where(is_bwd, me + off, me - off) + world, world)
     return chunk, is_bwd, off
+
+
+#: Row granularity of one rank's ring chunk. The ring kernels slice an
+#: (M, N) operand into ``world`` row chunks, and Mosaic refuses a row
+#: slice that is not whole 8-sublane tiles ("Slice shape along dimension
+#: 0 must be aligned to tiling (8)", "cannot statically prove that index
+#: in dimension 0 is a multiple of 8").
+RING_ROW_TILE = 8
+
+
+def ring_padded_rows(m: int, world: int) -> int:
+    """Smallest row count >= ``m`` that splits into ``world`` chunks of
+    whole :data:`RING_ROW_TILE` tiles. ``world == 1`` takes no row
+    slice, so any ``m`` stands."""
+    return m if world == 1 else round_up(m, world * RING_ROW_TILE)
 
 
 def vmem_spec(block_shape=None, index_map=None):

@@ -78,8 +78,8 @@ class FlashDecodeContext:
     variant: str = "auto"
     # KV positions per VMEM tile for the tiled variant (dense path);
     # auto-shrunk so the two double-buffered (B, t_blk, Hkv, D) K/V tiles
-    # fit ``vmem_budget`` (BENCH_r02 class: an infeasible tile size must
-    # never reach the compiler — tests/test_vmem_budget.py).
+    # fit ``vmem_budget`` (an infeasible tile size must never reach
+    # the compiler — tests/test_vmem_budget.py).
     t_blk: int = 512
     vmem_budget: int = 10 * 1024 * 1024
     # Byte threshold for auto: einsum below (shard fits VMEM comfortably).
@@ -191,13 +191,29 @@ def _local_partials(q, k, v, first_pos, kv_len, groups: int,
     return a, l, m
 
 
+#: Lane width of the combine's (l, m) exchange buffers. The softmax
+#: statistics are (B, K, G) scalars, but a remote DMA moves whole
+#: (sublane, 128-lane) tiles: laid out with ``groups`` (2 or 4) as the
+#: minor dimension Mosaic refuses the per-rank slice ("Slice shape along
+#: dimension 3 must be aligned to tiling (128), but is 4"). So each
+#: statistic is broadcast along a trailing 128-lane dimension, like the
+#: head dimension of the ``a`` partial beside it.
+_STAT_LANES = 128
+
+
+def _lane_bcast(x):
+    return jnp.broadcast_to(x[..., None], x.shape + (_STAT_LANES,))
+
+
 def _merge(a, l, m):
-    """Merge per-rank partials stacked on the leading axis (w, B, K, G, ...)."""
+    """Merge per-rank partials stacked on the leading axis: ``a``
+    (w, B, K, G, D); ``l``/``m`` (w, B, K, G, _STAT_LANES), every lane
+    holding the same value."""
     m_star = jnp.max(m, axis=0, keepdims=True)
     scale = jnp.exp(m - m_star)
-    num = jnp.sum(a * scale[..., None], axis=0)
+    num = jnp.sum(a * scale[..., :1], axis=0)
     den = jnp.sum(l * scale, axis=0)
-    return num / jnp.maximum(den, 1e-20)[..., None]
+    return num / jnp.maximum(den[..., :1], 1e-20)
 
 
 def combine_peer(me, p, world: int):
@@ -271,8 +287,8 @@ def _decode_kernel(q_ref, k_ref, v_ref, len_ref, o_ref, abuf, lbuf, mbuf,
     a, l, m = _local_partials(q_ref[:], k_ref[:], v_ref[:],
                               me * t_loc, kv_len, groups, mosaic=True)
     abuf[me] = a
-    lbuf[me] = l
-    mbuf[me] = m
+    lbuf[me] = _lane_bcast(l)
+    mbuf[me] = _lane_bcast(m)
     _exchange_and_merge(abuf, lbuf, mbuf, send_sem, recv_sem, o_ref,
                         axis=axis, world=world)
 
@@ -391,16 +407,17 @@ def _tiled_decode_kernel(q_ref, len_ref, table_ref, k_hbm, v_hbm, o_ref,
     m_f, l_f, a_f = lax.fori_loop(0, n_tiles, tile_step, (m0, l0, a0))
 
     abuf[me] = a_f
-    lbuf[me] = l_f
-    mbuf[me] = m_f
+    lbuf[me] = _lane_bcast(l_f)
+    mbuf[me] = _lane_bcast(m_f)
     _exchange_and_merge(abuf, lbuf, mbuf, send_sem, recv_sem, o_ref,
                         axis=axis, world=world)
 
 
 def _combine_shapes(world, b, hkv, groups, d):
+    stat = jax.ShapeDtypeStruct((world, b, hkv, groups, _STAT_LANES),
+                                jnp.float32)
     return (jax.ShapeDtypeStruct((world, b, hkv, groups, d), jnp.float32),
-            jax.ShapeDtypeStruct((world, b, hkv, groups), jnp.float32),
-            jax.ShapeDtypeStruct((world, b, hkv, groups), jnp.float32))
+            stat, stat)
 
 
 @resilient("flash_decode")

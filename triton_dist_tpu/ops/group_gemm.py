@@ -341,12 +341,11 @@ def ag_group_gemm(x: jax.Array, w: jax.Array, expert_ids: jax.Array,
         choice = _IMPL_TUNED.get(shape_key)
         if choice is None and not isinstance(x, jax.core.Tracer):
             from triton_dist_tpu.tools.autotuner import autotune
-            from triton_dist_tpu.runtime.utils import make_perturbed_runner
 
             def make_fn(impl):
                 fn = jax.jit(lambda xv: ag_group_gemm(
                     xv, w, expert_ids, num_experts, ctx, impl=impl))
-                return make_perturbed_runner(fn, x)
+                return lambda: fn(x)
 
             res = autotune(make_fn, [{"impl": "ring"}, {"impl": "fused"}],
                            key=tune_key, iters=8, warmup_iters=2)

@@ -360,12 +360,11 @@ def moe_reduce_rs(act: jax.Array, w_down: jax.Array, expert_ids: jax.Array,
         choice = _IMPL_TUNED.get(shape_key)
         if choice is None and not isinstance(act, jax.core.Tracer):
             from triton_dist_tpu.tools.autotuner import autotune
-            from triton_dist_tpu.runtime.utils import make_perturbed_runner
 
             def make_fn(impl):
                 fn = jax.jit(lambda a: moe_reduce_rs(
                     a, w_down, expert_ids, weights, ctx, impl=impl))
-                return make_perturbed_runner(fn, act)
+                return lambda: fn(act)
 
             res = autotune(make_fn, [{"impl": "ring"}, {"impl": "fused"}],
                            key=tune_key, iters=8, warmup_iters=2)

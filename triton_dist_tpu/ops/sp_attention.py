@@ -81,8 +81,8 @@ class SpAttentionContext:
     head_axis: str | None = None
     # VMEM budget for the fused kernel's resident q-group + state
     # (bytes): the wrapper sizes the slab group so q_buf + (m, l, acc)
-    # + the fixed KV tiles/output stage fit (BENCH_r02 class: an
-    # over-budget residency must never reach the compiler).
+    # + the fixed KV tiles/output stage fit (an over-budget
+    # residency must never reach the compiler).
     vmem_budget: int = 10 * 1024 * 1024
 
     @property
@@ -171,8 +171,8 @@ def _sp_fused_kernel(q_hbm, k_ref, v_ref, o_hbm, kw_hbm, vw_hbm, q_buf,
     VMEM discipline: q lives in HBM pre-slabbed and is processed in
     GROUPS of ``n_res`` slabs — each group's q + fp32 (m, l, acc) state
     are VMEM-resident, sized to the budget by the wrapper (the bench
-    prefill shape put ~50 MB of q+state against the 16 MB chip —
-    BENCH_r02's class). The KV ring runs ONCE, during group 0 (its
+    prefill shape puts ~50 MB of q+state against the 16 MB default
+    scoped cap). The KV ring runs ONCE, during group 0 (its
     forwarding fills the HBM workspace); later groups re-consume the
     landed chunks with no further communication. K/V inputs, the AG
     workspace and the output stay in HBM (outputs drain through a
@@ -396,7 +396,7 @@ def sp_ag_attention_fused(q: jax.Array, k: jax.Array, v: jax.Array,
     n_slabs = n_q * hkv
 
     # Size the resident q-group to the VMEM budget (the bench prefill
-    # shape put ~50 MB of q+state on a 16 MB chip — BENCH_r02's class).
+    # shape puts ~50 MB of q+state against the 16 MB default cap).
     item = q.dtype.itemsize
     fixed = (2 * 2 * b * t_sub * hkv * d * k.dtype.itemsize   # k/v tiles
              + 2 * b * rows * d * item)                       # o stage

@@ -2,9 +2,9 @@
 layout.
 
 The reference leaves strategy choice to the user (its tests hard-code
-TP=8 etc.); here the framework's divisibility rules and the round-3
-measured crossovers (BENCH_NOTES_r3.md; e.g. replicated GEMM-AR wins
-small-batch decode) pick a starting point:
+TP=8 etc.); here the framework's divisibility rules and a rule of
+thumb (replicated GEMM-AR for small-batch decode; not measured on
+the current chip) pick a starting point:
 
 - **tp** divides BOTH the kv-head count and the MLP intermediate
   (gcd-based cap) and grows until the per-chip parameter bytes fit
@@ -141,9 +141,9 @@ def plan_parallelism(config, n_chips: int, max_seq: int = 4096,
         prefill = decode = "sp"
     else:
         prefill = "ag_rs"
-        # Round-3 measured crossover (BENCH_NOTES_r3.md): replicated
-        # GEMM-AR wins small decode batches; the sharded path wins once
-        # the batch splits usefully across tp.
+        # Rule of thumb, not measured on the current chip: replicated
+        # GEMM-AR for small decode batches; the sharded path once the
+        # batch splits usefully across tp.
         decode = "gemm_ar" if decode_batch < 8 * tp else "ag_rs"
         reasons.append(f"decode={decode} at batch {decode_batch}")
 

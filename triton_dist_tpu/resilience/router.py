@@ -397,8 +397,16 @@ def _default_config(bound: inspect.BoundArguments,
     return ",".join(parts)
 
 
-def _has_tracer(bound: inspect.BoundArguments) -> bool:
+def _is_tracing(bound: inspect.BoundArguments) -> bool:
+    """True when this call is being staged rather than executed: some
+    argument is a tracer, or the thread is inside a trace (``jit``,
+    ``export``, ``shard_map``) whose operands the op closed over. The
+    ambient trace is thread-local, so a traced call must never hop to
+    the watchdog thread — there it would run eagerly on the default
+    backend instead of being staged."""
     import jax
+    if not jax.core.trace_ctx.is_top_level():
+        return True
     for v in bound.arguments.values():
         for leaf in jax.tree_util.tree_leaves(v):
             if isinstance(leaf, jax.core.Tracer):
@@ -521,7 +529,7 @@ def resilient(op: str, *, fused_impls: tuple[str, ...] = ("pallas",),
                 # perf watch (tests, benches, and direct users are the
                 # main source of reference-branch wall times).
                 if (bound.arguments.get("impl") == fallback_impl
-                        and obs.enabled() and not _has_tracer(bound)):
+                        and obs.enabled() and not _is_tracing(bound)):
                     t0 = time.perf_counter()
                     out = fn(*args, **kwargs)
                     _record_sample(op, "xla", bound, t0, out)
@@ -570,7 +578,7 @@ def resilient(op: str, *, fused_impls: tuple[str, ...] = ("pallas",),
                         return _guarded(op, key, config, call,
                                         bound, fallback_impl)
                 _count_fallback(op, reason)
-                if obs.enabled() and not _has_tracer(bound):
+                if obs.enabled() and not _is_tracing(bound):
                     t0 = time.perf_counter()
                     out = call(fallback_impl)
                     _record_sample(op, "xla", bound, t0, out)
@@ -592,7 +600,7 @@ def _guarded(op, key, config, call, bound, fallback_impl):
 
     fused_impl = bound.arguments["impl"]
     obs.counter(f"resilience.{op}.fused_total").inc()
-    tracing = _has_tracer(bound)
+    tracing = _is_tracing(bound)
     timeout = compile_timeout_s()
     rec = not tracing and obs.enabled()
     t0 = time.perf_counter() if rec else 0.0

@@ -2,8 +2,8 @@
 
 ``jax._src.pallas.mosaic.interpret.shared_memory.Semaphore.wait`` busy-spins
 (``while True: ... continue``) when waiting on a DMA semaphore whose
-matching DMA has not been issued yet. On low-core-count hosts (CI boxes,
-this image has 1 CPU), the spinning waiter threads starve the device threads
+matching DMA has not been issued yet. On low-core-count hosts (CI boxes),
+the spinning waiter threads starve the device threads
 that would issue those DMAs — GIL + lock-convoy on the shared-memory lock —
 so multi-device kernels hang nondeterministically.
 
@@ -27,12 +27,11 @@ def patch_interpreter_spin() -> None:
     global _PATCHED
     if _PATCHED:
         return
-    try:
-        from jax._src.pallas.mosaic.interpret import shared_memory
-        from jax._src.pallas.mosaic.interpret import vector_clock as vc
-    except ImportError:  # interpreter layout changed; leave upstream as-is
-        _PATCHED = True
-        return
+    # Private modules of the installed jax (0.9.0): an import error here
+    # means the interpreter moved and this patch needs re-deriving —
+    # fail loudly rather than hang later on the unpatched spin.
+    from jax._src.pallas.mosaic.interpret import shared_memory
+    from jax._src.pallas.mosaic.interpret import vector_clock as vc
 
     def wait(self, value, global_core_id, *, has_tasks=False):
         global_core_id = int(global_core_id)

@@ -61,8 +61,13 @@ def topology_aware_grid(devices: np.ndarray, shape) -> np.ndarray:
             and flat.size == len(jax.devices()) and flat.size > 1):
         try:
             from jax.experimental import mesh_utils
-            return np.asarray(
-                mesh_utils.create_device_mesh(shape, devices=list(flat)))
+            # A 1-D request comes back in enumeration order (on a 2x2,
+            # 0-1-2-3 puts a diagonal between ring neighbours); asked
+            # for a leading unit axis, mesh_utils lays the same devices
+            # out as a ring of physical neighbours (0-1-3-2).
+            ask = (1,) + shape if len(shape) == 1 else shape
+            return np.asarray(mesh_utils.create_device_mesh(
+                ask, devices=list(flat))).reshape(shape)
         except Exception as e:  # noqa: BLE001 — layout is an optimization
             import warnings
             warnings.warn(

@@ -651,7 +651,12 @@ def main():  # pragma: no cover - manual demo
     ap.add_argument("--max-seq", type=int, default=1024)
     args = ap.parse_args()
 
-    mesh = Mesh(np.array(jax.devices()), ("tp",))
+    from triton_dist_tpu.runtime.compile_cache import (
+        configure_compile_cache)
+    from triton_dist_tpu.runtime.topology import topology_aware_grid
+    configure_compile_cache()
+    devices = np.array(jax.devices())
+    mesh = Mesh(topology_aware_grid(devices, devices.shape), ("tp",))
     if args.model_dir:
         model, params = AutoLLM.from_pretrained(args.model_dir, mesh=mesh)
     else:

@@ -1,10 +1,10 @@
 """Static VMEM-footprint assertion for Pallas kernels.
 
-BENCH_r02 died because a default GEMM config allocated 16.5 MB of VMEM
-scratch against the v5e's 16 MB limit — and nothing between the config
-table and the hardware compiler checked the budget (VERDICT r2 weak 1 /
-next 10: "a static VMEM-footprint assertion helper so config bugs fail
-in CI instead of on the chip"). The reference has no analog (its
+Mosaic's default scoped-VMEM cap is 16 MB: a default GEMM config that
+declared 16.5 MB of scratch was refused by the chip's compiler, and
+nothing between the config table and the compiler had checked the
+budget. This helper makes such config bugs fail in CI instead of on the
+chip. The reference has no analog (its
 configs are validated by running on the GPU); on TPU the budget is
 statically computable from the ``pallas_call`` signature.
 

@@ -80,10 +80,13 @@ def main(argv=None):
 
     from triton_dist_tpu.models import AutoLLM, make_train_step
     from triton_dist_tpu.models.checkpoint import load_params, save_params
+    from triton_dist_tpu.runtime.compile_cache import (
+        configure_compile_cache)
     from triton_dist_tpu.runtime.dist import initialize_distributed
 
     import numpy as np
 
+    configure_compile_cache()
     initialize_distributed({"tp": len(jax.devices())})
     model, params = AutoLLM.from_pretrained(args.model, fwd_mode=args.mode,
                                             impl=args.impl)

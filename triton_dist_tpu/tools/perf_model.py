@@ -31,8 +31,7 @@ class ChipSpec:
 
 # Public-spec table (order matters: first matching substring wins).
 # "lite" keys first: real device_kind strings are e.g. "TPU v5 lite" /
-# "TPU v6 lite", which no bare "v5e"/"v6e" substring matches — missing
-# them would silently select the cpu-sim spec on the bench chip.
+# "TPU v6 lite", which no bare "v5e"/"v6e" substring matches.
 CHIP_SPECS = {
     "v5 lite": ChipSpec("v5e", 197.0, 819.0, 50.0, 4),
     "v6 lite": ChipSpec("v6e", 918.0, 1640.0, 100.0, 4),
@@ -40,19 +39,30 @@ CHIP_SPECS = {
     "v5p": ChipSpec("v5p", 459.0, 2765.0, 100.0, 6),
     "v5e": ChipSpec("v5e", 197.0, 819.0, 50.0, 4),
     "v4": ChipSpec("v4", 275.0, 1228.0, 50.0, 6),
-    "cpu": ChipSpec("cpu-sim", 1.0, 50.0, 10.0, 2),
 }
+
+#: Stand-in spec for the CPU test meshes only — never a default for an
+#: accelerator the table does not know.
+CPU_SIM_SPEC = ChipSpec("cpu-sim", 1.0, 50.0, 10.0, 2)
 
 
 def get_chip_spec(device=None) -> ChipSpec:
-    """Identify the local chip (reference topology probes)."""
+    """Identify the local chip (reference topology probes). A CPU
+    device gets the simulator spec; an accelerator whose
+    ``device_kind`` is not in :data:`CHIP_SPECS` is an error — a
+    roofline against made-up peaks is worse than none."""
     if device is None:
         device = jax.devices()[0]
-    kind = getattr(device, "device_kind", "cpu").lower()
+    if device.platform == "cpu":
+        return CPU_SIM_SPEC
+    kind = device.device_kind.lower()
     for key, spec in CHIP_SPECS.items():
         if key in kind:
             return spec
-    return CHIP_SPECS["cpu"]
+    raise ValueError(
+        f"no chip spec for device_kind {device.device_kind!r} "
+        f"(platform {device.platform!r}); add it to "
+        f"tools/perf_model.CHIP_SPECS with its published peaks")
 
 
 def estimate_gemm_sol_time_ms(m: int, n: int, k: int,
@@ -423,8 +433,8 @@ def vet_vmem(op: str, cfg: dict, *, cap: int | None = None,
     """Static VMEM gate for one autotune candidate: a rejection reason
     when the declared footprint exceeds ``cap`` (default
     ``HARD_FOOTPRINT_CAP``), else ``None``. Pure Python — no compile
-    is invoked, so a config that would wedge a Mosaic compile (the
-    BENCH_r02 / smoke-queue class) is refused up front."""
+    is invoked, so a config Mosaic would refuse (or hang on) for its
+    VMEM footprint is refused up front."""
     if cap is None:
         from triton_dist_tpu.ops.common import HARD_FOOTPRINT_CAP
         cap = HARD_FOOTPRINT_CAP

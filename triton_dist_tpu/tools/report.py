@@ -6,7 +6,7 @@ numbers as rendered tables (README.md:96-205) — this is the generator
 for ours.
 
 Usage:
-    python -m triton_dist_tpu.tools.report --bench BENCH_r03.json
+    python -m triton_dist_tpu.tools.report --bench bench_result.json
     python -m triton_dist_tpu.tools.report --sweep sweep.jsonl
 """
 
