@@ -1,0 +1,26 @@
+"""Published peaks of the chips the benchmark may run on, keyed by the
+``device_kind`` JAX reports. A device that is not in the table is an
+error, never a default: a roofline against made-up peaks is worse than
+none.
+
+Source: Google Cloud documentation, "TPU v5e" system architecture page
+(per chip: 197 TFLOP/s bf16, 393 TOP/s int8, 16 GB HBM2e at 819 GB/s,
+1,600 Gbit/s inter-chip interconnect).
+"""
+
+from __future__ import annotations
+
+PEAKS = {
+    "TPU v5 lite": {"bf16_flops": 197e12, "int8_ops": 393e12,
+                    "hbm_bytes_per_s": 819e9, "hbm_bytes": 16e9,
+                    "ici_bits_per_s": 1600e9},
+}
+
+
+def peaks_for(device_kind: str) -> dict:
+    try:
+        return PEAKS[device_kind]
+    except KeyError:
+        raise ValueError(
+            f"no published peaks for device_kind {device_kind!r}; add it to "
+            f"benchmark/harness/peaks.py with its source") from None
