@@ -28,7 +28,7 @@ from triton_dist_tpu import obs, resilience
 from triton_dist_tpu.ops.allreduce import (all_reduce,
                                            create_allreduce_context)
 from triton_dist_tpu.ops.gemm_reduce_scatter import (
-    create_gemm_rs_context, gemm_rs)
+    create_gemm_rs_context, gemm_ar, gemm_rs)
 from triton_dist_tpu.ops.p2p import create_p2p_context, pp_shift
 from triton_dist_tpu.testing import faults
 
@@ -313,6 +313,29 @@ def test_force_fused_surfaces_infra_errors(mesh1, monkeypatch,
             all_reduce(xp, ctx, impl="pallas")
     assert _counters()["resilience.allreduce.watchdog_trips"] == 1
     assert len(resilience.known_bad_cache()) == 1
+
+
+@pytest.mark.parametrize("budget,counted", [(1024, 1), (None, 0)])
+def test_gemm_ar_degrade_inside_the_entry_is_counted(mesh1, registry,
+                                                     budget, counted):
+    """When no variant with an all-gather epilogue fits the VMEM budget,
+    ``gemm_ar`` degrades to the XLA dot+psum inside its fused branch,
+    where the router cannot see it: the entry counts it itself under the
+    router's own names (reason ``no_ag_epilogue``), once per program
+    build. A call that keeps its kernel counts nothing."""
+    ctx = create_gemm_rs_context(mesh1, "tp")
+    if budget is not None:
+        ctx.vmem_budget = budget    # nothing fits -> hbm -> hbm_kt -> xla
+    a = jnp.arange(64 * 128, dtype=jnp.float32).reshape(64, 128) / 4096.0
+    b = jnp.arange(128 * 256, dtype=jnp.float32).reshape(128, 256) / 8192.0
+    out = gemm_ar(a, b, ctx, impl="pallas")
+    np.testing.assert_allclose(np.asarray(out), np.asarray(a) @ np.asarray(b),
+                               rtol=1e-3, atol=1e-3)
+    c = _counters()
+    assert c.get("resilience.fallbacks_total", 0) == counted
+    assert c.get("resilience.gemm_ar.fallbacks_total", 0) == counted
+    assert c.get("resilience.gemm_ar.fallback.no_ag_epilogue", 0) == counted
+    assert c["resilience.gemm_ar.fused_total"] == 1    # the router's view
 
 
 def test_user_errors_propagate_not_swallowed(mesh1, registry):

@@ -1056,15 +1056,25 @@ class StreamSession:
         """
         assert not self.live[row] and row not in self._pending, \
             f"row {row} is occupied"
-        prompt = [int(t) for t in prompt]
-        assert prompt, "prompts must be non-empty"
         eng = self.engine
-        if (chunk and not eng.paged and eng.prefill_mode != "sp"
-                and len(prompt) > chunk
-                and -(-len(prompt) // chunk) * chunk <= eng.kv.max_seq):
-            return self._start_chunked(row, prompt, int(chunk),
-                                       gen_budget=gen_budget)
-        return self._admit_whole(row, prompt, gen_budget=gen_budget)
+        n = len(prompt)
+        assert n, "prompts must be non-empty"
+        chunked = bool(
+            chunk and not eng.paged and eng.prefill_mode != "sp"
+            and n > chunk and -(-n // chunk) * chunk <= eng.kv.max_seq)
+        # The padded length the admission program will run: whole
+        # chunks, else the prompt's bucket (a prefix-cache hit on the
+        # paged path shrinks it to the suffix's, see _admit_paged).
+        lb = (-(-n // chunk) * int(chunk) if chunked
+              else min(self._bucket(n), eng.kv.max_seq))
+        args = {"row": row, "prompt_len": n, "bucket": lb}
+        with obs.span("engine.stream_admission", args=args):
+            prompt = [int(t) for t in prompt]
+            if chunked:
+                return self._start_chunked(row, prompt, int(chunk), lb,
+                                           gen_budget=gen_budget)
+            return self._admit_whole(row, prompt, lb, args,
+                                     gen_budget=gen_budget)
 
     def _bucket(self, n: int) -> int:
         """Power-of-two prompt bucket rounded up to a multiple of the
@@ -1072,13 +1082,13 @@ class StreamSession:
         lb = self.engine._bucket_len(n)
         return -(-lb // self._bucket_quantum) * self._bucket_quantum
 
-    def _admit_whole(self, row: int, prompt: list,
+    def _admit_whole(self, row: int, prompt: list, lb: int, args: dict,
                      gen_budget: int | None = None) -> int:
         eng = self.engine
         eng.key, sub = jax.random.split(eng.key)
         if eng.paged:
-            return self._admit_paged(row, prompt, gen_budget, sub)
-        lb = min(self._bucket(len(prompt)), eng.kv.max_seq)
+            return self._admit_paged(row, prompt, lb, args, gen_budget,
+                                     sub)
         padded = prompt + [0] * (lb - len(prompt))
         ids = jnp.asarray([padded], jnp.int32)
         first, self.caches = eng._admit(
@@ -1086,12 +1096,13 @@ class StreamSession:
             jnp.int32(row), sub)
         first = int(first)
         self.admit_info = {"cached": 0}
+        self._count_admitted(len(prompt), lb)
         self._mark_admitted(row, len(prompt))
         self.token = self.token.at[row].set(first)
         self._spec_start(row, prompt, first, gen_budget)
         return first
 
-    def _admit_paged(self, row: int, prompt: list,
+    def _admit_paged(self, row: int, prompt: list, lb: int, args: dict,
                      gen_budget: int | None, sub) -> int:
         """Block-granular paged admission with cross-request prefix
         reuse: map cached prefix blocks into the row's lanes, then run
@@ -1123,7 +1134,9 @@ class StreamSession:
             self.cur_table = kv.block_table()
             if cached:
                 suffix = prompt[cached:]
-                lb = self._bucket(len(suffix))
+                # Only the uncached suffix runs: the span's begin event
+                # (which holds ``args``) says the bucket that ran.
+                lb = args["bucket"] = self._bucket(len(suffix))
                 ids = jnp.asarray([suffix + [0] * (lb - len(suffix))],
                                   jnp.int32)
                 if eng._admit_prefix is None:
@@ -1133,7 +1146,6 @@ class StreamSession:
                     jnp.int32(len(suffix)),
                     self.cur_table[:, row:row + 1], sub)
             else:
-                lb = min(self._bucket(L), kv.max_seq)
                 ids = jnp.asarray([prompt + [0] * (lb - L)], jnp.int32)
                 first, self.caches = eng._admit(
                     self.params, self.caches, ids, jnp.int32(L),
@@ -1153,6 +1165,7 @@ class StreamSession:
         kv.register_prefix(row, prompt, hashes=hashes)
         self._note_prefix(row, L, cached)
         self.admit_info = {"cached": cached}
+        self._count_admitted(L - cached, lb)
         self._mark_admitted(row, L)
         self.token = self.token.at[row].set(first)
         self._spec_start(row, prompt, first, gen_budget)
@@ -1184,13 +1197,11 @@ class StreamSession:
                                  "cached_tokens": cached})
 
     def _start_chunked(self, row: int, prompt: list, chunk: int,
-                       gen_budget: int | None = None):
+                       lb: int, gen_budget: int | None = None):
         eng = self.engine
         if eng._admit_chunk is None:
             eng._admit_chunk = eng._build_admit_chunk()
             eng._admit_finish = eng._build_admit_finish()
-        n_chunks = -(-len(prompt) // chunk)
-        lb = n_chunks * chunk
         padded = prompt + [0] * (lb - len(prompt))
         eng.key, sub = jax.random.split(eng.key)
         self._pending[row] = {
@@ -1199,12 +1210,21 @@ class StreamSession:
             "small": [(jnp.zeros((1, lb) + ck.shape[2:], ck.dtype),
                        jnp.zeros((1, lb) + cv.shape[2:], cv.dtype))
                       for ck, cv in self.caches]}
-        return self.prefill_step(row)
+        return self._prefill_slice(row)
 
     def prefill_step(self, row: int):
         """Advance row ``row``'s chunked admission by one slice; returns
         the first sampled token (int) once the last slice lands, else
         ``None``."""
+        st = self._pending[row]
+        with obs.span("engine.stream_admission",
+                      args={"row": row, "prompt_len": st["len"],
+                            "bucket": st["ids"].shape[1]}):
+            return self._prefill_slice(row)
+
+    def _prefill_slice(self, row: int):
+        """One slice, inside the caller's admission span (the first
+        slice runs in ``prefill_into_row``'s)."""
         eng = self.engine
         st = self._pending[row]
         c = st["chunk"]
@@ -1221,6 +1241,7 @@ class StreamSession:
             jnp.int32(row), st["key"])
         first = int(first)
         self.admit_info = {"cached": 0}
+        self._count_admitted(st["len"], st["ids"].shape[1])
         self._mark_admitted(row, st["len"])
         self.token = self.token.at[row].set(first)
         self._spec_start(row, st["ids"][0, :st["len"]].tolist(), first,
@@ -1279,6 +1300,14 @@ class StreamSession:
         packed payload; a block neither held locally nor shipped fails
         the admission with ``ValueError`` (the caller's re-prefill
         fallback), with full rollback like any failed admission."""
+        # bucket 0: no admission program runs, only the block uploads.
+        with obs.span("engine.stream_admission",
+                      args={"row": row, "prompt_len": len(prompt),
+                            "bucket": 0}):
+            return self._adopt_row(row, prompt, first, gen_budget, blocks)
+
+    def _adopt_row(self, row: int, prompt, first: int,
+                   gen_budget: int | None, blocks: dict) -> int:
         from triton_dist_tpu.serving import kv_stream
         eng, kv = self.engine, self.engine.kv
         assert eng.paged, "adopt_row needs a paged engine"
@@ -1332,10 +1361,16 @@ class StreamSession:
         self._spec_start(row, prompt, int(first), gen_budget)
         return int(first)
 
+    @staticmethod
+    def _count_admitted(ran: int, padded: int) -> None:
+        """One admission's work: the tokens the request needed run (the
+        uncached suffix on the paged path) and the padded length the
+        program(s) ran; their ratio is the work the buckets waste."""
+        obs.counter("engine.admit_prompt_tokens").inc(ran)
+        obs.counter("engine.admit_bucket_tokens").inc(padded)
+
     def _mark_admitted(self, row: int, prompt_len: int) -> None:
         obs.counter("engine.stream_admissions").inc()
-        _trace.instant("engine.stream_admission", "engine",
-                       args={"row": row, "prompt_len": prompt_len})
         self.offsets = self.offsets.at[row].set(prompt_len)
         self._host_off[row] = prompt_len
         self.live[row] = True
@@ -1421,6 +1456,7 @@ class StreamSession:
                 self.cur_table = eng.kv.block_table()
         done = jnp.asarray([not alive for alive in self.live])
         obs.counter(f"engine.decode_path.{kind}").inc()
+        obs.counter("engine.decode_live_rows").inc(sum(self.live))
         with obs.span("engine.stream_step"):
             eng.key, sub = jax.random.split(eng.key)
             self.token, self.caches, self.offsets = step_fn(
@@ -1501,6 +1537,8 @@ class StreamSession:
         if k_w not in eng._spec_step:
             eng._spec_step[k_w] = eng._build_spec_verify_step(k_w)
         step_fn = eng._spec_step[k_w]
+        obs.counter("engine.decode_path.spec").inc()
+        obs.counter("engine.decode_live_rows").inc(len(live_rows))
         with obs.span("engine.spec_verify"):
             nxt, self.caches = step_fn(self.params, self.caches,
                                        jnp.asarray(toks_in),

@@ -402,6 +402,7 @@ def reset() -> None:
 
 class _NullSpan:
     __slots__ = ()
+    elapsed_ms = None      # nothing was timed
 
     def __enter__(self):
         return self
@@ -457,7 +458,7 @@ class _Span:
     inside the span leaves the un-ended begin in the flight record."""
 
     __slots__ = ("_hist", "_name", "_cat", "_args", "_t0", "_ann",
-                 "_traced")
+                 "_traced", "elapsed_ms")
 
     def __init__(self, hist, name: str, cat: str | None = None,
                  args: dict | None = None):
@@ -467,6 +468,10 @@ class _Span:
             name.split(".", 1)[0], "op")
         self._args = args
         self._ann = None
+        #: The region's duration once it has closed — the value the
+        #: histogram got, for a caller that feeds it on (the pump's
+        #: SLO tracker) without timing the region a second time.
+        self.elapsed_ms = None
 
     def __enter__(self):
         self._ann = _enter_annotate(self._name)
@@ -477,7 +482,7 @@ class _Span:
         return self
 
     def __exit__(self, *exc):
-        dt_ms = (time.perf_counter() - self._t0) * 1e3
+        dt_ms = self.elapsed_ms = (time.perf_counter() - self._t0) * 1e3
         if self._traced:
             _trace.end(self._name, self._cat)
         ann, self._ann = self._ann, None

@@ -274,6 +274,7 @@ def fast_all_to_all(send_buf: jax.Array, send_counts: jax.Array,
     def body(buf, counts, rcounts):
         recv = pl.pallas_call(
             kernel,
+            name="all_to_all",
             out_shape=jax.ShapeDtypeStruct(buf.shape, buf.dtype),
             in_specs=[pl.BlockSpec(memory_space=pltpu.SMEM),
                       pl.BlockSpec(memory_space=pltpu.SMEM),

@@ -356,6 +356,7 @@ def all_gather(x: jax.Array, ctx: AllGatherContext | None = None,
     def body(xs):
         return pl.pallas_call(
             kernel,
+            name=f"all_gather_{method.value}",
             out_shape=jax.ShapeDtypeStruct(x.shape, x.dtype),
             in_specs=[pl.BlockSpec(memory_space=pltpu.VMEM)],
             out_specs=pl.BlockSpec(memory_space=pltpu.VMEM),
@@ -406,6 +407,7 @@ def broadcast(x: jax.Array, root: int = 0,
     def body(xs):
         return pl.pallas_call(
             kernel,
+            name="broadcast",
             out_shape=jax.ShapeDtypeStruct((rows,) + x.shape[1:], x.dtype),
             in_specs=[pl.BlockSpec(memory_space=pltpu.VMEM)],
             out_specs=pl.BlockSpec(memory_space=pltpu.VMEM),

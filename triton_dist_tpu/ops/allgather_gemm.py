@@ -737,6 +737,7 @@ def ag_gemm_multi(a: jax.Array, bs,
             wcat = ws[0] if n_b == 1 else jnp.concatenate(ws, axis=1)
             ag, ccat = pl.pallas_call(
                 nb_kernel,
+                name="ag_gemm_hbm",
                 out_shape=(jax.ShapeDtypeStruct((m, k), a.dtype),
                            jax.ShapeDtypeStruct((m, n_tot_loc), a.dtype)),
                 in_specs=[any_spec()] * 2,
@@ -789,6 +790,7 @@ def ag_gemm_multi(a: jax.Array, bs,
             wcat = ws[0] if n_b == 1 else jnp.concatenate(ws, axis=1)
             ag, ccat = pl.pallas_call(
                 hbm_kernel,
+                name="ag_gemm_hbm_kt",
                 out_shape=(jax.ShapeDtypeStruct((m, k), a.dtype),
                            jax.ShapeDtypeStruct((m, n_tot_loc), a.dtype)),
                 in_specs=[any_spec()] * 2,
@@ -828,6 +830,7 @@ def ag_gemm_multi(a: jax.Array, bs,
     def body(xs, *ws):
         out = pl.pallas_call(
             kernel,
+            name="ag_gemm_vmem",
             out_shape=tuple(
                 [jax.ShapeDtypeStruct((m, k), a.dtype)] +
                 [jax.ShapeDtypeStruct((m, b.shape[1] // world), a.dtype)
@@ -1218,6 +1221,7 @@ def ag_swiglu(a: jax.Array, w_gate: jax.Array, w_up: jax.Array,
     def body(xs, wg, wu, *bs):
         out = pl.pallas_call(
             kernel,
+            name="ag_swiglu",
             out_shape=(jax.ShapeDtypeStruct((m, k), a.dtype),
                        jax.ShapeDtypeStruct((m, n_loc), a.dtype)),
             in_specs=[any_spec()] * 3

@@ -327,6 +327,7 @@ def all_reduce(x: jax.Array, ctx: AllReduceContext | None = None,
             part = jnp.pad(part, ((0, m_pad - m), (0, 0)))
         r = pl.pallas_call(
             kernel,
+            name=f"all_reduce_{method.value}",
             out_shape=jax.ShapeDtypeStruct((m_pad, n), x.dtype),
             in_specs=[pl.BlockSpec(memory_space=pltpu.VMEM)],
             out_specs=pl.BlockSpec(memory_space=pltpu.VMEM),

@@ -475,6 +475,7 @@ def gqa_fwd_batch_decode(q: jax.Array, cache_k: jax.Array,
         def body(qs, ks, vs, n):
             out, *_ = pl.pallas_call(
                 kernel,
+                name="flash_decode_einsum",
                 out_shape=(jax.ShapeDtypeStruct((b, hq, d), q.dtype),)
                 + _combine_shapes(world, b, hkv, groups, d),
                 in_specs=[pl.BlockSpec(memory_space=pltpu.VMEM)] * 3 +
@@ -516,6 +517,7 @@ def gqa_fwd_batch_decode(q: jax.Array, cache_k: jax.Array,
         table = jnp.zeros((1, 1), jnp.int32)
         out, *_ = pl.pallas_call(
             kernel,
+            name="flash_decode_tiled",
             out_shape=(jax.ShapeDtypeStruct((b, hq, d), q.dtype),)
             + _combine_shapes(world, b, hkv, groups, d),
             in_specs=[pl.BlockSpec(memory_space=pltpu.VMEM),
@@ -616,6 +618,7 @@ def gqa_fwd_batch_decode_paged(q: jax.Array, pool_k: jax.Array,
     def body(qs, n, table, ks, vs):
         out, *_ = pl.pallas_call(
             kernel,
+            name="flash_decode_paged",
             out_shape=(jax.ShapeDtypeStruct((b, hq, d), q.dtype),)
             + _combine_shapes(world, b, hkv, groups, d),
             in_specs=[pl.BlockSpec(memory_space=pltpu.VMEM),

@@ -32,7 +32,7 @@ from jax.experimental.pallas import tpu as pltpu
 from jax.sharding import Mesh, PartitionSpec as P
 
 import triton_dist_tpu.language as dl
-from triton_dist_tpu.resilience import resilient
+from triton_dist_tpu.resilience import count_fallback, resilient
 from triton_dist_tpu.ops.common import (
     DEFAULT_VMEM_BUDGET,
     HARD_FOOTPRINT_CAP,
@@ -735,7 +735,9 @@ def _entry(a, b, ctx, impl, all_gather_epilogue):
         # any resident B panel). Degrade to the XLA dot+psum rather than
         # fall through to the full-residency vmem kernel, whose scratch
         # would be infeasible at exactly these shapes (an infeasible
-        # config must never reach Mosaic).
+        # config must never reach Mosaic). The router never sees this
+        # branch, so it is counted here (per program build).
+        count_fallback(op_name, "no_ag_epilogue")
         return run_xla()
 
     if variant == "hbm":
@@ -752,6 +754,7 @@ def _entry(a, b, ctx, impl, all_gather_epilogue):
         def nb_body(xs, ws):
             out, *_ = pl.pallas_call(
                 kernel,
+                name=f"{op_name}_hbm",
                 out_shape=(
                     jax.ShapeDtypeStruct((out_rows, n), a.dtype),
                     jax.ShapeDtypeStruct((max(world - 1, 1), rows, n),
@@ -808,6 +811,7 @@ def _entry(a, b, ctx, impl, all_gather_epilogue):
         def hbm_body(xs, ws):
             out, *_ = pl.pallas_call(
                 kernel,
+                name=f"{op_name}_hbm_kt",
                 out_shape=(
                     jax.ShapeDtypeStruct((rows, n), a.dtype),
                     jax.ShapeDtypeStruct((max(world - 1, 1), rows, n),
@@ -864,6 +868,7 @@ def _entry(a, b, ctx, impl, all_gather_epilogue):
     def body(xs, ws):
         return pl.pallas_call(
             kernel,
+            name=f"{op_name}_vmem",
             out_shape=jax.ShapeDtypeStruct((out_rows, n), a.dtype),
             in_specs=[pl.BlockSpec(memory_space=pltpu.VMEM),
                       pl.BlockSpec(memory_space=pltpu.VMEM)],

@@ -435,6 +435,7 @@ def _ag_group_gemm_fused(x, w, expert_ids, num_experts, ctx):
         dest_all = lax.all_gather(dest, axis, tiled=True)
         _, cpad = pl.pallas_call(
             kernel,
+            name="ag_group_gemm",
             out_shape=(jax.ShapeDtypeStruct((world * m_pad, k), x.dtype),
                        jax.ShapeDtypeStruct((world * m_pad, n_loc),
                                             x.dtype)),

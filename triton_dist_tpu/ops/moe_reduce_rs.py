@@ -444,6 +444,7 @@ def _moe_rs_fused(act, w_down, expert_ids, weights, ctx):
 
         out, *_ = pl.pallas_call(
             kernel,
+            name="moe_reduce_rs",
             out_shape=(
                 jax.ShapeDtypeStruct((rows, h), act.dtype),
                 jax.ShapeDtypeStruct((max(world - 1, 1), rows, h),

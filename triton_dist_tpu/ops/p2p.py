@@ -117,6 +117,7 @@ def pp_shift(x: jax.Array, ctx: P2PContext | None = None, delta: int = 1,
     def body(xs):
         return pl.pallas_call(
             kernel,
+            name="p2p_shift",
             out_shape=jax.ShapeDtypeStruct(xs.shape, xs.dtype),
             in_specs=[pl.BlockSpec(memory_space=pltpu.VMEM)],
             out_specs=pl.BlockSpec(memory_space=pltpu.VMEM),

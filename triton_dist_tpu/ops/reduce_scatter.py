@@ -243,6 +243,7 @@ def reduce_scatter(x: jax.Array, ctx: ReduceScatterContext | None = None,
     def body(xs):
         return pl.pallas_call(
             kernel,
+            name=f"reduce_scatter_{method.value}",
             out_shape=jax.ShapeDtypeStruct((rows, n), x.dtype),
             in_specs=[pl.BlockSpec(memory_space=pltpu.VMEM)],
             out_specs=pl.BlockSpec(memory_space=pltpu.VMEM),

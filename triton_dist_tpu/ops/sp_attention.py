@@ -419,6 +419,7 @@ def sp_ag_attention_fused(q: jax.Array, k: jax.Array, v: jax.Array,
         qp = qp.transpose(1, 3, 0, 2, 4, 5).reshape(n_slabs, b, rows, d)
         out, *_ = pl.pallas_call(
             kernel,
+            name="sp_ag_attention",
             out_shape=(jax.ShapeDtypeStruct((n_slabs, b, rows, d),
                                             q.dtype),
                        jax.ShapeDtypeStruct((world, b, s_loc, hkv, d),

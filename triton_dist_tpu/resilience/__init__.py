@@ -42,6 +42,7 @@ from triton_dist_tpu.resilience.knownbad import (  # noqa: F401
 from triton_dist_tpu.resilience.router import (  # noqa: F401
     FallbackSpec,
     NonFiniteOutput,
+    count_fallback,
     decide,
     device_kind,
     force_fused,

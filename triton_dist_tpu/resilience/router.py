@@ -258,7 +258,12 @@ def decide(op: str, key: str) -> str | None:
     return None
 
 
-def _count_fallback(op: str, reason: str) -> None:
+def count_fallback(op: str, reason: str) -> None:
+    """One fallback of ``op`` to its XLA branch, by reason. Public for
+    the op entries that degrade by themselves, inside the fused branch
+    and so out of the router's sight (``gemm_ar`` without a feasible
+    all-gather epilogue): they count here at trace time, once per
+    program build, like ``obs.record_comm``."""
     obs.counter("resilience.fallbacks_total").inc()
     obs.counter(f"resilience.{op}.fallbacks_total").inc()
     obs.counter(f"resilience.{op}.fallback.{reason}").inc()
@@ -577,7 +582,7 @@ def resilient(op: str, *, fused_impls: tuple[str, ...] = ("pallas",),
                             f"resilience.{op}.policy_probes").inc()
                         return _guarded(op, key, config, call,
                                         bound, fallback_impl)
-                _count_fallback(op, reason)
+                count_fallback(op, reason)
                 if obs.enabled() and not _is_tracing(bound):
                     t0 = time.perf_counter()
                     out = call(fallback_impl)
@@ -652,7 +657,7 @@ def _guarded(op, key, config, call, bound, fallback_impl):
         reason = ("watchdog" if isinstance(e, CompileTimeout)
                   else "nonfinite" if isinstance(e, NonFiniteOutput)
                   else "error")
-        _count_fallback(op, reason)
+        count_fallback(op, reason)
         if rec:
             t1 = time.perf_counter()
             out = call(fallback_impl)

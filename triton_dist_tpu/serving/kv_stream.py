@@ -200,6 +200,7 @@ def symm_ship(x, mesh=None, axis: str = "tp", delta: int = 1,
     def body(xs):
         return pl.pallas_call(
             kernel,
+            name="kv_ship",
             out_shape=jax.ShapeDtypeStruct(xs.shape, xs.dtype),
             in_specs=[pl.BlockSpec(memory_space=pltpu.VMEM)],
             out_specs=pl.BlockSpec(memory_space=pltpu.VMEM),

@@ -726,7 +726,10 @@ class Scheduler:
             admits = []
             with self._cond:
                 while self._running and not self._queue and not rows:
-                    self._cond.wait()
+                    # No live row and nothing queued: the idleness
+                    # that is the traffic's, not the program's.
+                    with obs.span("serving.pump_wait"):
+                        self._cond.wait()
                 if not self._running:
                     break
                 free = sess.free_rows()
@@ -762,11 +765,17 @@ class Scheduler:
                 # wedged-replica failure class the router's dispatch
                 # deadline exists for).
                 hook()
-            t_iter0 = time.perf_counter()
-            prof = (self.devprof.iteration()
-                    if self.devprof is not None and (admits or rows)
+            # One turn of engine work (the cond wait above is idleness,
+            # not work); the admissions and the shared step nest inside
+            # it on this thread, so the turn's self time is the pump's
+            # own: token recording, retirement, waking handlers.
+            work = bool(admits or rows)
+            turn = (obs.span("serving.pump_iteration") if work
                     else contextlib.nullcontext())
-            with prof:
+            prof = (self.devprof.iteration()
+                    if self.devprof is not None and work
+                    else contextlib.nullcontext())
+            with turn, prof:
                 for row, req in admits:
                     admit(row, req)
                 for row in sorted(prefilling):  # one slice each, FIFO-ish
@@ -846,14 +855,11 @@ class Scheduler:
                                 break   # retired mid-burst (stop/EOS)
                             record(row, req, int(tok))
             occupancy.set(len(rows))
-            if admits or live or prefilling:
-                # Iteration time = this pump turn's engine work (the
-                # cond wait above is idleness, not work). Evaluation is
-                # rate-limited inside the tracker; a breach arms the
+            if work and self.slo is not None:
+                # The span's own reading (None when telemetry and
+                # tracing are both off: nothing was timed). Evaluation
+                # is rate-limited inside the tracker; a breach arms the
                 # flight recorder (obs.slo).
-                it_ms = (time.perf_counter() - t_iter0) * 1e3
-                obs.histogram("serving.pump_iteration_ms").observe(
-                    it_ms)
-                if self.slo is not None:
-                    self.slo.observe("pump", it_ms)
-                    self.slo.evaluate()
+                if turn.elapsed_ms is not None:
+                    self.slo.observe("pump", turn.elapsed_ms)
+                self.slo.evaluate()
