@@ -654,6 +654,51 @@ def test_admission_upload_failure_releases_blocks(paged_tiny,
         sched.stop()
 
 
+def test_admission_failure_after_dispatch_restarts_session(paged_tiny,
+                                                          monkeypatch):
+    """The admission programs DONATE the session's caches, so one that
+    fails after dispatch (device OOM, a runtime error surfacing at the
+    first token) takes every row's K/V with it. That is the death of
+    the session, not of one request: the admitted request and both
+    occupants fail with an error that names the lost cache and its
+    culprit, ``serving.pump_errors`` counts it, a fresh session serves
+    the next request bit-identically, and no block stays stranded (the
+    autouse leak audit). The silent version would degrade one request
+    and let the next shared step die on "Array has been deleted"."""
+    from triton_dist_tpu import obs
+    model, params = paged_tiny
+    eng = _paged_engine(model, batch=3)
+    reg = obs.enable(obs.Registry())
+    sched = Scheduler(eng, params).start()
+    try:
+        golden = _solo_paged_golden(model, params, [1, 2, 3], 2)
+        assert sched.submit([1, 2, 3], 2).result(timeout=180) == golden
+        real, calls = eng._admit, {"n": 0}
+
+        def admit_then_fail(*args):
+            out = real(*args)           # the program runs and donates
+            calls["n"] += 1
+            if calls["n"] == 3:
+                raise RuntimeError("injected failure after dispatch")
+            return out
+
+        monkeypatch.setattr(eng, "_admit", admit_then_fail)
+        # One pump turn admits all three: rows 0 and 1 are live when
+        # the third admission fails.
+        reqs = [sched.submit([7 + i, 8, 9], 48) for i in range(3)]
+        for r in reqs:
+            with pytest.raises(RuntimeError,
+                               match="KV cache was lost.*injected"):
+                r.result(timeout=180)
+        counters = reg.snapshot()["counters"]
+        assert counters["serving.pump_errors"] == 1
+        assert counters.get("serving.admit_errors", 0) == 0
+        assert sched.submit([1, 2, 3], 2).result(timeout=180) == golden
+    finally:
+        sched.stop()
+        obs.disable()
+
+
 def test_paged_prefix_metrics_and_report(paged_tiny):
     """ISSUE 6 acceptance: serving.prefix_hit_rate /
     serving.prefill_tokens_saved and the kv.* block gauges are visible

@@ -24,7 +24,8 @@ import numpy as np
 
 from triton_dist_tpu import obs
 from triton_dist_tpu.obs import trace as _trace
-from triton_dist_tpu.models.kv_cache import KVCacheManager
+from triton_dist_tpu.models.kv_cache import (
+    KVCacheLost, KVCacheManager, jit_rewriting_caches)
 
 
 def sample_token(logits: jax.Array, key: jax.Array | None = None,
@@ -366,7 +367,7 @@ class Engine:
     def _build_decode_step(self, path: str = "plain"):
         fwd = self._decode_forward(path)
 
-        @jax.jit
+        @jit_rewriting_caches
         def step(params, caches, token, offset, key, kv_start, table):
             logits, caches = fwd(params, caches, token, offset,
                                  kv_start, table)
@@ -381,7 +382,7 @@ class Engine:
         keep emitting their stop token."""
         fwd = self._decode_forward(path)
 
-        @jax.jit
+        @jit_rewriting_caches
         def step(params, caches, token, offset, key, done, stop, kv_start,
                  table):
             logits, caches = fwd(params, caches, token, offset,
@@ -602,7 +603,7 @@ class Engine:
         mask exposes it."""
         model, mode = self.model, self.decode_mode
 
-        @jax.jit
+        @jit_rewriting_caches
         def step(params, caches, tokens, offsets, table):
             logits, caches = model.forward(
                 params, tokens, caches, offsets, mode=mode,
@@ -618,7 +619,7 @@ class Engine:
         token and do not advance). One compiled program per token."""
         model, mode = self.model, self.decode_mode
 
-        @jax.jit
+        @jit_rewriting_caches
         def step(params, caches, token, offsets, key, done, table):
             logits, caches = model.forward(
                 params, token[:, None], caches, offsets, mode=mode,
@@ -639,7 +640,7 @@ class Engine:
         between the two steps mid-request (decode_path="auto")."""
         fwd = self._mega_forward
 
-        @jax.jit
+        @jit_rewriting_caches
         def step(params, caches, token, offsets, key, done, table):
             logits, caches = fwd(params, caches, token, offsets, None,
                                  table)
@@ -664,7 +665,7 @@ class Engine:
         argument that makes stale-lane reuse safe."""
         model, mode = self.model, self.prefill_mode
 
-        @jax.jit
+        @jit_rewriting_caches
         def admit(params, caches, ids, length, row, key):
             lb = ids.shape[1]                       # bucketed length
             small = [(jnp.zeros((1, lb) + ck.shape[2:], ck.dtype),
@@ -689,7 +690,7 @@ class Engine:
         the pool IS the row's storage (vLLM-style)."""
         model, mode = self.model, self.prefill_mode
 
-        @jax.jit
+        @jit_rewriting_caches
         def admit(params, pools, ids, length, table_row, key):
             logits, pools = model.forward(params, ids, pools, 0,
                                           mode=mode,
@@ -713,7 +714,7 @@ class Engine:
         them (the standard pad-slot safety argument)."""
         model, mode = self.model, self.prefill_mode
 
-        @jax.jit
+        @jit_rewriting_caches
         def admit(params, pools, ids, start, length, table_row, key):
             logits, pools = model.forward(params, ids, pools, start,
                                           mode=mode,
@@ -735,7 +736,7 @@ class Engine:
         never stalls the rows already decoding (docs/serving.md)."""
         model, mode = self.model, self.prefill_mode
 
-        @jax.jit
+        @jit_rewriting_caches
         def chunk_step(params, small, ids, offset):
             return model.forward(params, ids, small, offset, mode=mode)
         return chunk_step
@@ -748,7 +749,7 @@ class Engine:
         causally invisible and overwritten before any mask exposes
         them)."""
 
-        @jax.jit
+        @functools.partial(jit_rewriting_caches, cache_argnum=0)
         def finish(caches, small, logits, idx, row, key):
             last = jax.lax.dynamic_slice_in_dim(logits, idx, 1,
                                                 axis=1)[:, 0]
@@ -1070,11 +1071,31 @@ class StreamSession:
         args = {"row": row, "prompt_len": n, "bucket": lb}
         with obs.span("engine.stream_admission", args=args):
             prompt = [int(t) for t in prompt]
-            if chunked:
-                return self._start_chunked(row, prompt, int(chunk), lb,
-                                           gen_budget=gen_budget)
-            return self._admit_whole(row, prompt, lb, args,
-                                     gen_budget=gen_budget)
+            try:
+                if chunked:
+                    return self._start_chunked(row, prompt, int(chunk),
+                                               lb, gen_budget=gen_budget)
+                return self._admit_whole(row, prompt, lb, args,
+                                         gen_budget=gen_budget)
+            except Exception as e:
+                self._check_caches(e)
+                raise
+
+    def _check_caches(self, cause: BaseException) -> None:
+        """Called on a failed admission. The admission programs donate
+        the session's caches, so one that fails after dispatch (device
+        OOM, a runtime error surfacing at the first token) has deleted
+        every row's K/V, not only the admitted row's: say so, and name
+        the culprit, instead of letting the next shared step die on
+        "Array has been deleted". A failure before dispatch (tracing,
+        a host-side upload, a shape error) consumed nothing and
+        degrades its one request as before."""
+        if any(leaf.is_deleted() for leaf in jax.tree.leaves(self.caches)):
+            raise KVCacheLost(
+                "the session's KV cache was lost: an admission program "
+                "failed after its caches were donated "
+                f"({type(cause).__name__}: {cause}); every row's K/V "
+                "went with it, the session must be reopened") from cause
 
     def _bucket(self, n: int) -> int:
         """Power-of-two prompt bucket rounded up to a multiple of the
@@ -1091,10 +1112,15 @@ class StreamSession:
                                      sub)
         padded = prompt + [0] * (lb - len(prompt))
         ids = jnp.asarray([padded], jnp.int32)
-        first, self.caches = eng._admit(
+        first, caches = eng._admit(
             self.params, self.caches, ids, jnp.int32(len(prompt)),
             jnp.int32(row), sub)
+        # Rebind only once the first token materializes: a program that
+        # fails after dispatch then leaves self.caches on the donated
+        # (deleted) leaves, which is how _check_caches tells it from a
+        # failure that consumed nothing.
         first = int(first)
+        self.caches = caches
         self.admit_info = {"cached": 0}
         self._count_admitted(len(prompt), lb)
         self._mark_admitted(row, len(prompt))
@@ -1141,20 +1167,22 @@ class StreamSession:
                                   jnp.int32)
                 if eng._admit_prefix is None:
                     eng._admit_prefix = eng._build_admit_paged_prefix()
-                first, self.caches = eng._admit_prefix(
+                first, caches = eng._admit_prefix(
                     self.params, self.caches, ids, jnp.int32(cached),
                     jnp.int32(len(suffix)),
                     self.cur_table[:, row:row + 1], sub)
             else:
                 ids = jnp.asarray([prompt + [0] * (lb - L)], jnp.int32)
-                first, self.caches = eng._admit(
+                first, caches = eng._admit(
                     self.params, self.caches, ids, jnp.int32(L),
                     self.cur_table[:, row:row + 1], sub)
             # Materialize HERE: jit returns futures, so an async
             # runtime failure (device OOM, comm error) would otherwise
             # surface past the rollback window below and leave a
-            # zombie live row holding its blocks forever.
+            # zombie live row holding its blocks forever. The caches
+            # are rebound only after it (see _admit_whole).
             first = int(first)
+            self.caches = caches
         except Exception:
             # The program never ran to completion: hand the row's
             # blocks straight back (a stranded allocation is a slow
@@ -1220,7 +1248,11 @@ class StreamSession:
         with obs.span("engine.stream_admission",
                       args={"row": row, "prompt_len": st["len"],
                             "bucket": st["ids"].shape[1]}):
-            return self._prefill_slice(row)
+            try:
+                return self._prefill_slice(row)
+            except Exception as e:
+                self._check_caches(e)
+                raise
 
     def _prefill_slice(self, row: int):
         """One slice, inside the caller's admission span (the first
@@ -1236,10 +1268,11 @@ class StreamSession:
             return None
         del self._pending[row]
         idx = st["len"] - 1 - (st["pos"] - c)   # last real token's index
-        first, self.caches = eng._admit_finish(  # in the final chunk
+        first, caches = eng._admit_finish(      # in the final chunk
             self.caches, st["small"], logits, jnp.int32(idx),
             jnp.int32(row), st["key"])
         first = int(first)
+        self.caches = caches
         self.admit_info = {"cached": 0}
         self._count_admitted(st["len"], st["ids"].shape[1])
         self._mark_admitted(row, st["len"])

@@ -13,11 +13,61 @@ as the reference, which caches after the column-parallel KV projection);
 
 from __future__ import annotations
 
+import collections
+import functools
+
 import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from triton_dist_tpu import obs
+
+
+class KVCacheLost(RuntimeError):
+    """A program that had been given (donated) a session's KV caches
+    failed after dispatch: the buffers are gone, and every row's K/V
+    with them. The session is dead; its driver reopens one."""
+
+
+def jit_rewriting_caches(fn, cache_argnum: int = 1):
+    """``jax.jit`` for a program that takes the KV caches as argument
+    ``cache_argnum`` and returns them rewritten.
+
+    The caches are DONATED: every caller rebinds its caches to the
+    program's output, so the input buffers are garbage the moment the
+    call returns, and XLA may alias them to the output and write the
+    new positions in place instead of copying every leaf first (one
+    whole-cache copy per decode step and per admission otherwise). The
+    caller's old leaves are deleted by the call — a program that fails
+    after dispatch takes the caches with it (``KVCacheLost``).
+
+    A donated leaf only aliases an output of its own shape and dtype
+    (JAX's own rule), so that is checked where it is free: while
+    tracing, once per compile. A program whose caches changed shape or
+    dtype on the way out counts under
+    ``engine.cache_donation_fallbacks`` and runs (copying): the counter
+    reads 0 while the mechanism is engaged, and the benchmark counts
+    any ``fallback`` counter against ``correct``."""
+    @functools.wraps(fn)
+    def program(*args):
+        out = fn(*args)
+        spare = collections.Counter(
+            (x.shape, x.dtype) for x in jax.tree.leaves(out))
+        spare.subtract(
+            (x.shape, x.dtype) for x in jax.tree.leaves(args[cache_argnum]))
+        if min(spare.values()) < 0:
+            obs.counter("engine.cache_donation_fallbacks").inc()
+        return out
+    return jax.jit(program, donate_argnums=(cache_argnum,))
+
+
+def _zero_leaves(shape, dtype, sharding, num_layers: int):
+    """[(k, v)] * L of zeros, every leaf a buffer of its own: the
+    programs that rewrite the caches donate them, and one buffer under
+    two leaves cannot be donated twice in one call."""
+    return [(jnp.zeros(shape, dtype, device=sharding),
+             jnp.zeros(shape, dtype, device=sharding))
+            for _ in range(num_layers)]
 
 
 class KVCacheManager:
@@ -41,12 +91,8 @@ class KVCacheManager:
     def init(self):
         """Allocate the cache pytree: [(k, v)] * L."""
         shape = (self.batch, self.max_seq, self.num_kv_heads, self.head_dim)
-        z = jnp.zeros(shape, self.dtype)
-        return [
-            (jax.device_put(z, self.sharding),
-             jax.device_put(z, self.sharding))
-            for _ in range(self.num_layers)
-        ]
+        return _zero_leaves(shape, self.dtype, self.sharding,
+                            self.num_layers)
 
     def inc_offset(self, n: int) -> int:
         """Advance the write position (reference ``inc_offset``)."""
@@ -648,10 +694,9 @@ class PagedKVCacheManager:
         ``slots_per_dev``."""
         shape = (self.world * self.phys_slots_per_dev, self.page_size,
                  self.num_kv_heads, self.head_dim)
-        sh = NamedSharding(self.mesh, P(self.axis))
-        z = jax.device_put(jnp.zeros(shape, self.dtype), sh)
-        # arrays are immutable — one zero transfer shared by all refs
-        return [(z, z) for _ in range(self.num_layers)]
+        return _zero_leaves(shape, self.dtype,
+                            NamedSharding(self.mesh, P(self.axis)),
+                            self.num_layers)
 
     @staticmethod
     def _addr(offset, page_size: int, n_pages: int):

@@ -44,6 +44,8 @@ import jax.numpy as jnp
 import numpy as np
 
 from triton_dist_tpu import obs
+from triton_dist_tpu.models.kv_cache import (
+    KVCacheManager, jit_rewriting_caches)
 
 __all__ = ["DEFAULT_K", "SpecConfig", "NGramDrafter", "ModelDrafter",
            "SpecState", "accept_greedy", "draft_model_from_preset"]
@@ -201,7 +203,6 @@ class ModelDrafter:
 
     def __init__(self, model, params, k: int, batch: int, max_seq: int,
                  mode: str = "xla_ar"):
-        from triton_dist_tpu.models.kv_cache import KVCacheManager
         self.model, self.params = model, params
         self.k = int(k)
         self.mode = mode
@@ -222,7 +223,7 @@ class ModelDrafter:
     def _build_step(self):
         model, mode = self.model, self.mode
 
-        @jax.jit
+        @jit_rewriting_caches
         def step(params, caches, token, offsets):
             logits, caches = model.forward(params, token[:, None],
                                            caches, offsets, mode=mode)
@@ -233,7 +234,7 @@ class ModelDrafter:
     def _build_admit(self):
         model, mode = self.model, self.mode
 
-        @jax.jit
+        @jit_rewriting_caches
         def admit(params, caches, ids, row):
             lb = ids.shape[1]
             small = [(jnp.zeros((1, lb) + ck.shape[2:], ck.dtype),

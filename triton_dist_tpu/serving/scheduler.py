@@ -103,6 +103,7 @@ import time
 import warnings
 
 from triton_dist_tpu import obs
+from triton_dist_tpu.models.kv_cache import KVCacheLost
 from triton_dist_tpu.obs import attrib, devprof, history, slo, trace
 
 __all__ = ["DEFAULT_MAX_WAITING", "Draining", "QueueFull", "Request",
@@ -615,6 +616,23 @@ class Scheduler:
         prefilling: set[int] = set()         # rows mid-chunked-prefill
         occupancy = obs.gauge("serving.batch_occupancy")
 
+        def restart(e: Exception, *admitting: Request) -> None:
+            """The session died (the shared step failed, or an
+            admission lost the caches it had donated): every occupant
+            degrades, ``admitting`` (a request not yet in ``rows``)
+            with them, and a fresh session opens; the scheduler keeps
+            serving."""
+            nonlocal sess
+            obs.counter("serving.pump_errors").inc()
+            for req in [*rows.values(), *admitting]:
+                self._fail(req, e)
+            rows.clear()
+            budgets.clear()
+            prefilling.clear()
+            sess = self.engine.stream_session(self.params)
+            self._session = sess
+            occupancy.set(0)
+
         def record(row: int, req: Request, tok: int) -> None:
             req.tokens.append(tok)
             if req.t_first is None:
@@ -699,6 +717,11 @@ class Scheduler:
                         first = sess.prefill_into_row(
                             row, req.prompt, chunk=self.prefill_chunk,
                             gen_budget=req.gen_len)
+            except KVCacheLost as e:
+                # The admission failed AFTER dispatch and took every
+                # row's K/V with it: not one request's failure.
+                restart(e, req)
+                return
             except Exception as e:  # noqa: BLE001 — degrade THIS request
                 sess.cancel_prefill(row)
                 obs.counter("serving.admit_errors").inc()
@@ -783,6 +806,9 @@ class Scheduler:
                     try:
                         with self._bind(req):
                             first = sess.prefill_step(row)
+                    except KVCacheLost as e:
+                        restart(e)
+                        break
                     except Exception as e:  # noqa: BLE001
                         sess.cancel_prefill(row)
                         prefilling.discard(row)
@@ -827,18 +853,9 @@ class Scheduler:
                             # 1..k+1 from a speculative verify step.
                             bursts = sess.decode_burst()
                     except Exception as e:  # noqa: BLE001
-                        # The SHARED step died: every occupant degrades
-                        # (the cache state is suspect) and the session
-                        # restarts fresh; the scheduler keeps serving.
-                        obs.counter("serving.pump_errors").inc()
-                        for _, req in list(rows.items()):
-                            self._fail(req, e)
-                        rows.clear()
-                        budgets.clear()
-                        prefilling.clear()
-                        sess = self.engine.stream_session(self.params)
-                        self._session = sess
-                        occupancy.set(0)
+                        # The SHARED step died: the cache state is
+                        # suspect (and donated away).
+                        restart(e)
                         continue
                     bt = sess.last_burst_timing
                     for row, req in live:
