@@ -189,7 +189,11 @@ def test_decode_step_and_admission_write_the_caches_in_place(
     donation: the chip's compiler aliases every byte of the caches to
     the output and the entry computation holds no ``copy`` of a
     cache-shaped array. Without donation the same step read alias 0
-    and one whole-leaf copy per leaf (3.5 GiB a call at 28 layers)."""
+    and one whole-leaf copy per leaf (3.5 GiB a call at 28 layers).
+    The admission also carries the session's small state (last
+    tokens, offsets, the sampling key) in and out and seats its row
+    in-graph; a greedy engine, the benchmark's, traces no key split
+    into either program (ISSUE 31)."""
     import re
     from triton_dist_tpu.models import AutoLLM, Engine, ModelConfig
     # The engine builds its own kernel contexts, which interpret where
@@ -216,15 +220,23 @@ def test_decode_step_and_admission_write_the_caches_in_place(
     caches = [(sds(leaf), sds(leaf)) for _ in range(layers)]
     cache_bytes = 2 * layers * int(np.prod(leaf)) * 2
     key, i32 = sds((2,), jnp.uint32), jnp.int32
+    token, offsets = sds((8,), i32), sds((8,), i32)
     programs = {
         "step": eng._build_stream_step().lower(
-            params, caches, sds((8,), i32), sds((8,), i32), key,
-            sds((8,), jnp.bool_), None),
+            params, caches, token, offsets, key, sds((8,), jnp.bool_),
+            None),
         "admit": eng._build_admit().lower(
             params, caches, sds((1, 128), i32), sds((), i32),
-            sds((), i32), key)}
+            sds((), i32), token, offsets, key)}
     for name, lowered in programs.items():
         compiled = lowered.compile()
+        # step: (tokens, caches, offsets); admit: (first token, caches,
+        # tokens and offsets with the row seated, the next key).
+        out = compiled.out_info
+        assert [(x.shape, x.dtype) for x in (out[0], *out[2:])] == (
+            [((8,), i32), ((8,), i32)] if name == "step" else
+            [((), i32), ((8,), i32), ((8,), i32), ((2,), jnp.uint32)]), name
+        assert "threefry" not in lowered.as_text(), name
         assert compiled.memory_analysis().alias_size_in_bytes \
             == cache_bytes, name
         text = compiled.as_text()

@@ -15,7 +15,6 @@ triton_dist_gemm_ar (replicated small-batch decode).
 
 from __future__ import annotations
 
-import functools
 import time
 
 import jax
@@ -58,6 +57,16 @@ def sample_token(logits: jax.Array, key: jax.Array | None = None,
                 jnp.where(keep, s, jnp.inf), axis=-1)[:, None]
             logits = jnp.where(logits >= kept_min, logits, -jnp.inf)
     return jax.random.categorical(key, logits, axis=-1).astype(jnp.int32)
+
+
+def _seat_row(token, offsets, row, first, length):
+    """Row ``row`` starts decoding: ``first`` is its last token,
+    ``length`` its write offset. Inlined into every admission program;
+    a program of its own only where none ran (``adopt_row``)."""
+    return token.at[row].set(first), offsets.at[row].set(length)
+
+
+_seat_row = jax.jit(_seat_row, inline=True)
 
 
 #: The auto policy's prior when no measurement exists: the only silicon
@@ -613,6 +622,32 @@ class Engine:
                     caches)
         return step
 
+    # The admission programs below carry the session's small state
+    # (sampling key, last token and write offset per row) through the
+    # device: each takes it, seats its row in-graph and hands it back,
+    # so the thread that drives a session makes ONE dispatch per
+    # admission and no eager op around it (ISSUE 31).
+    def _draw_key(self, key):
+        """``(next key, this draw's key)``, traced inside the program
+        that samples. Only a sampling engine splits (``key, sub =
+        split(key)``, the sequence the host-side splits produced);
+        greedy traces no split and hands the key back as it came."""
+        if self.temperature > 0.0:
+            key, sub = jax.random.split(key)
+            return key, sub
+        return key, None
+
+    def _first_token(self, logits, idx, caches, token, offsets, key, row,
+                     length):
+        """Tail of the admission programs: sample the first token at
+        position ``idx`` of ``logits`` and seat the row."""
+        last = jax.lax.dynamic_slice_in_dim(logits, idx, 1, axis=1)[:, 0]
+        key, sub = self._draw_key(key)
+        first = sample_token(last, sub, self.temperature, self.top_k,
+                             self.top_p)[0]
+        token, offsets = _seat_row(token, offsets, row, first, length)
+        return first, caches, token, offsets, key
+
     def _build_stream_step(self):
         """One decode step with PER-ROW write offsets: each live row
         decodes at its own cache position (frozen rows re-emit their
@@ -666,39 +701,37 @@ class Engine:
         model, mode = self.model, self.prefill_mode
 
         @jit_rewriting_caches
-        def admit(params, caches, ids, length, row, key):
+        def admit(params, caches, ids, length, row, token, offsets, key):
             lb = ids.shape[1]                       # bucketed length
             small = [(jnp.zeros((1, lb) + ck.shape[2:], ck.dtype),
                       jnp.zeros((1, lb) + cv.shape[2:], cv.dtype))
                      for ck, cv in caches]
             logits, small = model.forward(params, ids, small, 0, mode=mode)
-            last = jax.lax.dynamic_slice_in_dim(logits, length - 1, 1,
-                                                axis=1)[:, 0]
-            first = sample_token(last, key, self.temperature, self.top_k, self.top_p)
             new_caches = []
             for (ck, cv), (sk, sv) in zip(caches, small):
                 ck = jax.lax.dynamic_update_slice(ck, sk, (row, 0, 0, 0))
                 cv = jax.lax.dynamic_update_slice(cv, sv, (row, 0, 0, 0))
                 new_caches.append((ck, cv))
-            return first[0], new_caches
+            return self._first_token(logits, length - 1, new_caches, token,
+                                     offsets, key, row, length)
         return admit
 
     def _build_admit_paged(self):
         """Paged admission: the batch-1 prefill scatters straight into
         the freshly-allocated pages of the admitted row (its
-        (w, 1, n_pages) table slice) — no scratch cache, no row copy;
-        the pool IS the row's storage (vLLM-style)."""
+        (w, 1, n_pages) slice of ``table``, cut in-graph) — no scratch
+        cache, no row copy; the pool IS the row's storage (vLLM-style)."""
         model, mode = self.model, self.prefill_mode
 
         @jit_rewriting_caches
-        def admit(params, pools, ids, length, table_row, key):
-            logits, pools = model.forward(params, ids, pools, 0,
-                                          mode=mode,
-                                          block_table=table_row)
-            last = jax.lax.dynamic_slice_in_dim(logits, length - 1, 1,
-                                                axis=1)[:, 0]
-            first = sample_token(last, key, self.temperature, self.top_k, self.top_p)
-            return first[0], pools
+        def admit(params, pools, ids, length, row, table, token, offsets,
+                  key):
+            logits, pools = model.forward(
+                params, ids, pools, 0, mode=mode,
+                block_table=jax.lax.dynamic_slice_in_dim(table, row, 1,
+                                                         axis=1))
+            return self._first_token(logits, length - 1, pools, token,
+                                     offsets, key, row, length)
         return admit
 
     def _build_admit_paged_prefix(self):
@@ -715,15 +748,14 @@ class Engine:
         model, mode = self.model, self.prefill_mode
 
         @jit_rewriting_caches
-        def admit(params, pools, ids, start, length, table_row, key):
-            logits, pools = model.forward(params, ids, pools, start,
-                                          mode=mode,
-                                          block_table=table_row)
-            last = jax.lax.dynamic_slice_in_dim(logits, length - 1, 1,
-                                                axis=1)[:, 0]
-            first = sample_token(last, key, self.temperature,
-                                 self.top_k, self.top_p)
-            return first[0], pools
+        def admit(params, pools, ids, start, length, row, table, token,
+                  offsets, key):
+            logits, pools = model.forward(
+                params, ids, pools, start, mode=mode,
+                block_table=jax.lax.dynamic_slice_in_dim(table, row, 1,
+                                                         axis=1))
+            return self._first_token(logits, length - 1, pools, token,
+                                     offsets, key, row, start + length)
         return admit
 
     def _build_admit_chunk(self):
@@ -749,18 +781,16 @@ class Engine:
         causally invisible and overwritten before any mask exposes
         them)."""
 
-        @functools.partial(jit_rewriting_caches, cache_argnum=0)
-        def finish(caches, small, logits, idx, row, key):
-            last = jax.lax.dynamic_slice_in_dim(logits, idx, 1,
-                                                axis=1)[:, 0]
-            first = sample_token(last, key, self.temperature, self.top_k,
-                                 self.top_p)
+        @jit_rewriting_caches
+        def finish(small, caches, logits, idx, length, row, token, offsets,
+                   key):
             new_caches = []
             for (ck, cv), (sk, sv) in zip(caches, small):
                 ck = jax.lax.dynamic_update_slice(ck, sk, (row, 0, 0, 0))
                 cv = jax.lax.dynamic_update_slice(cv, sv, (row, 0, 0, 0))
                 new_caches.append((ck, cv))
-            return first[0], new_caches
+            return self._first_token(logits, idx, new_caches, token,
+                                     offsets, key, row, length)
         return finish
 
     @staticmethod
@@ -982,8 +1012,11 @@ class StreamSession:
         if engine._admit is None:
             engine._admit = (engine._build_admit_paged() if engine.paged
                              else engine._build_admit())
-        self.token = jnp.zeros((b,), jnp.int32)
-        self.offsets = jnp.zeros((b,), jnp.int32)
+        # Last token and write offset per row: handed to every stream
+        # program and rebound to what it returns, never touched by an
+        # eager op in between (host values until the first program).
+        self.token = np.zeros((b,), np.int32)
+        self.offsets = np.zeros((b,), np.int32)
         self.live = [False] * b
         self._decode_kind: str | None = None  # decided path, unconsumed
         self._host_off = [0] * b     # host shadow of per-row offsets
@@ -1103,33 +1136,50 @@ class StreamSession:
         lb = self.engine._bucket_len(n)
         return -(-lb // self._bucket_quantum) * self._bucket_quantum
 
+    @staticmethod
+    def _padded_ids(tokens, lb: int) -> np.ndarray:
+        """The prompt as the (1, lb) int32 host buffer an admission
+        program takes: zero-padded on the right to its bucket."""
+        ids = np.zeros((1, lb), np.int32)
+        ids[0, :len(tokens)] = tokens
+        return ids
+
+    def _run_admission(self, program, head, *inputs) -> int:
+        """THE dispatch of an admission: ``program(head, caches,
+        *inputs, token, offsets, key)`` seats the row in-graph (its
+        first token, its write offset, the next sampling key), so what
+        the host adds is NumPy values that upload with the call.
+        Returns the first token.
+
+        Everything is rebound only once the first token materializes: a
+        program that fails after dispatch then leaves ``self.caches`` on
+        the donated (deleted) leaves, which is how ``_check_caches``
+        tells it from a failure that consumed nothing, and a paged
+        caller's rollback still sees the row un-admitted."""
+        eng = self.engine
+        first, caches, token, offsets, key = program(
+            head, self.caches, *inputs, self.token, self.offsets, eng.key)
+        first = int(first)
+        self.caches, self.token, self.offsets, eng.key = (
+            caches, token, offsets, key)
+        return first
+
     def _admit_whole(self, row: int, prompt: list, lb: int, args: dict,
                      gen_budget: int | None = None) -> int:
         eng = self.engine
-        eng.key, sub = jax.random.split(eng.key)
         if eng.paged:
-            return self._admit_paged(row, prompt, lb, args, gen_budget,
-                                     sub)
-        padded = prompt + [0] * (lb - len(prompt))
-        ids = jnp.asarray([padded], jnp.int32)
-        first, caches = eng._admit(
-            self.params, self.caches, ids, jnp.int32(len(prompt)),
-            jnp.int32(row), sub)
-        # Rebind only once the first token materializes: a program that
-        # fails after dispatch then leaves self.caches on the donated
-        # (deleted) leaves, which is how _check_caches tells it from a
-        # failure that consumed nothing.
-        first = int(first)
-        self.caches = caches
+            return self._admit_paged(row, prompt, lb, args, gen_budget)
+        first = self._run_admission(
+            eng._admit, self.params, self._padded_ids(prompt, lb),
+            np.int32(len(prompt)), np.int32(row))
         self.admit_info = {"cached": 0}
         self._count_admitted(len(prompt), lb)
         self._mark_admitted(row, len(prompt))
-        self.token = self.token.at[row].set(first)
         self._spec_start(row, prompt, first, gen_budget)
         return first
 
     def _admit_paged(self, row: int, prompt: list, lb: int, args: dict,
-                     gen_budget: int | None, sub) -> int:
+                     gen_budget: int | None) -> int:
         """Block-granular paged admission with cross-request prefix
         reuse: map cached prefix blocks into the row's lanes, then run
         only the SUFFIX through the prefill (the whole prompt when the
@@ -1158,31 +1208,26 @@ class StreamSession:
             # raise (device OOM), and a failure after admit_row must
             # hand the row's blocks back like any program failure.
             self.cur_table = kv.block_table()
+            # _run_admission materializes the first token in HERE: jit
+            # returns futures, so an async runtime failure (device OOM,
+            # comm error) would otherwise surface past the rollback
+            # window and leave a zombie live row holding its blocks
+            # forever.
             if cached:
                 suffix = prompt[cached:]
                 # Only the uncached suffix runs: the span's begin event
                 # (which holds ``args``) says the bucket that ran.
                 lb = args["bucket"] = self._bucket(len(suffix))
-                ids = jnp.asarray([suffix + [0] * (lb - len(suffix))],
-                                  jnp.int32)
                 if eng._admit_prefix is None:
                     eng._admit_prefix = eng._build_admit_paged_prefix()
-                first, caches = eng._admit_prefix(
-                    self.params, self.caches, ids, jnp.int32(cached),
-                    jnp.int32(len(suffix)),
-                    self.cur_table[:, row:row + 1], sub)
+                first = self._run_admission(
+                    eng._admit_prefix, self.params,
+                    self._padded_ids(suffix, lb), np.int32(cached),
+                    np.int32(len(suffix)), np.int32(row), self.cur_table)
             else:
-                ids = jnp.asarray([prompt + [0] * (lb - L)], jnp.int32)
-                first, caches = eng._admit(
-                    self.params, self.caches, ids, jnp.int32(L),
-                    self.cur_table[:, row:row + 1], sub)
-            # Materialize HERE: jit returns futures, so an async
-            # runtime failure (device OOM, comm error) would otherwise
-            # surface past the rollback window below and leave a
-            # zombie live row holding its blocks forever. The caches
-            # are rebound only after it (see _admit_whole).
-            first = int(first)
-            self.caches = caches
+                first = self._run_admission(
+                    eng._admit, self.params, self._padded_ids(prompt, lb),
+                    np.int32(L), np.int32(row), self.cur_table)
         except Exception:
             # The program never ran to completion: hand the row's
             # blocks straight back (a stranded allocation is a slow
@@ -1195,7 +1240,6 @@ class StreamSession:
         self.admit_info = {"cached": cached}
         self._count_admitted(L - cached, lb)
         self._mark_admitted(row, L)
-        self.token = self.token.at[row].set(first)
         self._spec_start(row, prompt, first, gen_budget)
         return first
 
@@ -1230,11 +1274,9 @@ class StreamSession:
         if eng._admit_chunk is None:
             eng._admit_chunk = eng._build_admit_chunk()
             eng._admit_finish = eng._build_admit_finish()
-        padded = prompt + [0] * (lb - len(prompt))
-        eng.key, sub = jax.random.split(eng.key)
         self._pending[row] = {
-            "ids": np.asarray([padded], np.int32), "len": len(prompt),
-            "chunk": chunk, "pos": 0, "key": sub, "budget": gen_budget,
+            "ids": self._padded_ids(prompt, lb), "len": len(prompt),
+            "chunk": chunk, "pos": 0, "budget": gen_budget,
             "small": [(jnp.zeros((1, lb) + ck.shape[2:], ck.dtype),
                        jnp.zeros((1, lb) + cv.shape[2:], cv.dtype))
                       for ck, cv in self.caches]}
@@ -1260,23 +1302,20 @@ class StreamSession:
         eng = self.engine
         st = self._pending[row]
         c = st["chunk"]
-        ids_chunk = jnp.asarray(st["ids"][:, st["pos"]:st["pos"] + c])
         logits, st["small"] = eng._admit_chunk(
-            self.params, st["small"], ids_chunk, jnp.int32(st["pos"]))
+            self.params, st["small"], st["ids"][:, st["pos"]:st["pos"] + c],
+            np.int32(st["pos"]))
         st["pos"] += c
         if st["pos"] < st["ids"].shape[1]:
             return None
         del self._pending[row]
         idx = st["len"] - 1 - (st["pos"] - c)   # last real token's index
-        first, caches = eng._admit_finish(      # in the final chunk
-            self.caches, st["small"], logits, jnp.int32(idx),
-            jnp.int32(row), st["key"])
-        first = int(first)
-        self.caches = caches
+        first = self._run_admission(            # in the final chunk
+            eng._admit_finish, st["small"], logits, np.int32(idx),
+            np.int32(st["len"]), np.int32(row))
         self.admit_info = {"cached": 0}
         self._count_admitted(st["len"], st["ids"].shape[1])
         self._mark_admitted(row, st["len"])
-        self.token = self.token.at[row].set(first)
         self._spec_start(row, st["ids"][0, :st["len"]].tolist(), first,
                          st.get("budget"))
         return first
@@ -1389,8 +1428,12 @@ class StreamSession:
         kv.register_prefix(row, prompt, hashes=hashes)
         self._note_prefix(row, L, cached)
         self.admit_info = {"cached": cached, "adopted": True}
+        # No admission program ran to seat the row: the same in-graph
+        # update, as one program of its own.
+        self.token, self.offsets = _seat_row(
+            self.token, self.offsets, np.int32(row), np.int32(first),
+            np.int32(L))
         self._mark_admitted(row, L)
-        self.token = self.token.at[row].set(int(first))
         self._spec_start(row, prompt, int(first), gen_budget)
         return int(first)
 
@@ -1403,8 +1446,9 @@ class StreamSession:
         obs.counter("engine.admit_bucket_tokens").inc(padded)
 
     def _mark_admitted(self, row: int, prompt_len: int) -> None:
+        """Host bookkeeping of an admission; the device's copy of the
+        row's offset and token was written by the admission program."""
         obs.counter("engine.stream_admissions").inc()
-        self.offsets = self.offsets.at[row].set(prompt_len)
         self._host_off[row] = prompt_len
         self.live[row] = True
 
@@ -1487,10 +1531,20 @@ class StreamSession:
                     grew |= eng.kv.ensure_position(r, self._host_off[r])
             if grew:
                 self.cur_table = eng.kv.block_table()
-        done = jnp.asarray([not alive for alive in self.live])
+        done = ~np.asarray(self.live)
         obs.counter(f"engine.decode_path.{kind}").inc()
         obs.counter("engine.decode_live_rows").inc(sum(self.live))
         with obs.span("engine.stream_step"):
+            # The step still splits its key HERE, eagerly (two small
+            # device programs, ~1 ms of dispatch on the v5e, wasted on
+            # a greedy engine), and not in-graph as the admissions do.
+            # The benchmark's clock check (benchmark/harness/hostspans.
+            # paired_steps) wants the step program's START inside this
+            # span, on a device timeline that half of all captures put
+            # 1.1-1.3 ms early: the ~1.6 ms of launch these dispatches
+            # make up survives that, the ~0.6 ms of the bare dispatch
+            # did not (8 of 95 spans matched; PERF.md, PR 31). They go,
+            # through Engine._draw_key, once that check is repaired.
             eng.key, sub = jax.random.split(eng.key)
             self.token, self.caches, self.offsets = step_fn(
                 self.params, self.caches, self.token, self.offsets, sub,
@@ -1573,8 +1627,7 @@ class StreamSession:
         obs.counter("engine.decode_path.spec").inc()
         obs.counter("engine.decode_live_rows").inc(len(live_rows))
         with obs.span("engine.spec_verify"):
-            nxt, self.caches = step_fn(self.params, self.caches,
-                                       jnp.asarray(toks_in),
+            nxt, self.caches = step_fn(self.params, self.caches, toks_in,
                                        self.offsets, self.cur_table)
             if timed:
                 jax.block_until_ready(nxt)
@@ -1598,11 +1651,11 @@ class StreamSession:
             self.cur_table = eng.kv.block_table()
         # Commit the device-side state from the host shadows (frozen
         # rows keep their stale offset/token like the base step).
-        self.offsets = jnp.asarray(self._host_off, jnp.int32)
-        tok_vec = np.asarray(self.token).copy()
+        self.offsets = np.asarray(self._host_off, np.int32)
+        tok_vec = toks_in[:, 0].copy()
         for r in live_rows:
             tok_vec[r] = bursts[r][-1]
-        self.token = jnp.asarray(tok_vec)
+        self.token = tok_vec
         self._note_spec(n_drafted, n_accepted, n_emitted)
         if timed:
             t2 = time.perf_counter()

@@ -263,9 +263,10 @@ class ModelDrafter:
         if self._admit is None:
             self._admit = self._build_admit()
         lb = min(self._bucket(len(prompt)), self.max_seq)
-        ids = jnp.asarray([prompt + [0] * (lb - len(prompt))], jnp.int32)
+        ids = np.zeros((1, lb), np.int32)
+        ids[0, :len(prompt)] = prompt
         self.caches = self._admit(self.params, self.caches, ids,
-                                  jnp.int32(row))
+                                  np.int32(row))
         self._off[row] = len(prompt)
         self._pending[row] = []
         self._seed.pop(row, None)
