@@ -155,20 +155,19 @@ def cache_entries(path: str) -> int:
 
 def resilience_counters() -> dict:
     """The router's counters that say what really ran: fused calls,
-    fallbacks and their reasons, policy provenance, watchdog trips."""
+    fallbacks and their reasons, watchdog trips."""
     from triton_dist_tpu import obs
     counters = obs.snapshot().get("counters", {})
-    keep = ("fused_total", "fallbacks_total", ".fallback.",
-            "policy_source", "watchdog", "policy_probes")
+    keep = ("fused_total", "fallbacks_total", ".fallback.", "watchdog")
     return {k: v for k, v in sorted(counters.items())
             if k.startswith("resilience.") and any(s in k for s in keep)}
 
 
 def check_no_fallback(delta: dict, phase: str) -> int:
-    """No op of the phase was served by its fallback or steered by a
-    stored number; returns how many fused kernels were counted."""
+    """No op of the phase was served by its fallback; returns how many
+    fused kernels were counted."""
     bad = {k: v for k, v in delta.items()
-           if "fallback" in k or "policy_source" in k or "watchdog" in k}
+           if "fallback" in k or "watchdog" in k}
     check(not bad, f"{phase}: ops left their fused path: {bad}")
     fused = sum(v for k, v in delta.items() if k.endswith(".fused_total"))
     check(fused > 0, f"{phase}: no fused kernel was counted")

@@ -1,7 +1,7 @@
 """Tutorial 02: fused AllGather-GEMM and overlap measurement.
 
-Analog of the reference's tutorials/07 (AG-GEMM) + the overlap-efficiency
-methodology from BASELINE.md: run the fused collective matmul, verify
+Analog of the reference's tutorials/07 (AG-GEMM) with an
+overlap-efficiency reading: run the fused collective matmul, verify
 against the XLA golden, and report the measured speedup next to the
 perf-model upper bound.
 

@@ -5,7 +5,7 @@
 #
 # Usage (run the SAME command on every host):
 #   COORDINATOR=host0:8476 NPROC=4 PROC_ID=<this host idx> \
-#       scripts/launch.sh python tests/... | examples/... | bench.py
+#       scripts/launch.sh python tests/... | examples/...
 #
 # On Cloud TPU pods the launcher env is usually injected already
 # (JAX_COORDINATOR_ADDRESS etc.) — then just `python your_script.py`;

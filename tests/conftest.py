@@ -96,8 +96,7 @@ def _isolate_trace(monkeypatch, tmp_path):
 @pytest.fixture(autouse=True)
 def _isolate_observatory():
     """The SLO observatory's process-local rings (request-attribution
-    waterfalls; the perfwatch sample windows reset through
-    resilience.reset_for_tests below) start empty for every test, so
+    waterfalls) start empty for every test, so
     one test's requests cannot leak into another's
     ``request_stats``."""
     from triton_dist_tpu.obs import attrib
@@ -115,8 +114,8 @@ def _isolate_resilience(monkeypatch, tmp_path):
     another test's fused path to XLA."""
     monkeypatch.setenv("TDT_KNOWN_BAD_CACHE",
                        str(tmp_path / "known_bad.json"))
-    # Defense in depth: a module imported by one test (bench.py sets
-    # this for real runs) must not pin routing for every later test.
+    # Defense in depth: a module imported by one test (tpu_smoke.py
+    # sets this for real runs) must not pin routing for every later test.
     monkeypatch.delenv("TDT_FORCE_FUSED", raising=False)
     from triton_dist_tpu import resilience
     from triton_dist_tpu.testing import faults

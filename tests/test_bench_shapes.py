@@ -3,7 +3,7 @@
 test_vmem_budget checks that the bench-shape configs FIT; these check
 that they COMPUTE CORRECTLY: each fused op runs in interpret mode on the
 world=8 mesh with the exact variant + block config its default path
-resolves at the real bench.py shape (world=1, 2048x4096x4096 bf16), so
+resolves at the per-op sweep's shape (world=1, 2048x4096x4096 bf16), so
 a schedule/config regression fails here in CI instead of on the chip
 (reference analog: test/nvidia/test_ag_gemm.py:72-197's shape sweep).
 

@@ -20,8 +20,7 @@ Quick tier, CPU only. Covered here:
 - the ``profile_export`` CLI (validate rc contract, summary, chrome
   conversion) and ``trace_export --merge-profile`` overlay;
 - the ``annotation-coverage`` tdt-check pass incl. the strip-a-span
-  mutation (``devprof.unlabeled``);
-- ``bench_ops`` measured-overlap wellformedness + floor gates.
+  mutation (``devprof.unlabeled``).
 """
 
 import json
@@ -629,83 +628,6 @@ def test_mutant_scheduler_without_kind(tmp_path):
     findings = lint_annotations.check_sampler(
         devprof.__file__.rstrip("c"), p)
     assert [f.code for f in findings] == ["devprof.step_path_blended"]
-
-
-# ---------------------------------------------------------------------------
-# bench_ops: measured-overlap wellformedness + floors.
-# ---------------------------------------------------------------------------
-
-def test_overlap_wellformed_gate():
-    from triton_dist_tpu.tools.bench_ops import (
-        check_overlap_measured_wellformed)
-    # Part didn't run → nothing demanded.
-    assert check_overlap_measured_wellformed({}) == []
-    # Ran + measured number → pass; malformed value → fail.
-    ok = {"ag_gemm_pallas_ms": 1.0, "ag_gemm_overlap_pct_measured": 42.5}
-    assert check_overlap_measured_wellformed(ok) == []
-    bad = {"ag_gemm_pallas_ms": 1.0,
-           "ag_gemm_overlap_pct_measured": 142.5}
-    assert check_overlap_measured_wellformed(bad)
-    # Ran + explicit marker → pass; ran + nothing → fail.
-    marker = {"gemm_rs_pallas_ms": 1.0,
-              "gemm_rs_overlap_requires_chip": True}
-    assert check_overlap_measured_wellformed(marker) == []
-    naked = {"gemm_ar_pallas_ms": 1.0}
-    fails = check_overlap_measured_wellformed(naked)
-    assert fails and "gemm_ar" in fails[0]
-
-
-def test_measured_overlap_floor_gate(tmp_path):
-    from triton_dist_tpu.tools.bench_ops import (
-        check_measured_overlap_floors, load_measured_overlap_floors)
-    baseline = tmp_path / "BASELINE.json"
-    baseline.write_text(json.dumps({
-        "regression_floors": {"tpu": {}, "cpu": {}},
-        "measured_overlap_floors": {
-            "tpu": {"ag_gemm_overlap_pct_measured": 5.0,
-                    "_comment": "x"}, "cpu": {}}}))
-    floors = load_measured_overlap_floors(str(baseline), "tpu")
-    assert floors == {"ag_gemm_overlap_pct_measured": 5.0}
-    assert check_measured_overlap_floors(
-        {"ag_gemm_overlap_pct_measured": 12.0}, floors) == []
-    assert check_measured_overlap_floors(
-        {"ag_gemm_overlap_pct_measured": 2.0}, floors)
-    # A marker-run (no measured key) passes the floor gate — the
-    # wellformedness gate owns that contract.
-    assert check_measured_overlap_floors(
-        {"ag_gemm_overlap_requires_chip": True}, floors) == []
-    # The shipped BASELINE.json carries the tpu-tier hook.
-    from triton_dist_tpu.tools.bench_ops import _default_baseline_path
-    shipped = load_measured_overlap_floors(_default_baseline_path(),
-                                           "tpu")
-    assert "ag_gemm_overlap_pct_measured" in shipped
-
-
-def test_regress_from_file_gates_overlap(tmp_path):
-    """End-to-end through run_regress: a checkpoint whose fused part
-    ran without measured-overlap evidence fails the gate."""
-    from triton_dist_tpu.tools import bench_ops
-    extras = {"ag_gemm_vs_xla": 1.0, "gemm_rs_vs_xla": 1.0,
-              "flash_decode_vs_xla": 1.0,
-              "serving_sched_vs_serial": 5.0,
-              "serving_prefix_ttft_vs_cold": 5.0,
-              "serving_mega_vs_plain": 1.0,
-              "serving_spec_vs_plain": 1.62,
-              "serving_fleet_vs_single": 0.84,
-              "serving_router_vs_direct": 0.9,
-              "serving_history_on_vs_off": 0.97,
-              "serving_disagg_vs_unified": 0.31,
-              "ag_gemm_pallas_ms": 1.0, "baseline_anomaly": None}
-    path = tmp_path / "ck.json"
-    path.write_text(json.dumps({"extras": extras}))
-    rc = bench_ops.run_regress(bench_ops._default_baseline_path(),
-                               str(path), "cpu")
-    assert rc == 1
-    extras["ag_gemm_overlap_requires_chip"] = True
-    path.write_text(json.dumps({"extras": extras}))
-    rc = bench_ops.run_regress(bench_ops._default_baseline_path(),
-                               str(path), "cpu")
-    assert rc == 0
 
 
 # ---------------------------------------------------------------------------

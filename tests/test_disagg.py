@@ -405,25 +405,8 @@ def test_kvstream_claimed_and_changed_selection():
 
 
 # ---------------------------------------------------------------------------
-# Satellites: regress gate + dashboards.
+# Satellites: dashboards.
 # ---------------------------------------------------------------------------
-
-def test_check_disagg_wellformed_gate():
-    from triton_dist_tpu.tools.bench_ops import check_disagg_wellformed
-    good = {"serving_disagg_tokens_per_s": 10.0,
-            "serving_disagg_vs_unified": 1.1,
-            "serving_disagg_handoffs": 3,
-            "serving_disagg_handoff_p50_ms": 12.0,
-            "serving_disagg_dedup_ratio": 0.5}
-    assert check_disagg_wellformed(good) == []
-    assert check_disagg_wellformed({}) == []   # part not run: no-op
-    bad = dict(good, serving_disagg_vs_unified=0.0)
-    assert check_disagg_wellformed(bad)
-    bad = dict(good, serving_disagg_handoffs=0)
-    assert check_disagg_wellformed(bad)
-    bad = dict(good, serving_disagg_dedup_ratio=1.5)
-    assert check_disagg_wellformed(bad)
-
 
 def test_fleet_top_tier_column(paged_tiny):
     from triton_dist_tpu.obs.fleet import FleetView

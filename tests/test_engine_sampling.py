@@ -10,9 +10,6 @@ import pytest
 from triton_dist_tpu.models import DenseLLM, Engine, ModelConfig
 from triton_dist_tpu.models.engine import sample_token
 
-#: Engine-integration tier (model-driven, ~2 min total) -> full tier only.
-pytestmark = pytest.mark.slow
-
 
 def _cfg():
     return ModelConfig(hidden_size=32, intermediate_size=64,

@@ -9,9 +9,6 @@ import pytest
 
 from triton_dist_tpu.models import DenseLLM, Engine, ModelConfig
 
-#: Heavy interpret-mode numerics -> full tier only (quick tier: pytest -m 'not slow').
-pytestmark = pytest.mark.slow
-
 
 @pytest.fixture()
 def small_model(mesh8, key):
@@ -41,6 +38,7 @@ def solo(model, params, mesh8, prompt, gen_len, stop=()):
     return row
 
 
+@pytest.mark.slow(reason="51-63 s")
 def test_stream_more_requests_than_rows(small_model, mesh8):
     model, params = small_model
     prompts = [[1, 2, 3], [9, 8], [4, 5, 6, 7], [11], [23, 29], [31]]
@@ -54,6 +52,7 @@ def test_stream_more_requests_than_rows(small_model, mesh8):
         assert row == want, (prompt, row, want)
 
 
+@pytest.mark.slow(reason="54-69 s")
 def test_stream_stop_tokens_free_rows_early(small_model, mesh8):
     model, params = small_model
     # pick a stop token that actually occurs early for some prompt by
@@ -122,6 +121,7 @@ def _solo_sp(model, params, prompt, gen_len):
     return _SP_GOLDEN_CACHE[key]
 
 
+@pytest.mark.slow(reason="76-92 s each")
 @pytest.mark.parametrize("paged", [False, True])
 def test_stream_sp_and_paged(sp_model, paged):
     """Continuous batching over the long-context engine families: the
@@ -140,6 +140,7 @@ def test_stream_sp_and_paged(sp_model, paged):
         assert row == want, (paged, prompt, row, want)
 
 
+@pytest.mark.slow(reason="27-29 s")
 def test_stream_paged_fewer_requests_than_rows(sp_model):
     """n_req < batch (advisor r3, medium): lanes that are NEVER admitted
     still run the per-row KV write each decode step through their
@@ -170,6 +171,7 @@ def test_stream_sampled_deterministic_per_seed(small_model):
     assert outs[0] == outs[1]
 
 
+@pytest.mark.slow(reason="105-116 s")
 def test_stream_randomized_admission_fuzz(small_model, mesh8):
     """Seeded fuzz over the admission scheduler: random prompt lengths,
     a random stop token, 12 requests through 3 rows — every streamed
@@ -189,6 +191,7 @@ def test_stream_randomized_admission_fuzz(small_model, mesh8):
         assert row == want, (prompt, row, want)
 
 
+@pytest.mark.slow(reason="49-50 s")
 def test_stream_2d_tp_x_sp(mesh8, key):
     """Streaming over the 2-D tp×sp grid: heads tensor-parallel inside
     the sequence ring, per-row offsets through forward_sp."""
@@ -215,6 +218,7 @@ def test_stream_2d_tp_x_sp(mesh8, key):
         assert row == want, (prompt, row, want)
 
 
+@pytest.mark.slow(reason="ep 50-54 s, tp 32-35 s")
 @pytest.mark.parametrize("moe_parallel", ["tp", "ep"])
 def test_stream_moe_model(mesh8, key, moe_parallel):
     """Per-row offsets thread through Qwen3MoE.forward — in BOTH MoE

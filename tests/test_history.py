@@ -772,29 +772,3 @@ def test_report_history_section():
     tel = render_telemetry({"counters": {}, "gauges": {},
                             "histograms": {}, "history": hist})
     assert "#### history" in tel
-
-
-# ---------------------------------------------------------------------------
-# bench_ops: the serving_history shape gate.
-# ---------------------------------------------------------------------------
-
-def test_check_history_wellformed():
-    from triton_dist_tpu.tools.bench_ops import check_history_wellformed
-    # Part didn't run (no sentinel): nothing to check.
-    assert check_history_wellformed({}) == []
-    good = {"serving_history_tokens_per_s": 100.0,
-            "serving_history_on_vs_off": 0.97,
-            "serving_history_ticks": 12,
-            "serving_history_series": 5}
-    assert check_history_wellformed(good) == []
-    for key, bad in (("serving_history_on_vs_off", 0.0),
-                     ("serving_history_on_vs_off", None),
-                     ("serving_history_on_vs_off", True),
-                     ("serving_history_ticks", 0),
-                     ("serving_history_ticks", "many"),
-                     ("serving_history_series", 0),
-                     ("serving_history_series", None)):
-        extras = dict(good)
-        extras[key] = bad
-        fails = check_history_wellformed(extras)
-        assert fails and key in fails[0], (key, bad, fails)

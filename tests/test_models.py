@@ -12,9 +12,6 @@ from triton_dist_tpu.models import (
     AutoLLM, DenseLLM, Engine, ModelConfig, Qwen3MoE)
 from triton_dist_tpu.models.kv_cache import KVCacheManager
 
-#: Heavy interpret-mode numerics -> full tier only (quick tier: pytest -m 'not slow').
-pytestmark = pytest.mark.slow
-
 
 def tiny_dense_cfg():
     return ModelConfig(hidden_size=64, intermediate_size=128,
@@ -45,6 +42,7 @@ def _caches(model, b, t):
     return kv.init()
 
 
+@pytest.mark.slow(reason="47-56 s")
 def test_dense_modes_agree(dense, key):
     b, s, t = 2, 4, 16
     params = dense.init(key)
@@ -59,6 +57,7 @@ def test_dense_modes_agree(dense, key):
                                    rtol=2e-3, atol=2e-3, err_msg=mode)
 
 
+@pytest.mark.slow(reason="27-42 s")
 def test_dense_decode_matches_prefill(dense, key):
     """Greedy decode step must match the last-position logits of a longer
     prefill (KV-cache correctness across modes)."""
@@ -78,6 +77,7 @@ def test_dense_decode_matches_prefill(dense, key):
                                atol=2e-3)
 
 
+@pytest.mark.slow(reason="27-39 s")
 def test_moe_modes_agree(mesh8, key):
     b, s, t = 2, 4, 16
     model = Qwen3MoE(tiny_moe_cfg(), mesh=mesh8, axis="tp")
@@ -91,6 +91,7 @@ def test_moe_modes_agree(mesh8, key):
                                atol=3e-3)
 
 
+@pytest.mark.slow(reason="40-53 s")
 def test_engine_serve_greedy(dense, key):
     b, s, gen = 2, 4, 3
     params = dense.init(key)
@@ -246,6 +247,7 @@ def test_model_config_from_hf_dict():
     assert cfg.is_moe and cfg.head_dim == 32 and cfg.num_experts == 16
 
 
+@pytest.mark.slow(reason="39-58 s")
 def test_moe_ep_mode_matches_tp(mesh8, key):
     """Qwen3MoE under EP (expert-sharded + a2a dispatch) matches the TP
     model on the same weights — VERDICT r1 item 4 model gate."""
@@ -262,6 +264,7 @@ def test_moe_ep_mode_matches_tp(mesh8, key):
                                atol=3e-3)
 
 
+@pytest.mark.slow(reason="46-60 s")
 def test_moe_ep_engine_serve(mesh8, key):
     """EP-mode Qwen3MoE through the Engine decode loop."""
     from triton_dist_tpu.models.engine import Engine
@@ -298,6 +301,7 @@ def test_kv_cache_manager_contract(mesh8):
     assert kv.offset == 0
 
 
+@pytest.mark.slow(reason="45-61 s")
 def test_kv_cache_incremental_decode_matches_full(dense, key):
     """Token-by-token decode through the cache must equal one full
     forward over the same ids (cache write/read positions exact)."""
@@ -473,6 +477,7 @@ def test_paged_kv_alloc_many_rollback(mesh8):
         assert mgr._owned[:3].all() and not mgr._owned[3]
 
 
+@pytest.mark.slow(reason="18-31 s, unsteady")
 def test_checkpoint_roundtrip(mesh8, key, tmp_path):
     """Sharded params save/restore (orbax): restored arrays keep their
     shardings and drive an identical forward — capability absent in the
@@ -522,6 +527,7 @@ def _hf_parity_case(mesh8, hf_model_cls, hf_cfg, model_type):
                                atol=2e-3)
 
 
+@pytest.mark.slow(reason="28-30 s")
 def test_hf_transformers_parity_qwen3(mesh8):
     """Bit-level architecture parity vs the installed HF Qwen3 eager
     implementation — the external golden the self-consistency tests
@@ -536,6 +542,7 @@ def test_hf_transformers_parity_qwen3(mesh8):
     _hf_parity_case(mesh8, Qwen3ForCausalLM, hf_cfg, "qwen3")
 
 
+@pytest.mark.slow(reason="7-58 s, unsteady")
 def test_hf_transformers_parity_llama(mesh8):
     """Same vs HF Llama (no qk-norm — the Llama-3/Seed-OSS dense
     class)."""
@@ -563,6 +570,7 @@ def test_hf_transformers_parity_qwen3_gqa(devices):
     _hf_parity_case(mesh4, Qwen3ForCausalLM, hf_cfg, "qwen3")
 
 
+@pytest.mark.slow(reason="16-35 s, unsteady")
 def test_hf_transformers_parity_qwen3_moe(devices):
     """MoE parity vs HF Qwen3Moe eager: router softmax/top-k norm,
     expert stacking, shared attention — external golden for the MoE

@@ -36,9 +36,9 @@ def test_export_lint_all_cases(tmp_path, world):
 
 
 def test_export_lint_layer_bench_dims():
-    """bench.py layer_8b/32b compositions (Qwen3 per-chip TP8 slices,
+    """Decoder-layer compositions of Qwen3-8B/32B (per-chip TP8 slices,
     prefill ag_rs M=2048 + decode gemm_ar M=128) pass the Mosaic
-    verifier at the REAL dims the chip bench runs — K=5120 and odd
+    verifier at their REAL dims — K=5120 and odd
     N-widths never appear in the smoke shapes (round 4)."""
     import os
     import jax

@@ -13,7 +13,6 @@ import time
 
 import jax
 import jax.numpy as jnp
-import numpy as np
 import pytest
 
 from triton_dist_tpu.runtime.utils import perf_func_chained
@@ -77,52 +76,3 @@ def test_timing_selfcheck_has_no_unchecked_device(monkeypatch):
     monkeypatch.setattr(pm, "get_chip_spec", unknown)
     with pytest.raises(ValueError, match="TPU v9 hyper"):
         utils.timing_selfcheck()
-
-
-@pytest.mark.slow
-def test_world1_xla_baseline_pair_agreement():
-    """The bench's two world=1 XLA baselines are the same matmul behind
-    the same fold; with windowed min-of-5 timing they must agree within
-    the bench's 1.5x anomaly gate (plus slack for CI neighbors). This
-    is the in-CI replica of bench.py::_finalize_checks' cross-part
-    gate."""
-    import importlib.util
-    import pathlib
-
-    from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
-
-    from triton_dist_tpu.ops.allgather_gemm import (
-        create_ag_gemm_context, ag_gemm)
-    from triton_dist_tpu.ops.gemm_reduce_scatter import (
-        create_gemm_rs_context, gemm_rs)
-
-    root = pathlib.Path(__file__).resolve().parent.parent
-    spec = importlib.util.spec_from_file_location("bench", root / "bench.py")
-    bench = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(bench)
-
-    mesh = Mesh(np.array(jax.devices()[:1]), ("tp",))
-    m, k, nn = 64, 128, 128
-    key = jax.random.PRNGKey(0)
-    a = jax.random.normal(key, (m, k), jnp.float32).astype(jnp.bfloat16)
-    b = jax.random.normal(key, (k, nn), jnp.float32).astype(jnp.bfloat16)
-
-    ctx_ag = create_ag_gemm_context(mesh, "tp", interpret=None)
-    ctx_rs = create_gemm_rs_context(mesh, "tp", interpret=None)
-    a_ag = jax.device_put(a, NamedSharding(mesh, P("tp")))
-    b_ag = jax.device_put(b, NamedSharding(mesh, P(None, "tp")))
-    a_rs = jax.device_put(a, NamedSharding(mesh, P(None, "tp")))
-    b_rs = jax.device_put(b, NamedSharding(mesh, P("tp")))
-
-    t_ag = perf_func_chained(
-        bench._args_step(
-            lambda x, bb: bench._chain_fold(
-                ag_gemm(x, bb, ctx_ag, impl="xla"), m, k), b_ag),
-        a_ag, (8, 24))
-    t_rs = perf_func_chained(
-        bench._args_step(
-            lambda x, bb: bench._chain_fold(
-                gemm_rs(x, bb, ctx_rs, impl="xla"), m, k), b_rs),
-        a_rs, (8, 24))
-    ratio = max(t_ag, t_rs) / min(t_ag, t_rs)
-    assert ratio < 1.6, (t_ag, t_rs, ratio)

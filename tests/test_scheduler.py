@@ -1215,11 +1215,10 @@ def test_spec_metrics_and_waterfall_through_server(tiny):
         srv.stop()
 
 
-def test_metrics_catalog_wellformed(tiny, monkeypatch):
-    """CI satellite: every SLO/perfwatch metric in the documented
-    catalog appears in a live {"cmd": "metrics"} snapshot after real
-    traffic (+ a perfwatch sample/consult in the same process)."""
-    import json as _json
+def test_metrics_catalog_wellformed(tiny):
+    """CI satellite: every SLO metric in the documented catalog
+    appears in a live {"cmd": "metrics"} snapshot after real
+    traffic."""
     model, params = tiny
     srv = ModelServer(_engine(model), params, port=0).start()
     try:
@@ -1229,33 +1228,12 @@ def test_metrics_catalog_wellformed(tiny, monkeypatch):
                       [{"prompt_ids": [[1 + i, 2, 3]], "gen_len": 4}
                        for i in range(3)], timeout=180)
         assert all("tokens" in o for o in outs), outs
-        # Perfwatch metrics need samples + a policy consult: feed the
-        # process-shared watch and run one policy decision off a temp
-        # floor table (the PR-3 cpu-forcing test hook).
-        from triton_dist_tpu.obs import perfwatch, slo
-        from triton_dist_tpu.resilience import router
-        monkeypatch.setenv("TDT_PERFWATCH_MIN_SAMPLES", "2")
-        for _ in range(3):
-            perfwatch.record("catop", "fused", "b", 1.0)
-            perfwatch.record("catop", "xla", "b", 2.0)
-        floors = {"regression_floors": {"cpu": {"catop_vs_xla": 0.95}}}
-        import tempfile
-        with tempfile.NamedTemporaryFile("w", suffix=".json",
-                                         delete=False) as f:
-            _json.dump(floors, f)
-        monkeypatch.setenv("TDT_BASELINE_PATH", f.name)
-        monkeypatch.setenv("TDT_BASELINE_ROUTING", "cpu")
-        assert router.policy_reason("catop") is None   # live 2.0: fused
+        from triton_dist_tpu.obs import slo
         c = ChatClient(srv.host, srv.port, timeout=180)
         m = c.request({"cmd": "metrics"})["metrics"]
         c.close()
         for name in slo.gauge_catalog():
             assert name in m["gauges"], name
         assert "serving.pump_iteration_ms" in m["histograms"]
-        assert ("resilience.perfwatch.catop.live_ratio"
-                in m["gauges"])
-        assert ("resilience.perfwatch.samples.fused"
-                in m["counters"])
-        assert ("resilience.policy_source.live" in m["counters"])
     finally:
         srv.stop()

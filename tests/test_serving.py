@@ -1,15 +1,11 @@
 """Serving roundtrip test (reference model_server/chat demo, SURVEY §2.7)."""
 
-import pytest
 import jax
 import jax.numpy as jnp
 import numpy as np
 
 from triton_dist_tpu.models import DenseLLM, Engine, ModelConfig
 from triton_dist_tpu.serving import ChatClient, ModelServer
-
-#: Heavy interpret-mode numerics -> full tier only (quick tier: pytest -m 'not slow').
-pytestmark = pytest.mark.slow
 
 
 def test_server_client_roundtrip(mesh8, key):

@@ -175,7 +175,7 @@ def test_sparse_traffic_single_blip_cannot_breach(monkeypatch):
 
 
 def test_reset_windows_starts_fresh_epoch():
-    """bench.py's warmup/timed split: reset_windows drops every
+    """A warmup/timed split: reset_windows drops every
     retained subwindow so the next scrape prices only post-reset
     traffic."""
     ck = Clock()

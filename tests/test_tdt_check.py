@@ -266,7 +266,7 @@ def test_catalog_suffix_alternates_expand():
     cat = pathlib.Path(__file__).parents[1] / "docs" / "observability.md"
     pats = [p for _, cands in lint_metrics.catalog_patterns(cat)
             for p in cands]
-    assert any(p.endswith("perfwatch.samples.xla") for p in pats)
+    assert any(p.endswith("engine.decode_path.plain") for p in pats)
     assert any(p.endswith("_p99_ms") and "rolling" in p for p in pats)
 
 

@@ -477,7 +477,7 @@ def test_trace_fallback_miss_warns_once(tmp_path, monkeypatch):
 
 def test_top_render_dashboard_sections():
     """tools/top.py: the dashboard renders rolling SLOs, burn rates,
-    occupancy/pool, live op ratios, and request waterfalls from a
+    occupancy/pool, and request waterfalls from a
     plain metrics snapshot (no server needed)."""
     from triton_dist_tpu.tools import top
     snap = {
@@ -491,7 +491,6 @@ def test_top_render_dashboard_sections():
             "serving.batch_occupancy": 3,
             "serving.queue_depth": 1,
             "kv.block_utilization": 0.75,
-            "resilience.perfwatch.ag_gemm.live_ratio": 1.2,
             "trace.dropped_total": 7,
         },
         "counters": {"serving.admitted": 10, "serving.retired": 9},
@@ -506,7 +505,6 @@ def test_top_render_dashboard_sections():
     assert "slo burn rates" in out and "ttft_p99" in out
     assert "BREACH" not in out
     assert "block utilization" in out and "0.750" in out
-    assert "ag_gemm" in out and "1.200x" in out
     assert "rid 4" in out and "prefill 9" in out
     assert "TDT_TRACE_RING" in out
     snap["gauges"]["serving.slo_breached.ttft_p99"] = 1

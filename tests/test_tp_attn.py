@@ -8,9 +8,6 @@ import pytest
 from triton_dist_tpu.layers import TPAttn, precompute_rope_cache
 from triton_dist_tpu.layers.tp_attn import _attention_core
 
-#: Heavy interpret-mode numerics -> full tier only (quick tier: pytest -m 'not slow').
-pytestmark = pytest.mark.slow
-
 H = 64
 NQ, NKV, D = 16, 8, 8
 B, S, T = 2, 4, 8

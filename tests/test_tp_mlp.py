@@ -7,9 +7,6 @@ import pytest
 
 from triton_dist_tpu.layers import TPMLP
 
-#: Heavy interpret-mode numerics -> full tier only (quick tier: pytest -m 'not slow').
-pytestmark = pytest.mark.slow
-
 H, I, M = 64, 128, 16
 
 

@@ -7,7 +7,7 @@ the library's comm kernels request a 64 MB Mosaic scoped-VMEM limit via
 over declared buffers (constants in ops/common.py).
 A kernel built WITHOUT ``comm_params`` keeps Mosaic's 16 MB default and
 needs the tighter ``limit=`` argument. ``check_entry_vmem`` traces each
-op's ``impl="pallas"`` entry at the exact bench.py shapes with
+op's ``impl="pallas"`` entry at the shapes the chip is given with
 ``jax.eval_shape`` (no execution) and asserts the static footprint of
 every ``pallas_call`` it contains. World=1 (the bench environment) and
 world=8 are both checked: round 2's failure was world=1-specific
@@ -36,7 +36,7 @@ def test_ag_gemm_bench_shape_fits(world):
         create_ag_gemm_context, ag_gemm)
     mesh = _mesh(world)
     ctx = create_ag_gemm_context(mesh, "tp", interpret=True)
-    m, k, n = 2048, 4096, 4096  # bench.py shape
+    m, k, n = 2048, 4096, 4096  # the per-op sweep's shape
     check_entry_vmem(
         lambda a, b: ag_gemm(a, b, ctx, impl="pallas"),
         jax.ShapeDtypeStruct((m, k), bf16),
@@ -76,7 +76,7 @@ def test_flash_decode_serving_shape_fits(world):
     mesh = _mesh(world)
     ctx = create_flash_decode_context(mesh, "tp", interpret=True,
                                       variant="tiled", t_blk=512)
-    b, hq, hkv, d, t = 8, 32, 8, 128, 8192  # bench.py serving shape
+    b, hq, hkv, d, t = 8, 32, 8, 128, 8192  # serving shape
     check_entry_vmem(
         lambda q, kc, vc, n: gqa_fwd_batch_decode(q, kc, vc, n, ctx,
                                                   impl="pallas"),
@@ -104,7 +104,7 @@ def test_sp_attention_fused_prefill_shape_fits():
 
 
 def test_sp_attention_fused_bench_shape_fits():
-    """THE bench.py sp_attn shape at world=1 (s_loc=4096, hq=16): q +
+    """The sp_attn shape at world=1 (s_loc=4096, hq=16): q +
     state total ~50 MB — the q-group residency must bound what reaches
     VMEM."""
     from triton_dist_tpu.ops.sp_attention import (
@@ -122,7 +122,7 @@ def test_sp_attention_fused_bench_shape_fits():
 
 def test_train_step_bench_config_fits():
     """Trace the WHOLE fused train step (fwd + transpose-kernel bwd +
-    optax update) at bench.py's train config and assert every
+    optax update) at the train config below and assert every
     pallas_call inside fits — forward gates alone miss the backward's
     transposed shapes (e.g. gemm_rs contractions over inter=8192)."""
     from triton_dist_tpu.models import DenseLLM, ModelConfig
@@ -238,12 +238,12 @@ def test_ag_swiglu_bench_shape_fits(world):
     ctx = create_ag_gemm_context(mesh, "tp", interpret=True)
     m, k = 2048, 4096
     # ag_swiglu takes the GLOBAL weight width (n_loc = n // world
-    # inside). Gate (a) the exact width bench.py's tp_mlp runs at this
+    # inside). Gate (a) the exact width a tp_mlp runs at this
     # world (inter = 12288 // max(n,8) * n → per-chip 1536), and (b) a
     # 12288-global stress width (per-chip 12288 at world=1) so a config
     # that only fits scaled-down stand-ins cannot pass CI (review r3i:
     # the first version of this gate divided by world twice and tested
-    # an 8x-smaller kernel than the bench runs).
+    # an 8x-smaller kernel than a chip would run).
     for n in (4096, 12288 // max(world, 8) * world,
               3072 * world, 12288):
         check_entry_vmem(
@@ -257,7 +257,7 @@ def test_ag_swiglu_bench_shape_fits(world):
 @pytest.mark.parametrize("dims", [
     ("8b", 4096, 4, 1, 128, 1536), ("32b", 5120, 8, 1, 128, 3200)])
 def test_layer_bench_dims_fit(world, dims):
-    """bench.py layer_8b/32b (Qwen3 per-chip TP8 slice, prefill M=2048
+    """Decoder-layer dims of Qwen3-8B/32B (per-chip TP8 slice, prefill M=2048
     + decode M=128): every Pallas kernel in the fused decoder-layer
     step must fit the chip budget at both worlds."""
     from triton_dist_tpu.layers import TPAttn, precompute_rope_cache
@@ -317,7 +317,7 @@ def test_ag_swiglu_configs_table():
 
 @pytest.mark.slow
 def test_deep_mega_bench_config_fits():
-    """The 32-layer fused mega step at bench.py's deep TPU config: every
+    """The 32-layer fused mega step at the deep TPU config: every
     pallas_call within the declared cap. Run offline after the round-5
     on-chip mega MosaicError (HTTP 500 during the deep compile): the
     static footprint is clean, so the failure class was Mosaic's old

@@ -432,7 +432,7 @@ def run_smoke(log_path: str | None = None, only: str | None = None,
              q, pool_k, pool_v, table,
              jnp.int32(world * n_pages * page // 2), fd_paged_g))
 
-    # Serving shape (bench.py flash_decode line: B=8, 32 heads, t=8k).
+    # Serving shape: B=8, 32 heads, t=8k.
     def fd_serving():
         bs, hqs, hkvs, ds, ts = 8, 32, 8, 128, 8192
         qv = randn((bs, hqs, ds), k=15)

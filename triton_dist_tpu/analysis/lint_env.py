@@ -130,9 +130,8 @@ def run(root=None, files=None, docs_dir=None) -> list:
     root = Path(root)
     if files is None:
         files = sorted((root / "triton_dist_tpu").rglob("*.py"))
-        for extra in ("bench.py", "tpu_smoke.py"):
-            if (root / extra).exists():
-                files.append(root / extra)
+        if (root / "tpu_smoke.py").exists():
+            files.append(root / "tpu_smoke.py")
     if docs_dir is None:
         docs_dir = root / "docs"
     if not Path(docs_dir).exists():

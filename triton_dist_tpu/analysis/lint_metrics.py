@@ -90,10 +90,9 @@ def catalog_patterns(md_path) -> list:
     ``<placeholder>`` segments become wildcards. Suffix/alternate
     tokens (``.plain``, ``_p99_ms``, ``<name>_slow``) expand against
     the row's preceding full name at every split point sharing the
-    alternate's leading character — e.g. ``.xla`` after
-    ``resilience.perfwatch.samples.fused`` yields
-    ``resilience.perfwatch.samples.xla`` among its candidates; a
-    token matches when ANY candidate does."""
+    alternate's leading character — e.g. ``.plain`` after
+    ``engine.decode_path.mega`` yields ``engine.decode_path.plain``
+    among its candidates; a token matches when ANY candidate does."""
     text = Path(md_path).read_text()
     out = []
     in_catalog = False

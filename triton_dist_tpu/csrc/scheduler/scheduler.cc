@@ -143,8 +143,8 @@ int64_t tdt_schedule_critical_path(int32_t n_tasks, int32_t n_edges,
 // >= any child's by at least its own cost; zero-cost ties fall back to
 // topo position), so the mega executor can EMIT tasks in this order —
 // which biases XLA's buffer-liveness and latency-hiding scheduling
-// toward the critical path (measured: bench.py mega part compares peak
-// temp memory of topo- vs heft-emitted programs). Returns 0, or -1 on
+// toward the critical path (what to compare: peak temp memory of
+// topo- vs heft-emitted programs). Returns 0, or -1 on
 // a cycle. out receives the task ids in priority order.
 int32_t tdt_priority_order(int32_t n_tasks, int32_t n_edges,
                            const int32_t* edges, const int64_t* costs,

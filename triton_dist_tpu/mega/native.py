@@ -165,8 +165,8 @@ def priority_order(n_tasks: int, edges, costs=None) -> np.ndarray:
 
     This is the schedule's RUNTIME hook: the mega executor emits tasks
     in this order, which biases XLA's buffer-liveness/latency-hiding
-    scheduling toward the critical path (bench.py's mega part measures
-    the peak-temp-memory effect; VERDICT r3 weak-4 wiring)."""
+    scheduling toward the critical path (the effect to look for is a
+    lower peak of temporary memory in the lowered program)."""
     edges = _i32(np.asarray(edges).reshape(-1, 2))
     lib = _load()
     if lib is not None:

@@ -129,8 +129,8 @@ class MegaQwen3:
 
     def flat_args(self, params: dict, token: jax.Array, kv_caches,
                   offset, kv_start=None, table=None) -> list:
-        """The executor's positional argument list (also used by
-        bench.py to lower the program for memory analysis).
+        """The executor's positional argument list (also what lowers
+        the program for a memory analysis).
 
         ``offset``: scalar or (B,) per-row decode positions.
         ``kv_start`` (dense family): (B,) ragged left-pad boundaries;

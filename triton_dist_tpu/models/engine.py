@@ -89,17 +89,15 @@ class DecodePathPolicy:
     both paths hold a measured per-iteration time, the faster one
     wins; the decision is re-taken per batch (every pump iteration /
     serve call), so the selection tracks the batch shape the captures
-    were taken at — silicon numbers arbitrating, the same way
-    perfwatch live ratios arbitrate router policy
-    (docs/resilience.md). With no measurement (or only one path
+    were taken at. With no measurement (or only one path
     measured) the default is :data:`DEFAULT_AUTO_PATH` — except every
     :data:`PROBE_EVERY`-th decision, which runs the OTHER path so the
-    sampler can ever measure it (the perfwatch-probe analog: a policy
-    that only runs its prior can never collect the numbers to correct
-    it; outputs are bit-identical, so a probe costs only the paths'
-    speed difference). Probes are doubly gated on measurability: only
-    SAMPLABLE decisions probe (stream-session decode steps under the
-    scheduler — ``decide(samplable=True)``; a serve() call resolved
+    sampler can ever measure it (a policy that only runs its prior can
+    never collect the numbers to correct it; outputs are bit-identical,
+    so a probe costs only the paths' speed difference). Probes are
+    doubly gated on measurability: only SAMPLABLE decisions probe
+    (stream-session decode steps under the scheduler —
+    ``decide(samplable=True)``; a serve() call resolved
     outside the pump would run its whole generation on the probed
     path with nothing able to capture it), and only while a devprof
     sampler is alive (``obs.devprof.sampler_active()`` — the same

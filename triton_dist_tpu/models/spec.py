@@ -17,9 +17,8 @@ Two drafters:
 - :class:`NGramDrafter` (default, model-free): prompt-lookup /
   n-gram continuation — the most recent earlier occurrence of the
   row's trailing n-gram proposes the tokens that followed it. Zero
-  model cost, so it is measurable on CPU (bench.py ``serving_spec``);
-  it wins exactly on repetition-heavy workloads (code, templated
-  text, self-repeating greedy decodes).
+  model cost; it wins exactly on repetition-heavy workloads (code,
+  templated text, self-repeating greedy decodes).
 - :class:`ModelDrafter`: a small model (e.g. ``presets.qwen3_0_6b``
   drafting for an 8B/32B target — :func:`draft_model_from_preset`
   shares the preset machinery) runs its own per-row KV cache in

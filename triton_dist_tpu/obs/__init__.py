@@ -16,11 +16,9 @@ explicit ``{"cmd": "dump_trace"}``.
 
 The serving SLO observatory (ISSUE 8) sits on top: ``obs.slo`` keeps
 rolling-window percentiles + multi-window burn rates that arm the
-flight recorder on a latency-SLO breach, ``obs.perfwatch`` keeps live
-fused-vs-XLA wall-time medians the resilience router consults before
-its static BASELINE floors, and ``obs.attrib`` keeps per-request
-latency waterfalls (queue → prefill → decode) the server returns
-inline and ``tools/top.py`` renders live.
+flight recorder on a latency-SLO breach, and ``obs.attrib`` keeps
+per-request latency waterfalls (queue → prefill → decode) the server
+returns inline and ``tools/top.py`` renders live.
 
 The fleet plane (ISSUE 14, ``obs.fleet``) lifts all of it across N
 replicas: per-replica ``ReplicaHealth`` snapshots behind the server's
@@ -77,7 +75,7 @@ from triton_dist_tpu.obs.exposition import (  # noqa: F401
     render_prometheus,
 )
 from triton_dist_tpu.obs import (  # noqa: F401
-    attrib, devprof, fleet, flight, history, perfwatch, slo, trace)
+    attrib, devprof, fleet, flight, history, slo, trace)
 from triton_dist_tpu.obs.slo import (  # noqa: F401
     SLOTarget,
     SLOTracker,

@@ -23,9 +23,7 @@ Consumers (docs/observability.md "Request attribution"):
   ``"timing"``;
 - the last ``TDT_ATTRIB_RING`` (default 256) waterfalls sit in a
   process-local ring, queryable via ``{"cmd": "request_stats"}``;
-- ``tools/top.py`` renders the freshest entries in its refresh loop,
-  and bench.py embeds one sampled waterfall per serving part so
-  BENCH_*.json shows where TTFT went.
+- ``tools/top.py`` renders the freshest entries in its refresh loop.
 """
 
 from __future__ import annotations

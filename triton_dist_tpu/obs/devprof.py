@@ -2,10 +2,9 @@
 timelines and MEASURED overlap metrics.
 
 Every ``comms.<op>.overlap_pct`` number the repo publishes elsewhere is
-model-derived (``tools/perf_model``) or dispatch-derived (``bench.py``'s
-ingredient proxy, ``obs.trace``'s host-side chunk events) — while the
-only silicon measurement on record says overlap is 0.0% against a ≥90%
-north star (ROADMAP item 5). T3's thesis (PAPERS.md) is that
+model-derived (``tools/perf_model``) or dispatch-derived
+(``obs.trace``'s host-side chunk events), and no chip run has measured
+overlap yet. T3's thesis (PAPERS.md) is that
 fine-grained overlap wins are only real when read off the DEVICE
 timeline, and the reference's own evaluation is built on per-rank
 merged chrome traces. ``tools/profiler.py`` has long owned the capture
@@ -51,7 +50,7 @@ missing read-back side:
 Labels under jit: the router's annotation wraps the PYTHON invocation,
 so for a jitted call it brackets trace time (like the ``comms.*``
 counters). Measured per-op attribution therefore profiles EAGER
-dispatches — exactly how ``bench.py`` / ``tpu_smoke.py`` use it — while
+dispatches — exactly how ``tpu_smoke.py`` uses it — while
 the pump sampler attributes whole iterations (``device.step``), which
 is correct for jitted programs too because the label wraps the
 blocking call. docs/perf.md "Overlap accounting" spells out the tiers.

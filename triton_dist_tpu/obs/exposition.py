@@ -31,12 +31,12 @@ def histogram_quantile(h: dict, q: float, detail: bool = False):
     the containing bucket. A quantile landing in the +Inf overflow
     bucket reports the recorded ``max`` when the snapshot carries one,
     and otherwise CLIPS to the top finite bucket edge — windowed
-    histogram deltas (bench.py) and rolling windows (``obs.slo``)
+    histogram deltas and rolling windows (``obs.slo``)
     cannot know their extrema, and "at least the top edge" is a usable
     lower bound where ``None`` used to hide the whole percentile.
     ``detail=True`` returns ``(value, clipped)`` so callers can flag
     the clip. ``None`` (or ``(None, False)``) only on an empty or
-    malformed histogram. This is how bench.py turns the server's
+    malformed histogram. This is how a client turns the server's
     ``serving.ttft_ms`` histogram into p50/p99 without shipping raw
     samples."""
     value, clipped = None, False

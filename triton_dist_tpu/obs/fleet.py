@@ -306,7 +306,7 @@ def merge_fleet_snapshots(by_replica: dict) -> dict:
 
 
 #: The latency histograms every fleet-percentile surface reads
-#: (tools/report.py, tools/fleet_top.py, bench.py serving_fleet):
+#: (tools/report.py, tools/fleet_top.py):
 #: (snapshot histogram name, display label) pairs.
 PERCENTILE_HISTOGRAMS = (("serving.ttft_ms", "ttft"),
                          ("serving.tpot_ms", "tpot"))

@@ -44,7 +44,7 @@ __all__ = [
 
 def env_int(name: str, default: int, minimum: int | None = None) -> int:
     """Validated integer env knob — the one parser the obs modules
-    share (perfwatch / attrib; the ring/breaker knobs predate it)."""
+    share (attrib / history; the ring/breaker knobs predate it)."""
     v = os.environ.get(name, "").strip()
     if not v:
         return default

@@ -344,9 +344,9 @@ class SLOTracker:
 
     def reset_windows(self) -> None:
         """Drop every rolling window (breach state stays): the start
-        of a fresh measurement epoch. bench.py calls this between its
-        warmup and timed passes so the windowed percentiles it reports
-        cannot contain the warmup's cold-compile latencies."""
+        of a fresh measurement epoch, for a caller that warms up and
+        then measures: the windowed percentiles then cannot contain
+        the warm-up's cold-compile latencies."""
         for h in self.hists.values():
             h.clear()
 

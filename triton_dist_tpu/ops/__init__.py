@@ -15,8 +15,8 @@ Each op has (at least) two implementations:
 Resilience contract (docs/resilience.md): every public entry here
 wears the ``@resilient`` decorator, registering its ``impl="xla"``
 branch as the always-available escape hatch — the router diverts
-known-bad configs, BASELINE-measured slow regimes, and open-breaker
-ops to it, and retries fused infra failures on it with bit-identical
-numerics. ``tools/fallback_lint.py`` (quick tier) rejects any new
-entry that ships without one.
+known-bad configs and open-breaker ops to it, and retries fused infra
+failures on it with bit-identical numerics.
+``tools/fallback_lint.py`` (quick tier) rejects any new entry that
+ships without one.
 """

@@ -18,7 +18,7 @@ with the device's own chunk, so compute order is naturally rank-rotated
 
 ``impl="xla"``: ``lax.all_gather`` + ``jnp.dot`` — the unfused golden
 (XLA's latency-hiding scheduler may still overlap at coarse grain; it is
-also the measuring stick for overlap efficiency, BASELINE.md north star).
+also what overlap efficiency is measured against).
 """
 
 from __future__ import annotations

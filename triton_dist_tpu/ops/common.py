@@ -147,14 +147,13 @@ def record_overlap(op: str, cost, world: int | None = None,
                    dirs: int | None = None) -> None:
     """Per-op overlap gauges from a :class:`tools.perf_model
     .FusedGemmCost` breakdown: ``comms.<op>.overlap_pct`` (hidden
-    fraction of the ring communication under the chosen tile schedule —
-    the BASELINE.md >=90% north-star metric, previously only derivable
-    by hand from bench ingredients) and ``comms.<op>.exposed_comm_ms``.
+    fraction of the ring communication under the chosen tile schedule;
+    the upstream system's target is >= 90 %) and
+    ``comms.<op>.exposed_comm_ms``.
 
     Model-derived from the tile-loop timing structure at DISPATCH time
     (trace time under jit, like ``record_comm``), not a trace
-    decomposition — bench.py's ``comms.<op>.overlap_pct`` extras carry
-    the measured counterpart on chip. At world=1 there is no
+    decomposition: nothing here is measured. At world=1 there is no
     communication to expose, so the gauge reads 100.
 
     With event tracing on and ``world``/``dirs`` passed, the ring
@@ -334,28 +333,6 @@ def any_spec():
 
 def cdiv(a: int, b: int) -> int:
     return -(-a // b)
-
-
-def pow2_round(n: int) -> int:
-    """Smallest power of two >= ``n`` (0 stays 0)."""
-    n = int(n)
-    if n <= 0:
-        return 0
-    return 1 << (n - 1).bit_length()
-
-
-def shape_bucket(*arrays) -> str:
-    """Power-of-two-rounded shape signature of an op call's array
-    operands, e.g. ``"2048x4096:bfloat16,4096x4096:bfloat16"`` — the
-    pooling key for the live perf-ratio watch (``obs.perfwatch``).
-    Coarser than the resilience config key on purpose: a serving
-    process sees few distinct shapes but many calls, and nearby shapes
-    share a performance regime, while a 64x size difference never
-    pools."""
-    return ",".join(
-        "x".join(str(pow2_round(d)) for d in a.shape) + f":{a.dtype}"
-        for a in arrays
-        if hasattr(a, "shape") and hasattr(a, "dtype"))
 
 
 def round_up(a: int, b: int) -> int:

@@ -16,9 +16,9 @@ makes a bad kernel config degrade a *request*, never the process:
   (closed → open → half-open → closed).
 - ``resilience.router``   — the ``@resilient`` decorator on every
   public op entry in ``ops/``: routes to each op's ``impl="xla"``
-  reference path on known-bad hits, BASELINE-measured slow regimes,
-  or an open breaker, and converts fused infra failures into recorded
-  fallbacks. ``TDT_FORCE_FUSED=1`` bypasses routing (bench / smoke).
+  reference path on known-bad hits or an open breaker, and converts
+  fused infra failures into recorded fallbacks. ``TDT_FORCE_FUSED=1``
+  bypasses routing (smoke).
 
 Fault injection for all of the above lives in
 ``triton_dist_tpu.testing.faults``; policies and env knobs are
@@ -46,7 +46,6 @@ from triton_dist_tpu.resilience.router import (  # noqa: F401
     decide,
     device_kind,
     force_fused,
-    policy_reason,
     registered_fallbacks,
     resilient,
     reset_router,
@@ -60,5 +59,5 @@ from triton_dist_tpu.resilience.watchdog import (  # noqa: F401
 
 def reset_for_tests() -> None:
     """Reset every piece of process-local resilience state (breakers,
-    compiled-key set, baseline cache, known-bad singleton)."""
+    compiled-key set, known-bad singleton)."""
     reset_router()

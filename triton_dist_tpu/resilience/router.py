@@ -13,16 +13,13 @@ bit-identical to calling the reference path directly.
 
 Routing order (first match wins), per (op, config, device_kind):
 
-1. ``TDT_FORCE_FUSED=1``    → fused, always (bench / smoke / manual
+1. ``TDT_FORCE_FUSED=1``    → fused, always (smoke / manual
    revalidation; the watchdog still guards the compile).
 2. known-bad cache hit      → XLA (``resilience.knownbad`` — a config
    that ever hung Mosaic is never re-entered, across processes).
-3. BASELINE policy          → XLA for regimes where the measured
-   ``<op>_vs_xla`` ratio says the fused kernel is slower
-   (``BASELINE.json`` ``regression_floors``; see :func:`policy_reason`).
-4. open circuit breaker     → XLA until the cooldown's half-open probe
+3. open circuit breaker     → XLA until the cooldown's half-open probe
    (``resilience.breaker``).
-5. otherwise                → fused, guarded: first-compile runs under
+4. otherwise                → fused, guarded: first-compile runs under
    the watchdog (``resilience.watchdog``), infra failures (Mosaic /
    XLA runtime errors, injected faults, watchdog trips, optional
    non-finite-output guard) record into the breaker + known-bad cache
@@ -47,10 +44,8 @@ import contextlib
 import dataclasses
 import functools
 import inspect
-import json
 import os
 import threading
-import time
 
 from triton_dist_tpu import obs
 from triton_dist_tpu.resilience import knownbad
@@ -60,7 +55,7 @@ from triton_dist_tpu.resilience.watchdog import (CompileTimeout,
                                                  run_with_timeout)
 
 __all__ = ["FallbackSpec", "NonFiniteOutput", "decide", "device_kind",
-           "force_fused", "policy_reason", "registered_fallbacks",
+           "force_fused", "registered_fallbacks",
            "resilient", "reset_router"]
 
 
@@ -101,8 +96,8 @@ def registered_fallbacks() -> dict[str, FallbackSpec]:
 
 def force_fused() -> bool:
     """``TDT_FORCE_FUSED=1``: bypass all routing, always run fused
-    (bench.py and tpu_smoke.py set this — a measurement or smoke run
-    that silently measured XLA would be worse than one that fails)."""
+    (tpu_smoke.py sets this — a smoke run that silently exercised XLA
+    would be worse than one that fails)."""
     return os.environ.get("TDT_FORCE_FUSED", "").strip() in (
         "1", "true", "yes")
 
@@ -129,118 +124,6 @@ def device_kind() -> str:
     return _DEVICE_KIND
 
 
-def _platform_tier() -> str:
-    try:
-        import jax
-        return "tpu" if jax.default_backend() == "tpu" else "cpu"
-    except Exception:  # noqa: BLE001
-        return "cpu"
-
-
-# ---------------------------------------------------------------------------
-# BASELINE-driven policy.
-# ---------------------------------------------------------------------------
-
-_BASELINE_CACHE: dict[str, dict] = {}
-
-
-def _baseline_path() -> str:
-    env = os.environ.get("TDT_BASELINE_PATH")
-    if env:
-        return env
-    here = os.path.dirname(os.path.abspath(__file__))
-    return os.path.join(os.path.dirname(os.path.dirname(here)),
-                        "BASELINE.json")
-
-
-def _baseline_ratios(tier: str) -> dict:
-    path = _baseline_path()
-    key = f"{path}|{tier}"
-    cached = _BASELINE_CACHE.get(key)
-    if cached is None:
-        ratios = {}
-        try:
-            with open(path) as f:
-                floors = json.load(f).get("regression_floors", {})
-            tbl = floors.get(tier, {})
-            ratios = {k: float(v) for k, v in tbl.items()
-                      if not k.startswith("_")
-                      and isinstance(v, (int, float))}
-        except (OSError, ValueError):
-            pass
-        cached = _BASELINE_CACHE[key] = ratios
-    return cached
-
-
-def _routing_tier() -> str | None:
-    """Which BASELINE tier drives policy routing, or None for off.
-
-    Default: the ``tpu`` table on TPU backends only. The ``cpu`` table
-    explicitly prices the interpret-mode simulator, not the kernels
-    (BASELINE.json ``_comment``), and the CPU mesh is the test tier —
-    auto-routing there would silently turn every fused-path test into
-    an XLA test. ``TDT_BASELINE_ROUTING`` overrides: ``off``/``0``
-    disables everywhere, ``tpu``/``cpu`` forces that table (the test
-    hook for exercising the policy on the CPU mesh)."""
-    env = os.environ.get("TDT_BASELINE_ROUTING", "").strip().lower()
-    if env in ("off", "0", "none"):
-        return None
-    if env in ("tpu", "cpu"):
-        return env
-    tier = _platform_tier()
-    return "tpu" if tier == "tpu" else None
-
-
-def policy_reason(op: str) -> str | None:
-    """Non-None iff the active perf data says this op's fused kernel
-    is clearly slower than XLA in the active tier.
-
-    Two data sources, freshest first (docs/resilience.md "Live ratios
-    vs BASELINE floors"):
-
-    1. **Live measured ratio** (``obs.perfwatch``): rolling medians of
-       the wall times the ``@resilient`` entries themselves recorded,
-       consulted once BOTH branches carry
-       ``TDT_PERFWATCH_MIN_SAMPLES`` samples — a chip run
-       self-corrects a stale floor without a redeploy.
-       ``TDT_PERFWATCH_ROUTING=0`` opts out.
-    2. **Static BASELINE floor**: the ``regression_floors`` table is a
-       CI gate that deliberately sits just UNDER the measured ratios
-       (BASELINE.json ``_comment``), so a floor slightly below 1.0 can
-       belong to an op that actually measures faster than XLA (r5
-       gemm_ar: floor 0.95, measured 1.065×).
-
-    Both compare against ``TDT_POLICY_THRESHOLD`` (default 0.9): route
-    to XLA only below it, treat [threshold, ∞) as parity-or-better —
-    the parity margin floors need because they understate measured
-    ratios (live medians don't, but one threshold keeps the policy
-    legible). Every decision's provenance counts into
-    ``resilience.policy_source.{live,floor}`` (plus per-op twins), so
-    the floor→live switchover is observable."""
-    tier = _routing_tier()
-    if tier is None:
-        return None
-    thr = float(os.environ.get("TDT_POLICY_THRESHOLD", "0.9"))
-    from triton_dist_tpu.obs import perfwatch
-    if perfwatch.routing_enabled():
-        live = perfwatch.ratio(op)
-        if live is not None:
-            obs.counter("resilience.policy_source.live").inc()
-            obs.counter(f"resilience.{op}.policy_source.live").inc()
-            if live < thr:
-                return (f"live {op}_vs_xla={round(live, 4)} < {thr} "
-                        f"(perfwatch median)")
-            return None
-    ratio = _baseline_ratios(tier).get(f"{op}_vs_xla")
-    if ratio is None:
-        return None
-    obs.counter("resilience.policy_source.floor").inc()
-    obs.counter(f"resilience.{op}.policy_source.floor").inc()
-    if ratio < thr:
-        return f"{op}_vs_xla={ratio} < {thr} ({tier})"
-    return None
-
-
 # ---------------------------------------------------------------------------
 # The routing decision.
 # ---------------------------------------------------------------------------
@@ -251,8 +134,6 @@ def decide(op: str, key: str) -> str | None:
         return None
     if key in knownbad.get_cache():
         return "known_bad"
-    if policy_reason(op) is not None:
-        return "policy"
     if not get_breaker(op).allow():
         return "breaker"
     return None
@@ -419,49 +300,6 @@ def _is_tracing(bound: inspect.BoundArguments) -> bool:
     return False
 
 
-def _shape_bucket(bound: inspect.BoundArguments) -> str:
-    """Perfwatch pooling key for this call: the pow2-rounded shape
-    signature of its array operands (``ops.common.shape_bucket``) —
-    coarser than the resilience config key on purpose."""
-    from triton_dist_tpu.ops.common import shape_bucket
-    arrays = []
-    for v in bound.arguments.values():
-        if hasattr(v, "shape") and hasattr(v, "dtype"):
-            arrays.append(v)
-        elif (isinstance(v, (list, tuple)) and v
-              and all(hasattr(e, "shape") and hasattr(e, "dtype")
-                      for e in v)):
-            arrays.extend(v)
-    return shape_bucket(*arrays)
-
-
-def _elapsed_ms(t0: float, out) -> float | None:
-    """Wall time since ``t0`` with ``out`` materialized first (so the
-    sample is device time, not async-dispatch time — the same
-    observer cost the engine spans document); None when blocking
-    fails. Observation only: never raises."""
-    try:
-        import jax
-        jax.block_until_ready(out)
-        return (time.perf_counter() - t0) * 1e3
-    except Exception:  # noqa: BLE001 — observation only
-        return None
-
-
-def _record_sample(op: str, branch: str, bound, t0: float, out) -> None:
-    """One live perf sample for an EAGER op call, into
-    ``obs.perfwatch``. Telemetry must never break the call it
-    measures."""
-    ms = _elapsed_ms(t0, out)
-    if ms is None:
-        return
-    try:
-        from triton_dist_tpu.obs import perfwatch
-        perfwatch.record(op, branch, _shape_bucket(bound), ms)
-    except Exception:  # noqa: BLE001 — observation only
-        pass
-
-
 def _op_annotation(op: str, impl, fallback_impl):
     """xprof ``TraceAnnotation`` labeling this invocation's branch —
     ``device.<op>.fused`` / ``device.<op>.xla`` — the label
@@ -529,16 +367,6 @@ def resilient(op: str, *, fused_impls: tuple[str, ...] = ("pallas",),
                 # Let the entry raise its own signature error.
                 return fn(*args, **kwargs)
             if bound.arguments.get("impl") not in fused_impls:
-                # Untouched by routing — but an explicit eager call of
-                # the reference path is a live "xla" sample for the
-                # perf watch (tests, benches, and direct users are the
-                # main source of reference-branch wall times).
-                if (bound.arguments.get("impl") == fallback_impl
-                        and obs.enabled() and not _is_tracing(bound)):
-                    t0 = time.perf_counter()
-                    out = fn(*args, **kwargs)
-                    _record_sample(op, "xla", bound, t0, out)
-                    return out
                 return fn(*args, **kwargs)
             config = (config_fn(bound) if config_fn
                       else _default_config(bound, env_keys))
@@ -559,35 +387,7 @@ def resilient(op: str, *, fused_impls: tuple[str, ...] = ("pallas",),
 
             reason = decide(op, key)
             if reason is not None:
-                if reason == "policy":
-                    from triton_dist_tpu.obs import perfwatch
-                    from triton_dist_tpu.resilience.breaker import (
-                        CLOSED)
-                    # Exploration probe (the policy-route analog of
-                    # the breaker's half-open): every Nth
-                    # policy-routed call runs the fused branch anyway,
-                    # so fused medians stay fresh and a recovered
-                    # kernel can route back in — never for known-bad
-                    # routes (decide() ordered them first), and only
-                    # while the breaker is fully CLOSED: decide()
-                    # checks policy before the breaker, so "policy"
-                    # can mask a breaker that is open over real infra
-                    # failures, and a probe must not re-enter those
-                    # (nor steal the half-open state's single-probe
-                    # slot).
-                    if (perfwatch.routing_enabled()
-                            and get_breaker(op).state == CLOSED
-                            and perfwatch.take_probe(op)):
-                        obs.counter(
-                            f"resilience.{op}.policy_probes").inc()
-                        return _guarded(op, key, config, call,
-                                        bound, fallback_impl)
                 count_fallback(op, reason)
-                if obs.enabled() and not _is_tracing(bound):
-                    t0 = time.perf_counter()
-                    out = call(fallback_impl)
-                    _record_sample(op, "xla", bound, t0, out)
-                    return out
                 return call(fallback_impl)
             return _guarded(op, key, config, call,
                             bound, fallback_impl)
@@ -607,8 +407,6 @@ def _guarded(op, key, config, call, bound, fallback_impl):
     obs.counter(f"resilience.{op}.fused_total").inc()
     tracing = _is_tracing(bound)
     timeout = compile_timeout_s()
-    rec = not tracing and obs.enabled()
-    t0 = time.perf_counter() if rec else 0.0
     try:
         f = faults.take("comm_error", op) if faults.active() else None
         if f is not None:
@@ -632,11 +430,6 @@ def _guarded(op, key, config, call, bound, fallback_impl):
             out = run_with_timeout(thunk, timeout, op=op, key=key)
         else:
             out = call(fused_impl)
-        # Stop the fused clock HERE: the numeric guard below is
-        # measurement overhead the xla branch never pays — timing it
-        # into the fused median would bias live ratios low and route
-        # ops to XLA on observer cost, not kernel performance.
-        fused_ms = _elapsed_ms(t0, out) if rec else None
         if not tracing:
             f = (faults.take("nan_payload", op)
                  if faults.active() else None)
@@ -649,20 +442,15 @@ def _guarded(op, key, config, call, bound, fallback_impl):
             raise
         _record_failure(op, key, config, e)
         if force_fused():
-            # Bench/smoke set TDT_FORCE_FUSED precisely so a run can
-            # never silently measure the XLA fallback while claiming
-            # to measure the fused kernel — the failure is recorded
+            # Smoke runs set TDT_FORCE_FUSED precisely so a run can
+            # never silently exercise the XLA fallback while claiming
+            # to exercise the fused kernel — the failure is recorded
             # (breaker, known-bad, counters) and then SURFACES.
             raise
         reason = ("watchdog" if isinstance(e, CompileTimeout)
                   else "nonfinite" if isinstance(e, NonFiniteOutput)
                   else "error")
         count_fallback(op, reason)
-        if rec:
-            t1 = time.perf_counter()
-            out = call(fallback_impl)
-            _record_sample(op, "xla", bound, t1, out)
-            return out
         return call(fallback_impl)
     if not tracing:
         # Only a real execution proves anything: a successful TRACE
@@ -671,24 +459,14 @@ def _guarded(op, key, config, call, bound, fallback_impl):
         # the watchdog) nor close a half-open breaker.
         _COMPILED.add(key)
         get_breaker(op).record_success()
-        if rec and fused_ms is not None:
-            try:
-                from triton_dist_tpu.obs import perfwatch
-                perfwatch.record(op, "fused", _shape_bucket(bound),
-                                 fused_ms)
-            except Exception:  # noqa: BLE001 — observation only
-                pass
     return out
 
 
 def reset_router() -> None:
-    """Drop router process state (tests): compiled-key set, baseline
-    cache, breakers, known-bad singleton, live perf-ratio windows. The
-    fallback registry is code-derived and survives."""
-    from triton_dist_tpu.obs import perfwatch
+    """Drop router process state (tests): compiled-key set, breakers,
+    known-bad singleton. The fallback registry is code-derived and
+    survives."""
     from triton_dist_tpu.resilience.breaker import reset_breakers
     _COMPILED.clear()
-    _BASELINE_CACHE.clear()
     reset_breakers()
     knownbad.reset_cache()
-    perfwatch.reset()

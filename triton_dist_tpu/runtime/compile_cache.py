@@ -1,6 +1,6 @@
 """Where the persistent XLA compilation cache lives — the one rule every
 entry point (``chip_smoke.py``, ``tdt-serve``, ``tdt-finetune``,
-``bench.py``, ``tpu_smoke.py``) applies before its first compile.
+``tpu_smoke.py``) applies before its first compile.
 
 The path is part of the cache key, so it must never move between runs:
 

@@ -15,8 +15,7 @@ requests across them (per-endpoint timeouts — the same ``timeout=``
 machinery, applied per connection); ``health()`` speaks the server's
 cheap ``{"cmd": "health"}`` verb; ``fanout(endpoints=[...])``
 round-robins a request list across replicas — the client-side fanout
-behind ``bench.py``'s ``serving_fleet`` part and
-``obs.fleet.FleetView``'s concurrent scrapes.
+behind ``obs.fleet.FleetView``'s concurrent scrapes.
 
 Fault awareness (ISSUE 15): multi-endpoint round-robin skips
 endpoints whose last round trip died at the socket level and retries
@@ -327,14 +326,13 @@ def fanout(host: str | None = None, port: int | None = None,
     request order. A request that fails client-side (timeout, refused
     connection) yields an ``{"error", "type"}`` dict in its slot, so
     the caller can count failures without unwinding the others. This
-    is the concurrent-client helper behind bench.py's
-    ``serving_throughput`` probe and the scheduler load tests.
+    is the concurrent-client helper behind the scheduler load tests.
 
     ``endpoints=[...]`` replaces ``host``/``port`` with a replica
     list: request ``i`` goes to ``endpoints[i % len(endpoints)]`` —
-    the client-side round-robin the ``serving_fleet`` bench and
-    ``obs.fleet.FleetView`` ride (per-request timeout, so one wedged
-    replica cannot stall the other slots). A slot whose endpoint
+    the client-side round-robin ``obs.fleet.FleetView`` rides
+    (per-request timeout, so one wedged replica cannot stall the other
+    slots). A slot whose endpoint
     fails client-side is retried ONCE on the next endpoint that no
     sibling slot has seen die (ISSUE 15): a replica death mid-fanout
     costs one retry, and cannot be mis-attributed as a client

@@ -1,12 +1,10 @@
-"""Render bench / sweep JSON into markdown tables.
+"""Render sweep rows and metrics snapshots into markdown tables.
 
-Consumes ``bench.py``'s one-line JSON (``--bench``) and/or
-``tools/bench_ops.py`` JSONL (``--sweep``); the reference publishes its
-numbers as rendered tables (README.md:96-205) — this is the generator
-for ours.
+The command line takes ``tools/bench_ops.py`` JSONL (``--sweep``);
+:func:`render_telemetry` renders an obs snapshot (the server's
+``{"cmd": "metrics"}`` payload) section by section.
 
 Usage:
-    python -m triton_dist_tpu.tools.report --bench bench_result.json
     python -m triton_dist_tpu.tools.report --sweep sweep.jsonl
 """
 
@@ -15,38 +13,6 @@ from __future__ import annotations
 import argparse
 import json
 import sys
-
-
-def render_bench(d: dict) -> str:
-    ex = dict(d.get("extras", {}))
-    telemetry = ex.pop("telemetry", None)
-    if d.get("prior_value") is not None:
-        # Probe-failure lines keep value null and carry the last good
-        # run under explicitly-prior fields — render those, not a
-        # "None ... (vs_baseline None)" head.
-        prov = d.get("from_prior_run", {})
-        head = (f"**{d.get('metric')}** = (this run measured nothing) "
-                f"— prior_value {d['prior_value']} {d.get('unit', '')} "
-                f"(prior_vs_baseline {d.get('prior_vs_baseline')}, "
-                f"age {prov.get('age_s', '?')}s, "
-                f"{prov.get('path', '?')})")
-    else:
-        head = (f"**{d.get('metric')}** = {d.get('value')} "
-                f"{d.get('unit', '')} "
-                f"(vs_baseline {d.get('vs_baseline')})")
-    lines = [head, ""]
-    groups: dict[str, dict] = {}
-    for k, v in ex.items():
-        op = k.split("_")[0] if "_" in k else k
-        groups.setdefault(op, {})[k] = v
-    lines.append("| key | value |")
-    lines.append("|---|---|")
-    for op in sorted(groups):
-        for k in sorted(groups[op]):
-            lines.append(f"| {k} | {groups[op][k]} |")
-    if telemetry:
-        lines += ["", render_telemetry(telemetry)]
-    return "\n".join(lines)
 
 
 _BREAKER_STATES = {0: "closed", 1: "OPEN", 2: "half-open"}
@@ -177,12 +143,11 @@ def render_disagg(snap: dict) -> str:
 
 def render_fleet(merged: dict | None) -> str:
     """Summarize a fleet-merged snapshot (``obs.fleet.
-    merge_fleet_snapshots`` — bench.py's ``serving_fleet`` part embeds
-    one under ``extras.telemetry.fleet``; docs/observability.md
-    "Fleet view"): the replica roster, per-replica queue/occupancy/
-    rolling-p99 rows, and the fleet rollup with BUCKET-MERGED TTFT/
-    TPOT percentiles. Empty string when no merged snapshot is
-    present."""
+    merge_fleet_snapshots``, under the snapshot's ``fleet`` key;
+    docs/observability.md "Fleet view"): the replica roster,
+    per-replica queue/occupancy/rolling-p99 rows, and the fleet rollup
+    with BUCKET-MERGED TTFT/TPOT percentiles. Empty string when no
+    merged snapshot is present."""
     if not merged or not merged.get("replicas"):
         return ""
     per = merged.get("per_replica", {})
@@ -224,9 +189,8 @@ def render_fleet(merged: dict | None) -> str:
 
 def render_router(status: dict | None) -> str:
     """Summarize a router-status payload (``RouterServer.status()`` —
-    the ``serving_router`` bench embeds one under
-    ``extras.telemetry.router``; a router's ``{"cmd": "metrics"}``
-    snapshot carries it under ``router``): per-replica placement rows
+    a router's ``{"cmd": "metrics"}`` snapshot carries it under
+    ``router``): per-replica placement rows
     with the router's breaker / in-flight / draining dimension, plus
     the failover and shed counters a failover postmortem reads first
     (docs/serving.md "Router"). Empty string when absent."""
@@ -268,8 +232,8 @@ def render_router(status: dict | None) -> str:
 def render_tracing(stats: dict | None) -> str:
     """Summarize the event-tracing / flight-recorder state
     (``obs.trace.stats()``, carried under the snapshot's ``trace`` key
-    by the server metrics command and bench extras —
-    docs/observability.md "Tracing"): events captured, events dropped
+    by the server metrics command — docs/observability.md
+    "Tracing"): events captured, events dropped
     to ring overwrites, and the last flight-record path so a
     postmortem reader knows which file to open in Perfetto. Empty
     string when the payload carries no tracing stats."""
@@ -296,31 +260,9 @@ def render_tracing(stats: dict | None) -> str:
     return "\n".join(lines)
 
 
-def render_waterfalls(wf: dict | None) -> str:
-    """Render sampled request-attribution waterfalls (``obs.attrib``
-    records bench.py embeds under ``extras.telemetry.waterfalls``):
-    where one request's TTFT went — queue vs prefill vs decode — next
-    to the aggregate numbers."""
-    if not wf:
-        return ""
-    lines = ["#### request waterfalls",
-             "| part | total_ms | queue_wait | prefill | decode | "
-             "tokens | cached |", "|---|---|---|---|---|---|---|"]
-    for part in sorted(wf):
-        r = wf[part] or {}
-        seg = r.get("segments", {})
-        lines.append(
-            f"| {part} | {r.get('total_ms')} | "
-            f"{seg.get('queue_wait_ms')} | {seg.get('prefill_ms')} | "
-            f"{seg.get('decode_ms')} | {r.get('tokens')} | "
-            f"{r.get('cached_tokens')} |")
-    return "\n".join(lines)
-
-
 def render_history(hist: dict | None) -> str:
-    """Summarize a sampled-history snapshot (``obs.history`` — the
-    ``serving_history`` bench embeds one under
-    ``extras.telemetry.history``; docs/observability.md "History
+    """Summarize a sampled-history snapshot (``obs.history``, under
+    the snapshot's ``history`` key; docs/observability.md "History
     plane"): per-series stats with a unicode sparkline, plus every
     retained early-warning excerpt. Empty string when no series were
     sampled."""
@@ -398,8 +340,8 @@ def render_devprof(snap: dict, stats: dict | None = None) -> str:
 
 
 def render_telemetry(snap: dict) -> str:
-    """Render an obs snapshot (bench ``extras.telemetry`` / server
-    ``{"cmd": "metrics"}`` payload — docs/observability.md) as
+    """Render an obs snapshot (the server's ``{"cmd": "metrics"}``
+    payload — docs/observability.md) as
     markdown: one counters/gauges table, one histogram summary table,
     plus dedicated resilience and tracing sections when those exist."""
     lines = ["### telemetry"]
@@ -411,7 +353,6 @@ def render_telemetry(snap: dict) -> str:
     router = render_router(snap.get("router"))
     tracing = render_tracing(snap.get("trace"))
     devprof = render_devprof(snap, snap.get("devprof"))
-    waterfalls = render_waterfalls(snap.get("waterfalls"))
     history = render_history(snap.get("history"))
     # trace.* gauges mirror what the tracing section already shows
     # (they exist for the Prometheus exposition path) — don't render
@@ -454,8 +395,6 @@ def render_telemetry(snap: dict) -> str:
         lines += [tracing, ""]
     if devprof:
         lines += [devprof, ""]
-    if waterfalls:
-        lines += [waterfalls, ""]
     if history:
         lines += [history, ""]
     if scalars:
@@ -501,28 +440,15 @@ def render_sweep(rows: list[dict]) -> str:
 
 def main(argv=None):
     ap = argparse.ArgumentParser()
-    ap.add_argument("--bench", default=None)
-    ap.add_argument("--sweep", default=None)
+    ap.add_argument("--sweep", required=True)
     args = ap.parse_args(argv)
-    if not (args.bench or args.sweep):
-        ap.error("need --bench and/or --sweep")
-    if args.bench:
-        with open(args.bench) as f:
-            d = json.load(f)
-        if "metric" not in d and "tail" in d:
-            # driver BENCH_r{N}.json wraps the emitted line in `tail`
-            line = [ln for ln in d["tail"].splitlines()
-                    if ln.startswith("{")]
-            d = json.loads(line[-1]) if line else d
-        print(render_bench(d))
-    if args.sweep:
-        rows = []
-        with open(args.sweep) as f:
-            for line in f:
-                line = line.strip()
-                if line:
-                    rows.append(json.loads(line))
-        print(render_sweep(rows))
+    rows = []
+    with open(args.sweep) as f:
+        for line in f:
+            line = line.strip()
+            if line:
+                rows.append(json.loads(line))
+    print(render_sweep(rows))
     return 0
 
 

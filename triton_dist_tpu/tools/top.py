@@ -6,9 +6,8 @@ the pump keeps the gauges fresh while it works, and the ``health``
 verb's seq/uptime header says how fresh) plus ``{"cmd":
 "request_stats"}`` and renders one refresh-loop screen: rolling
 p50/p99 latencies, per-target burn rates with breach flags, batch
-occupancy / queue depth, KV block-pool utilization, per-op live
-fused-vs-XLA ratios (``obs.perfwatch``), and the freshest request
-waterfalls (``obs.attrib``) — the terminal answer to "is serving
+occupancy / queue depth, KV block-pool utilization, and the freshest
+request waterfalls (``obs.attrib``) — the terminal answer to "is serving
 healthy right now and where is the latency going", no Perfetto dump
 required (docs/observability.md "SLOs and burn rates").
 
@@ -143,14 +142,6 @@ def render(snap: dict) -> str:
                            f"{_fmt(g['trace.dropped_total'])} "
                            f"(raise TDT_TRACE_RING)"))
     _rows(lines, "batch / pool", batch_rows)
-
-    ratio_rows = []
-    for k in sorted(g):
-        if k.startswith("resilience.perfwatch.") \
-                and k.endswith(".live_ratio"):
-            op = k[len("resilience.perfwatch."):-len(".live_ratio")]
-            ratio_rows.append((op, f"{_fmt(g[k])}x vs xla (live)"))
-    _rows(lines, "live op ratios", ratio_rows)
 
     # Device-time truth (obs.devprof): measured per-op attribution
     # from parsed jax.profiler captures, drift vs the modeled gauge,
