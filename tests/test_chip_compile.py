@@ -244,3 +244,18 @@ def test_decode_step_and_admission_write_the_caches_in_place(
         # The real step: its gemm_ar kernels (xla_ar prefill has none).
         assert ("tpu_custom_call" in text) == (name == "step")
         assert not re.findall(r"\[8,4096,8,128\]\S* copy\(", entry), name
+        if name != "step":
+            continue
+        # The step's attention picks its window in the graph (ISSUE 33): a
+        # loop over 512-position chunks and, past half of the cache, the
+        # whole read, one ``conditional`` per layer. Neither may copy a
+        # cache leaf or a chunk of one: a ``copy`` of that shape outside a
+        # fusion is materialised (a static slice under ``lax.switch`` cost
+        # one whole-leaf layout copy per branch here), inside one it is the
+        # operand layout of the contraction that consumes it.
+        assert len(re.findall(r" conditional\(", text)) == layers
+        loose = [head.split()[0] for head, body in re.findall(
+            r"^((?:ENTRY )?%\S+ [^\n]*\{)\n(.*?)^\}", text, re.M | re.S)
+            if "fused_computation" not in head
+            and re.search(r"\[8,(?:4096|512),8,128\]\S* copy\(", body)]
+        assert not loose, loose

@@ -230,3 +230,39 @@ def test_only_a_sampling_engine_splits_the_key_in_an_admission(
         np.asarray(eng.key),
         np.asarray(jax.random.split(jnp.asarray(key1))[0]))
     sess.close()
+
+
+# -- (d) one decode program, whatever window its attention reads -----------
+
+def test_one_step_program_serves_every_window(key):
+    """The stream step picks its attention window in the graph (ISSUE
+    33): steps whose live rows need one chunk and steps that need the
+    whole cache are the same compiled program, one launch each."""
+    mesh = Mesh(np.array(jax.devices()[:1]), ("tp",))
+    cfg = ModelConfig(hidden_size=32, intermediate_size=64,
+                      num_hidden_layers=1, num_attention_heads=4,
+                      num_key_value_heads=2, head_dim=8, vocab_size=64,
+                      max_position_embeddings=1024, dtype=jnp.float32)
+    model = DenseLLM(cfg, mesh=mesh, axis="tp", impl="xla")
+    eng = Engine(model, batch=2, max_seq=1024, prefill_mode="xla_ar",
+                 decode_mode="gemm_ar")
+    sess = eng.stream_session(model.init(key))
+    sess.prefill_into_row(0, list(range(1, 63)) * 8 + [5] * 14,
+                          gen_budget=8)          # 510 tokens
+    step, launches = eng._stream_step, []
+
+    def counting(*args):
+        launches.append(int(np.asarray(args[3]).max()))   # offsets
+        return step(*args)
+
+    eng._stream_step = counting
+    try:
+        for _ in range(4):
+            sess.decode_step()
+    finally:
+        eng._stream_step = step
+    sess.close()
+    # 510, 511 read one chunk; 512, 513 the whole cache: four launches
+    # of one executable.
+    assert launches == [510, 511, 512, 513]
+    assert step._cache_size() == 1

@@ -30,6 +30,33 @@ from triton_dist_tpu.ops.gemm_reduce_scatter import create_gemm_rs_context
 from triton_dist_tpu.ops.autodiff import ag_gemm_multi, gemm_rs, gemm_ar
 
 
+# The decode window: the attention of a call that says how many cache
+# positions its live queries can see (``kv_need``) reads the cache in
+# chunks of _WINDOW_CHUNK positions and stops at the chunk that covers
+# them. A position read through the loop costs about 1.5x what it costs
+# in the one fused whole-cache read (v5e, PERF.md PR 33), so past half
+# of the cache the whole read is as cheap and is taken. A module
+# constant, no knob.
+_WINDOW_CHUNK = 512
+
+
+def window_chunks(need, t: int):
+    """How many leading chunks of a ``t``-position cache cover ``need``
+    positions, or 0 where they pass half of it and the whole-cache read
+    runs instead. ``need`` is a host int or a traced int32 scalar: the
+    same arithmetic serves the step program and the session's host
+    shadow of it. Always 0 for ``t < 1024``: such a cache has one
+    program, the unbounded one."""
+    n = (need + _WINDOW_CHUNK - 1) // _WINDOW_CHUNK
+    return n * (2 * n * _WINDOW_CHUNK <= t)
+
+
+def decode_window(need: int, t: int) -> int:
+    """The cache positions a bounded read of ``need`` touches (host
+    ints): a multiple of the chunk, or ``t``."""
+    return int(window_chunks(need, t)) * _WINDOW_CHUNK or t
+
+
 class TPAttn:
     """GQA attention under TP. No QKV bias (Qwen3 dropped it)."""
 
@@ -94,7 +121,8 @@ class TPAttn:
                  rope_cache: RopeCache,
                  kv_cache: tuple[jax.Array, jax.Array],
                  offset: jax.Array, mode: str | None = None,
-                 kv_start: jax.Array | None = None):
+                 kv_start: jax.Array | None = None,
+                 kv_need: jax.Array | None = None):
         """One attention block.
 
         Args:
@@ -106,6 +134,10 @@ class TPAttn:
           offset: int32 write position into the cache — scalar, or a
             (B,) per-row vector when S == 1 (continuous batching;
             see _attention_core).
+          kv_need: optional traced int32 scalar, the number of cache
+            positions any LIVE query of this call may see; bounds the
+            attention's read of the cache (see _attention_core). Only
+            the stream decode step passes it.
         Returns:
           (out, (k_cache, v_cache)): out has the same layout as x.
         """
@@ -136,7 +168,7 @@ class TPAttn:
         k = apply_rope(k, rope_cache, position_ids)
 
         attn, new_cache = self._attention(q, k, v, kv_cache, offset,
-                                          kv_start)
+                                          kv_start, kv_need)
         attn = attn.reshape(b * s, self.num_heads * d)
 
         if sharded:
@@ -145,7 +177,8 @@ class TPAttn:
             out = gemm_ar(attn, params["w_o"], self.rs_ctx, impl=impl)
         return out, new_cache
 
-    def _attention(self, q, k, v, kv_cache, offset, kv_start=None):
+    def _attention(self, q, k, v, kv_cache, offset, kv_start=None,
+                   kv_need=None):
         """Cached GQA attention, shard_mapped over the head axis.
 
         Equivalent role to the reference's flash-attn call on local heads
@@ -157,18 +190,22 @@ class TPAttn:
         spec = P(None, None, axis, None)
         if kv_start is None:
             kv_start = jnp.zeros((q.shape[0],), jnp.int32)
+        # kv_need rides along as one more replicated scalar, and only
+        # when given: without it the traced program is unchanged.
+        bound = () if kv_need is None else (jnp.asarray(kv_need, jnp.int32),)
         f = nestable_shard_map(
             core, mesh=self.mesh,
-            in_specs=(spec, spec, spec, spec, spec, P(), P()),
+            in_specs=(spec, spec, spec, spec, spec, P(), P())
+            + (P(),) * len(bound),
             out_specs=(spec, spec, spec), check_vma=False)
         out, ck, cv = f(q, k, v, kv_cache[0], kv_cache[1],
                         jnp.asarray(offset, jnp.int32),
-                        jnp.asarray(kv_start, jnp.int32))
+                        jnp.asarray(kv_start, jnp.int32), *bound)
         return out, (ck, cv)
 
 
-def _attention_core(q, k, v, cache_k, cache_v, offset, kv_start, *,
-                    groups: int):
+def _attention_core(q, k, v, cache_k, cache_v, offset, kv_start,
+                    kv_need=None, *, groups: int):
     """Single-device cached causal GQA (fp32 softmax).
 
     q: (B, S, hq, D); k/v: (B, S, hkv, D); cache: (B, T, hkv, D).
@@ -185,10 +222,27 @@ def _attention_core(q, k, v, cache_k, cache_v, offset, kv_start, *,
     step) or a burst of S positions offset[b]+[0, S) (the speculative-
     decoding verify window, Engine spec steps; out-of-range positions
     are dropped by the scatter, which only frozen rows near max_seq
-    ever produce)."""
-    b, s, hq, d = q.shape
+    ever produce).
+
+    ``kv_need`` (optional traced int32 scalar) BOUNDS THE READ: the
+    number of cache positions any live query of this call may see, max
+    over live rows of offset + S. The K/V write goes to the full cache
+    as always. The read then runs chunk by chunk (``window_chunks``: a
+    loop whose trip count is traced, so one program serves every
+    length and nothing is decided on the host) over the leading chunks
+    that cover ``kv_need``: scores per chunk, ONE mask and fp32 softmax
+    over all of them, probs x V per chunk. The positions left out are
+    positions the causal mask zeroes for every live row, so a live
+    row's output is the unbounded call's up to the order of an fp32
+    sum. Past half of the cache the unbounded read itself runs (a
+    ``lax.cond``). A FROZEN row whose stale offset lies beyond the
+    window attends to whatever the window holds of its lane and gets
+    finite garbage, like a fully-masked pad row; its caller (the
+    stream step's ``where(done, token, nxt)``) already discards it.
+    ``None`` — every caller but the stream decode step — and any
+    ``T < 1024`` trace today's program over the whole cache."""
+    b, s = q.shape[:2]
     t = cache_k.shape[1]
-    hkv = cache_k.shape[2]
     if offset.ndim == 0:
         cache_k = lax.dynamic_update_slice(cache_k, k, (0, offset, 0, 0))
         cache_v = lax.dynamic_update_slice(cache_v, v, (0, offset, 0, 0))
@@ -209,21 +263,87 @@ def _attention_core(q, k, v, cache_k, cache_v, offset, kv_start, *,
             cache_v = cache_v.at[rows[:, None], pos].set(v)
         off_b = offset
 
+    max_chunks = t // (2 * _WINDOW_CHUNK)
+    if kv_need is None or max_chunks == 0:
+        out = _attend(q, cache_k, cache_v, off_b, kv_start, groups)
+    else:
+        n = window_chunks(kv_need, t)
+        out = lax.cond(
+            n > 0,
+            lambda *read: _attend_chunks(*read, n, groups, max_chunks),
+            lambda *read: _attend(*read, groups),
+            q, cache_k, cache_v, off_b, kv_start)
+    return out, cache_k, cache_v
+
+
+def _scores_dtype(q, cache_k):
     # Contractions run in the cache dtype when q matches it (MXU-native
     # bf16 is up to 3x an f32 matmul; f32 accumulation keeps scores
     # bit-identical to an upcast-first dot — r4, same treatment as
     # ops/flash_decode). Mismatched precision keeps the exact f32 path.
-    dt = cache_k.dtype if q.dtype == cache_k.dtype else jnp.float32
-    qg = q.reshape(b, s, hkv, groups, d).astype(dt)
-    scores = jnp.einsum("bskgd,btkd->bkgst", qg, cache_k.astype(dt),
-                        preferred_element_type=jnp.float32) * (d ** -0.5)
+    return cache_k.dtype if q.dtype == cache_k.dtype else jnp.float32
+
+
+def _masked_softmax(scores, off_b, kv_start):
+    """Causal + left-pad mask and fp32 softmax over the last axis of
+    ``scores`` (B, hkv, G, S, T'): position j is visible to query i of
+    row b iff kv_start[b] <= j <= off_b[b] + i."""
+    s, t = scores.shape[-2:]
     q_pos = off_b[:, None, None] + jnp.arange(s)[None, :, None]  # (B,S,1)
     causal = jnp.arange(t)[None, None, :] <= q_pos  # (B, S, T)
     live = jnp.arange(t)[None, :] >= kv_start[:, None]  # (B, T)
     mask = causal & live[:, None]  # (B, S, T)
     scores = jnp.where(mask[:, None, None], scores, -1e30)
-    probs = jax.nn.softmax(scores, axis=-1)
+    return jax.nn.softmax(scores, axis=-1)
+
+
+def _attend(q, cache_k, cache_v, off_b, kv_start, groups: int):
+    """The read half of :func:`_attention_core` over the whole cache:
+    scores, mask, fp32 softmax, probs x V. ``off_b``: (B,) position of
+    query 0."""
+    b, s, hq, d = q.shape
+    hkv = cache_k.shape[2]
+    dt = _scores_dtype(q, cache_k)
+    qg = q.reshape(b, s, hkv, groups, d).astype(dt)
+    scores = jnp.einsum("bskgd,btkd->bkgst", qg, cache_k.astype(dt),
+                        preferred_element_type=jnp.float32) * (d ** -0.5)
+    probs = _masked_softmax(scores, off_b, kv_start)
     out = jnp.einsum("bkgst,btkd->bskgd", probs.astype(dt),
                      cache_v.astype(dt),
                      preferred_element_type=jnp.float32)
-    return out.reshape(b, s, hq, d).astype(q.dtype), cache_k, cache_v
+    return out.reshape(b, s, hq, d).astype(q.dtype)
+
+
+def _attend_chunks(q, cache_k, cache_v, off_b, kv_start, n, groups: int,
+                   max_chunks: int):
+    """:func:`_attend` over the first ``n`` (traced, 1..max_chunks)
+    chunks of the cache. Scores of chunk i land in columns
+    [i*C, (i+1)*C) of one (.., max_chunks*C) buffer; the columns of
+    chunks not read stay 0 and lie beyond every live row's causal
+    mask, so the softmax is the whole-cache one."""
+    b, s, hq, d = q.shape
+    hkv, c = cache_k.shape[2], _WINDOW_CHUNK
+    dt = _scores_dtype(q, cache_k)
+    qg = q.reshape(b, s, hkv, groups, d).astype(dt)
+
+    def chunk(cache, i):
+        return lax.dynamic_slice_in_dim(cache, i * c, c, 1).astype(dt)
+
+    def score(i, scores):
+        sc = jnp.einsum("bskgd,btkd->bkgst", qg, chunk(cache_k, i),
+                        preferred_element_type=jnp.float32) * (d ** -0.5)
+        return lax.dynamic_update_slice_in_dim(scores, sc, i * c, 4)
+
+    scores = lax.fori_loop(
+        0, n, score,
+        jnp.zeros((b, hkv, groups, s, max_chunks * c), jnp.float32))
+    probs = _masked_softmax(scores, off_b, kv_start).astype(dt)
+
+    def weigh(i, acc):
+        return acc + jnp.einsum(
+            "bkgst,btkd->bskgd", lax.dynamic_slice_in_dim(probs, i * c, c, 4),
+            chunk(cache_v, i), preferred_element_type=jnp.float32)
+
+    out = lax.fori_loop(0, n, weigh,
+                        jnp.zeros((b, s, hkv, groups, d), jnp.float32))
+    return out.reshape(b, s, hq, d).astype(q.dtype)

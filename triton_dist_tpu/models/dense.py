@@ -126,7 +126,7 @@ class DenseLLM:
     # -- forward -----------------------------------------------------------
     def forward(self, params: dict, input_ids: jax.Array, kv_caches,
                 offset, mode: str | None = None, kv_start=None,
-                remat: bool = False, block_table=None):
+                remat: bool = False, block_table=None, kv_need=None):
         """input_ids: (B, S) int32; kv_caches: [(k, v)] * L; offset: scalar
         write position. Returns (logits (B, S, V), new_caches).
 
@@ -141,6 +141,12 @@ class DenseLLM:
         ``remat``: checkpoint each decoder layer — activations are
         recomputed in the backward pass instead of stored, trading
         FLOPs for HBM so long-sequence training fits (models/train.py).
+
+        ``kv_need``: optional traced int32 scalar, how many cache
+        positions the call's live queries can see; every layer's
+        attention then reads only the window that covers them
+        (layers/tp_attn._attention_core). The stream decode step passes
+        it; ``mode="sp"`` has ``kv_len`` of its own and ignores it.
         """
         c = self.config
         mode = mode or self.fwd_mode
@@ -168,7 +174,7 @@ class DenseLLM:
             h = rms_norm(x, lp["ln_attn"], c.rms_norm_eps)
             a, cache = self.attn(lp["attn"], h, position_ids,
                                  self.rope_cache, cache, offset, mode=mode,
-                                 kv_start=kv_start)
+                                 kv_start=kv_start, kv_need=kv_need)
             x = x + a
             h = rms_norm(x, lp["ln_mlp"], c.rms_norm_eps)
             x = x + self.mlp(lp["mlp"], h, mode=mode)

@@ -23,6 +23,7 @@ import numpy as np
 
 from triton_dist_tpu import obs
 from triton_dist_tpu.obs import trace as _trace
+from triton_dist_tpu.layers.tp_attn import decode_window
 from triton_dist_tpu.models.kv_cache import (
     KVCacheLost, KVCacheManager, jit_rewriting_caches)
 
@@ -649,13 +650,24 @@ class Engine:
     def _build_stream_step(self):
         """One decode step with PER-ROW write offsets: each live row
         decodes at its own cache position (frozen rows re-emit their
-        token and do not advance). One compiled program per token."""
+        token and do not advance). One compiled program per token.
+
+        The step tells the attention how far its live rows reach
+        (``kv_need``: the longest LIVE row's offset + 1; a dead row's
+        stale offset must not pick the window), and the attention
+        reads the cache only in the leading chunks that cover them,
+        counted inside this one program
+        (layers/tp_attn._attention_core). A frozen row whose stale
+        offset lies beyond that window computes finite garbage that
+        ``where(done, token, nxt)`` discards: the pad-slot argument of
+        :meth:`_build_admit`, applied to the read."""
         model, mode = self.model, self.decode_mode
 
         @jit_rewriting_caches
         def step(params, caches, token, offsets, key, done, table):
             logits, caches = model.forward(
                 params, token[:, None], caches, offsets, mode=mode,
+                kv_need=jnp.max(jnp.where(done, 0, offsets)) + 1,
                 **({"block_table": table} if table is not None else {}))
             nxt = sample_token(logits[:, -1], key, self.temperature,
                                self.top_k, self.top_p)
@@ -1532,6 +1544,16 @@ class StreamSession:
         done = ~np.asarray(self.live)
         obs.counter(f"engine.decode_path.{kind}").inc()
         obs.counter("engine.decode_live_rows").inc(sum(self.live))
+        if kind == "plain" and eng.decode_mode != "sp":
+            # The cache positions this step's attention reads per row:
+            # the host shadow of the window the step program picks
+            # from the same live offsets (layers/tp_attn.window_chunks;
+            # the mega and sp steps pass no kv_need).
+            need = max((off + 1 for off, live
+                        in zip(self._host_off, self.live) if live),
+                       default=1)
+            obs.counter("engine.decode_window_positions").inc(
+                decode_window(need, eng.kv.max_seq))
         with obs.span("engine.stream_step"):
             # The step still splits its key HERE, eagerly (two small
             # device programs, ~1 ms of dispatch on the v5e, wasted on

@@ -135,7 +135,7 @@ class Qwen3MoE:
     # -- forward -----------------------------------------------------------
     def forward(self, params: dict, input_ids: jax.Array, kv_caches,
                 offset, mode: str | None = None, kv_start=None,
-                block_table=None):
+                block_table=None, kv_need=None):
         """Same contract as DenseLLM.forward; MoE FFN needs the
         row-sharded layout (modes xla / ag_rs)."""
         c = self.config
@@ -177,7 +177,8 @@ class Qwen3MoE:
             h = rms_norm(x, lp["ln_attn"], c.rms_norm_eps)
             a, cache = self.attn(lp["attn"], h, position_ids,
                                  self.rope_cache, cache, offset,
-                                 mode=attn_mode, kv_start=kv_start)
+                                 mode=attn_mode, kv_start=kv_start,
+                                 kv_need=kv_need)
             x = x + a
             h = rms_norm(x, lp["ln_mlp"], c.rms_norm_eps)
             x = x + self.moe(lp["moe"], h, mode=moe_mode)
