@@ -259,3 +259,64 @@ def test_decode_step_and_admission_write_the_caches_in_place(
             if "fused_computation" not in head
             and re.search(r"\[8,(?:4096|512),8,128\]\S* copy\(", body)]
         assert not loose, loose
+
+
+@pytest.mark.parametrize("program", ["step", "admit"])
+def test_exaone_share_programs(meshes, monkeypatch, program):
+    """K-EXAONE-236B-A23B at the benchmark's own shapes (its configuration
+    file: published widths, 16 of 128 experts, 5 layers, 64 rows x 4096):
+    the stream step and a 2048-token admission compile for one v5e, fit
+    it beside their arguments, donate every cache leaf, full layers'
+    (64, 4096) and window layers' (64, 128) rings alike, and hold the
+    held experts' grouped matmuls as XLA's ragged dot."""
+    import json
+    import os
+    from benchmark.harness.builders import exaone
+    from triton_dist_tpu.models import AutoLLM, Engine, ModelConfig
+    monkeypatch.setenv("TDT_FORCE_COMPILED", "1")
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    with open(os.path.join(root, "benchmark", "configs",
+                           "k-exaone-236b-a23b.json")) as f:
+        cfg = json.load(f)
+    mesh = meshes[1]
+    hf = dict(exaone.model_dict(cfg), eos_token_id=None,
+              expert_parallel=cfg["expert_parallel"])
+    llm = AutoLLM.build(ModelConfig.from_hf_config(hf), mesh=mesh,
+                        axis="tp", impl="pallas")
+    eng = Engine(llm, **cfg["engine"])
+
+    def sds(shape, dtype=BF16):
+        return _sds(mesh, shape, P(), dtype)
+
+    params = jax.tree.map(
+        lambda a: sds(a.shape, a.dtype),
+        jax.eval_shape(llm.init, jax.random.PRNGKey(0)))
+    rows, max_seq = cfg["engine"]["batch"], cfg["engine"]["max_seq"]
+    assert llm.windows == (128, 128, 128, None, 128)
+    leaves = [(rows, w or max_seq, 8, 128) for w in llm.windows]
+    caches = [(sds(leaf), sds(leaf)) for leaf in leaves]
+    cache_bytes = sum(2 * 2 * int(np.prod(leaf)) for leaf in leaves)
+    assert cache_bytes == 2 * 2 * rows * 8 * 128 * (4 * 128 + max_seq)
+    i32, counts = jnp.int32, len(eng.count_names)
+    key = sds((2,), jnp.uint32)
+    token, offsets = sds((rows + counts,), i32), sds((rows,), i32)
+    if program == "step":
+        lowered = eng._build_stream_step().lower(
+            params, caches, token, offsets, key, sds((rows,), jnp.bool_),
+            None)
+    else:
+        lowered = eng._build_admit().lower(
+            params, caches, sds((1, 2048), i32), sds((), i32), sds((), i32),
+            token, offsets, key)
+    compiled = lowered.compile()
+    out = compiled.out_info
+    # What the host reads back each call: the tokens (or the first token)
+    # with the program's counts behind them, one vector.
+    assert (out[0].shape, out[0].dtype) == (
+        (rows + counts,) if program == "step" else (1 + counts,), i32)
+    mem = compiled.memory_analysis()
+    assert mem.alias_size_in_bytes == cache_bytes
+    assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < 12e9
+    text = compiled.as_text()
+    assert "ragged-dot" in text
+    assert "threefry" not in lowered.as_text()

@@ -25,8 +25,9 @@ from triton_dist_tpu.ops.common import nestable_shard_map
 
 from triton_dist_tpu.layers.common import shard_param
 from triton_dist_tpu.layers.ep_a2a import EPAll2AllLayer
-from triton_dist_tpu.ops.group_gemm import grouped_expert_ffn
-from triton_dist_tpu.ops.moe_utils import topk_routing
+from triton_dist_tpu.ops.group_gemm import (
+    grouped_expert_ffn, held_expert_ffn)
+from triton_dist_tpu.ops.moe_utils import sigmoid_topk_routing, topk_routing
 
 
 class EPMoE:
@@ -135,3 +136,95 @@ class EPMoE:
 
         out = a2a.combine(expert_out, weights, handle)
         return out[:t] if t_pad != t else out
+
+
+class EPShareMoE:
+    """One expert-parallel rank's share of a sigmoid-routed MoE layer
+    with a shared expert (DeepSeek-V3 / K-EXAONE style), WITHOUT its
+    exchange: the layer is told which experts it holds
+    (``[first_held, first_held + num_held)`` of ``num_experts``), scores
+    and selects over all of them, and computes its own experts' part of
+    every token's result plus the shared expert. That partial result is
+    the layer's output: on one chip of a deployment whose other ranks
+    are absent nothing stands in for them or for their traffic (the
+    exchange that sums the parts across ranks is ROADMAP R1).
+
+    Activations are replicated over ``axis`` (modes ``xla_ar`` /
+    ``gemm_ar``); the held experts' weights are replicated too, the
+    shared expert is a :class:`TPMLP`."""
+
+    def __init__(self, hidden_size: int, intermediate_size: int,
+                 num_experts: int, topk: int, first_held: int,
+                 num_held: int, shared_intermediate_size: int,
+                 mesh: Mesh | None = None, axis: str = "tp",
+                 dtype=jnp.bfloat16, impl: str = "pallas",
+                 norm_topk_prob: bool = True, scale: float = 1.0):
+        from triton_dist_tpu.layers.tp_mlp import TPMLP
+        if mesh is None:
+            from triton_dist_tpu.runtime.dist import get_mesh
+            mesh = get_mesh()
+        assert 0 <= first_held and first_held + num_held <= num_experts
+        self.mesh, self.axis = mesh, axis
+        self.hidden_size = hidden_size
+        self.intermediate_size = intermediate_size
+        self.num_experts, self.topk = num_experts, topk
+        self.first_held, self.num_held = first_held, num_held
+        self.dtype = dtype
+        self.norm_topk_prob, self.scale = norm_topk_prob, scale
+        self.shared = TPMLP(hidden_size, shared_intermediate_size,
+                            mesh=mesh, axis=axis, dtype=dtype, impl=impl)
+
+    def init(self, key: jax.Array) -> dict:
+        kr, kg, ku, kd, ks = jax.random.split(key, 5)
+        h, i, n = self.hidden_size, self.intermediate_size, self.num_held
+        params = {
+            "w_router": jax.random.normal(
+                kr, (h, self.num_experts), jnp.float32) * h**-0.5,
+            "e_bias": jnp.zeros((self.num_experts,), jnp.float32),
+            "w_gate": jax.random.normal(kg, (n, h, i), self.dtype) * h**-0.5,
+            "w_up": jax.random.normal(ku, (n, h, i), self.dtype) * h**-0.5,
+            "w_down": jax.random.normal(kd, (n, i, h), self.dtype) * i**-0.5,
+        }
+        out = self.shard_params(params)
+        out["shared"] = self.shared.init(ks)
+        return out
+
+    def shard_params(self, params: dict) -> dict:
+        out = {k: shard_param(params[k], self.mesh, P())
+               for k in ("w_router", "e_bias", "w_gate", "w_up", "w_down")}
+        if "shared" in params:
+            out["shared"] = self.shared.shard_params(params["shared"])
+        return out
+
+    def __call__(self, params: dict, x: jax.Array, mode: str = "xla_ar",
+                 live: jax.Array | None = None):
+        """x: (T, H) replicated. ``live`` (T,) bool: tokens that are
+        somebody's (not a bucket's pad, not a frozen row); the others
+        are routed nowhere and counted nowhere. Returns (out (T, H),
+        counts): ``routed_tokens``, ``held_pairs``,
+        ``pair_rows_computed``, ``experts_touched`` () int32 and
+        ``expert_pairs`` (num_held,) int32."""
+        assert mode in ("xla_ar", "gemm_ar"), (
+            f"EPShareMoE takes replicated activations, not mode {mode!r}")
+        logits = jnp.dot(x.astype(jnp.float32), params["w_router"],
+                         precision=jax.lax.Precision.HIGHEST)
+        weights, idx = sigmoid_topk_routing(
+            logits, params["e_bias"], self.topk, self.norm_topk_prob,
+            self.scale)
+        local = idx - self.first_held
+        mine = (local >= 0) & (local < self.num_held)
+        if live is not None:
+            mine &= live[:, None]
+        local = jnp.where(mine, local, self.num_held)
+        routed, sizes, rows = held_expert_ffn(
+            x, params["w_gate"], params["w_up"], params["w_down"], local,
+            weights, self.num_held / self.num_experts)
+        out = routed.astype(x.dtype) + self.shared(params["shared"], x,
+                                                   mode=mode)
+        n_live = (jnp.int32(x.shape[0]) if live is None
+                  else jnp.sum(live.astype(jnp.int32)))
+        counts = {"routed_tokens": n_live, "held_pairs": jnp.sum(sizes),
+                  "pair_rows_computed": rows,
+                  "experts_touched": jnp.sum((sizes > 0).astype(jnp.int32)),
+                  "expert_pairs": sizes}
+        return out, counts

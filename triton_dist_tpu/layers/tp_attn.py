@@ -122,7 +122,8 @@ class TPAttn:
                  kv_cache: tuple[jax.Array, jax.Array],
                  offset: jax.Array, mode: str | None = None,
                  kv_start: jax.Array | None = None,
-                 kv_need: jax.Array | None = None):
+                 kv_need: jax.Array | None = None,
+                 window: int | None = None, rope: bool = True):
         """One attention block.
 
         Args:
@@ -138,6 +139,13 @@ class TPAttn:
             positions any LIVE query of this call may see; bounds the
             attention's read of the cache (see _attention_core). Only
             the stream decode step passes it.
+          window: this layer's sliding window (position i sees j with
+            i - window < j <= i), or None for every earlier position.
+            A per-row decode step (vector ``offset``, S == 1) then
+            takes ``kv_cache`` as the row's RING of ``window``
+            positions (see _attention_core).
+          rope: whether this layer rotates q and k (a model may carry
+            rotary embeddings in some of its layers only).
         Returns:
           (out, (k_cache, v_cache)): out has the same layout as x.
         """
@@ -164,11 +172,12 @@ class TPAttn:
         if self.qk_norm:
             q = rms_norm(q, params["q_norm"], self.rms_eps)
             k = rms_norm(k, params["k_norm"], self.rms_eps)
-        q = apply_rope(q, rope_cache, position_ids)
-        k = apply_rope(k, rope_cache, position_ids)
+        if rope:
+            q = apply_rope(q, rope_cache, position_ids)
+            k = apply_rope(k, rope_cache, position_ids)
 
         attn, new_cache = self._attention(q, k, v, kv_cache, offset,
-                                          kv_start, kv_need)
+                                          kv_start, kv_need, window)
         attn = attn.reshape(b * s, self.num_heads * d)
 
         if sharded:
@@ -178,7 +187,7 @@ class TPAttn:
         return out, new_cache
 
     def _attention(self, q, k, v, kv_cache, offset, kv_start=None,
-                   kv_need=None):
+                   kv_need=None, window=None):
         """Cached GQA attention, shard_mapped over the head axis.
 
         Equivalent role to the reference's flash-attn call on local heads
@@ -187,6 +196,8 @@ class TPAttn:
         axis = self.axis
         groups = self.num_heads // self.num_kv_heads
         core = functools.partial(_attention_core, groups=groups)
+        if window is not None:     # only then: the partial is the jaxpr's
+            core = functools.partial(core, window=window)
         spec = P(None, None, axis, None)
         if kv_start is None:
             kv_start = jnp.zeros((q.shape[0],), jnp.int32)
@@ -205,7 +216,7 @@ class TPAttn:
 
 
 def _attention_core(q, k, v, cache_k, cache_v, offset, kv_start,
-                    kv_need=None, *, groups: int):
+                    kv_need=None, *, groups: int, window: int | None = None):
     """Single-device cached causal GQA (fp32 softmax).
 
     q: (B, S, hq, D); k/v: (B, S, hkv, D); cache: (B, T, hkv, D).
@@ -240,9 +251,30 @@ def _attention_core(q, k, v, cache_k, cache_v, offset, kv_start,
     finite garbage, like a fully-masked pad row; its caller (the
     stream step's ``where(done, token, nxt)``) already discards it.
     ``None`` — every caller but the stream decode step — and any
-    ``T < 1024`` trace today's program over the whole cache."""
+    ``T < 1024`` trace today's program over the whole cache.
+
+    ``window`` (static) makes this a SLIDING-WINDOW layer: query i sees
+    position j only if also ``j > offset + i - window``. With a scalar
+    offset (an admission's prefill, whole or in chunks, into a scratch
+    cache that holds every position) that is one more term of the mask.
+    With per-row offsets and S == 1 (the stream decode step) the cache
+    IS the row's ring of ``window`` slots, position p at slot
+    ``p % window``: the step overwrites slot ``offset % window`` and
+    reads the ring whole (:func:`_attend_ring`); ``kv_need`` has nothing
+    to bound there. A per-row burst (S > 1) on a ring is refused."""
     b, s = q.shape[:2]
     t = cache_k.shape[1]
+    if window is not None and offset.ndim:
+        if s != 1:
+            raise NotImplementedError(
+                "a per-row burst (the speculative verify window) cannot "
+                "run on a sliding-window layer's ring cache yet")
+        assert t == window, (t, window)
+        rows, slot = jnp.arange(b), offset % window
+        cache_k = cache_k.at[rows, slot].set(k[:, 0])
+        cache_v = cache_v.at[rows, slot].set(v[:, 0])
+        return (_attend_ring(q, cache_k, cache_v, offset, groups),
+                cache_k, cache_v)
     if offset.ndim == 0:
         cache_k = lax.dynamic_update_slice(cache_k, k, (0, offset, 0, 0))
         cache_v = lax.dynamic_update_slice(cache_v, v, (0, offset, 0, 0))
@@ -264,8 +296,8 @@ def _attention_core(q, k, v, cache_k, cache_v, offset, kv_start,
         off_b = offset
 
     max_chunks = t // (2 * _WINDOW_CHUNK)
-    if kv_need is None or max_chunks == 0:
-        out = _attend(q, cache_k, cache_v, off_b, kv_start, groups)
+    if window is not None or kv_need is None or max_chunks == 0:
+        out = _attend(q, cache_k, cache_v, off_b, kv_start, groups, window)
     else:
         n = window_chunks(kv_need, t)
         out = lax.cond(
@@ -284,20 +316,24 @@ def _scores_dtype(q, cache_k):
     return cache_k.dtype if q.dtype == cache_k.dtype else jnp.float32
 
 
-def _masked_softmax(scores, off_b, kv_start):
+def _masked_softmax(scores, off_b, kv_start, window=None):
     """Causal + left-pad mask and fp32 softmax over the last axis of
     ``scores`` (B, hkv, G, S, T'): position j is visible to query i of
-    row b iff kv_start[b] <= j <= off_b[b] + i."""
+    row b iff kv_start[b] <= j <= off_b[b] + i (and, on a sliding-window
+    layer, j > off_b[b] + i - window)."""
     s, t = scores.shape[-2:]
     q_pos = off_b[:, None, None] + jnp.arange(s)[None, :, None]  # (B,S,1)
     causal = jnp.arange(t)[None, None, :] <= q_pos  # (B, S, T)
+    if window is not None:
+        causal &= jnp.arange(t)[None, None, :] > q_pos - window
     live = jnp.arange(t)[None, :] >= kv_start[:, None]  # (B, T)
     mask = causal & live[:, None]  # (B, S, T)
     scores = jnp.where(mask[:, None, None], scores, -1e30)
     return jax.nn.softmax(scores, axis=-1)
 
 
-def _attend(q, cache_k, cache_v, off_b, kv_start, groups: int):
+def _attend(q, cache_k, cache_v, off_b, kv_start, groups: int,
+            window: int | None = None):
     """The read half of :func:`_attention_core` over the whole cache:
     scores, mask, fp32 softmax, probs x V. ``off_b``: (B,) position of
     query 0."""
@@ -307,10 +343,41 @@ def _attend(q, cache_k, cache_v, off_b, kv_start, groups: int):
     qg = q.reshape(b, s, hkv, groups, d).astype(dt)
     scores = jnp.einsum("bskgd,btkd->bkgst", qg, cache_k.astype(dt),
                         preferred_element_type=jnp.float32) * (d ** -0.5)
-    probs = _masked_softmax(scores, off_b, kv_start)
+    probs = _masked_softmax(scores, off_b, kv_start, window)
     out = jnp.einsum("bkgst,btkd->bskgd", probs.astype(dt),
                      cache_v.astype(dt),
                      preferred_element_type=jnp.float32)
+    return out.reshape(b, s, hq, d).astype(q.dtype)
+
+
+def ring_positions(last, window: int):
+    """The position each slot of a ``window``-slot ring holds once
+    position ``last`` (..., a traced int array) has been written, slot
+    ``p % window`` holding ``p``: the newest position of each residue,
+    negative where the sequence is shorter than the ring (slot never
+    written). Shape ``last.shape + (window,)``."""
+    slots = jnp.arange(window, dtype=jnp.int32)
+    return last[..., None] - (last[..., None] - slots) % window
+
+
+def _attend_ring(q, ring_k, ring_v, offset, groups: int):
+    """One decode query per row over its ring (B, W, hkv, D), the row's
+    own position ``offset[b]`` already written: every slot is inside the
+    window by construction; slots the row has not reached yet (their
+    position is negative) hold another occupant's or a pad's K/V and
+    are masked. Keys were rotated at their absolute positions before
+    they were cached, so the slots' order does not matter."""
+    b, s, hq, d = q.shape
+    w, hkv = ring_k.shape[1], ring_k.shape[2]
+    dt = _scores_dtype(q, ring_k)
+    qg = q.reshape(b, s, hkv, groups, d).astype(dt)
+    scores = jnp.einsum("bskgd,btkd->bkgst", qg, ring_k.astype(dt),
+                        preferred_element_type=jnp.float32) * (d ** -0.5)
+    written = ring_positions(offset, w) >= 0                   # (B, W)
+    scores = jnp.where(written[:, None, None, None], scores, -1e30)
+    probs = jax.nn.softmax(scores, axis=-1)
+    out = jnp.einsum("bkgst,btkd->bskgd", probs.astype(dt),
+                     ring_v.astype(dt), preferred_element_type=jnp.float32)
     return out.reshape(b, s, hq, d).astype(q.dtype)
 
 

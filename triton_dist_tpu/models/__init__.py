@@ -1,8 +1,8 @@
 """Models + engine (reference L7: python/triton_dist/models/).
 
 ``AutoLLM.from_pretrained`` (reference models/__init__.py:33) dispatches
-on the HF config's ``model_type``/MoE fields to ``DenseLLM`` or
-``Qwen3MoE`` and loads safetensors weights when present.
+on the HF config's ``model_type``/MoE fields to ``DenseLLM``,
+``Qwen3MoE`` or ``ExaoneMoE`` and loads safetensors weights when present.
 """
 
 from __future__ import annotations
@@ -10,16 +10,18 @@ from __future__ import annotations
 import glob
 import os
 
-from triton_dist_tpu.models.config import ModelConfig
+from triton_dist_tpu.models.config import ModelConfig, known_model_type
 from triton_dist_tpu.models.dense import DenseLLM
 from triton_dist_tpu.models.qwen_moe import Qwen3MoE
+from triton_dist_tpu.models.exaone_moe import ExaoneMoE
 from triton_dist_tpu.models.kv_cache import KVCacheManager
 from triton_dist_tpu.models.engine import Engine, StreamSession, sample_token
 from triton_dist_tpu.models.spec import SpecConfig
 from triton_dist_tpu.models.train import make_train_step, cross_entropy_loss
 from triton_dist_tpu.models import presets
 
-__all__ = ["ModelConfig", "DenseLLM", "Qwen3MoE", "KVCacheManager",
+__all__ = ["ModelConfig", "DenseLLM", "Qwen3MoE", "ExaoneMoE",
+           "KVCacheManager",
            "Engine", "StreamSession", "sample_token", "AutoLLM", "make_train_step", "presets",
            "cross_entropy_loss", "SpecConfig"]
 
@@ -48,7 +50,14 @@ class AutoLLM:
     @staticmethod
     def build(config: ModelConfig, mesh=None, axis: str = "tp",
               fwd_mode: str = "ag_rs", impl: str = "pallas"):
-        cls = Qwen3MoE if config.is_moe else DenseLLM
+        """The decoder of ``config.model_type`` (``config.MODEL_TYPES``;
+        an unknown type raises). A ``qwen3`` config with experts is the
+        MoE decoder: presets and tests build it without naming the type."""
+        name, _ = known_model_type(config.model_type)
+        if name == "DenseLLM" and config.is_moe:
+            name = "Qwen3MoE"
+        cls = {"DenseLLM": DenseLLM, "Qwen3MoE": Qwen3MoE,
+               "ExaoneMoE": ExaoneMoE}[name]
         return cls(config, mesh=mesh, axis=axis, fwd_mode=fwd_mode,
                    impl=impl)
 

@@ -260,6 +260,29 @@ class Engine:
         self.decode_policy = (DecodePathPolicy()
                               if decode_path == "auto" else None)
         self._mega = None
+        # Sliding-window layers (a model's ``windows``: one entry per
+        # layer) keep a ring of ``window`` positions per row, which the
+        # stream session's admission and step programs write and read.
+        # The paths that know no rings refuse such a model here: none
+        # of them may compute full attention in a window layer's place.
+        windows = tuple(getattr(model, "windows", ()) or ())
+        if any(windows):
+            cannot = [what for what, on in (
+                ("paged KV pools", paged),
+                ("the sequence-parallel modes (forward_sp)",
+                 "sp" in (prefill_mode, decode_mode)),
+                ("the mega decode step", decode_path != "plain"),
+                ("the speculative verify step", self.spec is not None),
+            ) if on]
+            if cannot:
+                raise NotImplementedError(
+                    f"{type(model).__name__} has sliding-window layers; "
+                    f"{' and '.join(cannot)} cannot serve them yet "
+                    f"(ROADMAP R3)")
+        #: Names of the counters the model makes inside its programs
+        #: (``forward(counted=True)``), or () — see
+        #: :meth:`_build_stream_step_counted`.
+        self.count_names = tuple(getattr(model, "count_names", ()) or ())
         if "sp" in (prefill_mode, decode_mode):
             # Sequence-parallel serving (long context): both phases must
             # share the sequence-sharded cache layout.
@@ -297,7 +320,8 @@ class Engine:
             assert not paged, "paged serving requires the sp modes"
             self.kv = KVCacheManager(
                 c.num_hidden_layers, batch, max_seq, c.num_key_value_heads,
-                c.head_dim, mesh=model.mesh, axis=model.axis, dtype=c.dtype)
+                c.head_dim, mesh=model.mesh, axis=model.axis, dtype=c.dtype,
+                windows=windows)
         self.prefill_mode = prefill_mode
         self.decode_mode = decode_mode
         self.temperature = temperature
@@ -413,6 +437,12 @@ class Engine:
         rectangle — static shapes); the loop exits early once every row
         has stopped.
         """
+        if getattr(self.kv, "windows", ()):
+            raise NotImplementedError(
+                "Engine.serve prefills a whole batch into the row caches, "
+                "where a sliding-window layer keeps rings: serve this "
+                "model through a stream session (serve_stream, the "
+                "scheduler, ModelServer)")
         if self.spec is not None:
             # Explicit refusal, not a silent ignore (the PR-10 config-
             # check discipline): serve()'s rectangular decode loop has
@@ -637,15 +667,50 @@ class Engine:
         return key, None
 
     def _first_token(self, logits, idx, caches, token, offsets, key, row,
-                     length):
+                     length, counts=None):
         """Tail of the admission programs: sample the first token at
-        position ``idx`` of ``logits`` and seat the row."""
+        position ``idx`` of ``logits`` and seat the row. ``counts`` (a
+        counting model's, else None) ride home behind the first token:
+        what the host reads back is then a vector."""
         last = jax.lax.dynamic_slice_in_dim(logits, idx, 1, axis=1)[:, 0]
         key, sub = self._draw_key(key)
         first = sample_token(last, sub, self.temperature, self.top_k,
                              self.top_p)[0]
         token, offsets = _seat_row(token, offsets, row, first, length)
+        if counts is not None:
+            first = jnp.concatenate([first[None], counts])
         return first, caches, token, offsets, key
+
+    def _prompt_forward(self, params, ids, small, offset, length):
+        """An admission's forward over ``ids`` (1, S) at ``offset`` into
+        the scratch caches: ``(logits, small, counts or None)``. A
+        counting model is told which positions are the prompt's (the
+        bucket's pad is routed to no expert) and computes the ONE logit
+        row that is read, the prompt's last position: its logits are
+        (1, 1, V)."""
+        model, mode = self.model, self.prefill_mode
+        if not self.count_names:
+            return (*model.forward(params, ids, small, offset, mode=mode),
+                    None)
+        live = (offset + jnp.arange(ids.shape[1]) < length)[None]
+        at = jnp.clip(length - 1 - offset, 0, ids.shape[1] - 1)
+        return model.forward(params, ids, small, offset, mode=mode,
+                             live=live, logits_at=at, counted=True)
+
+    def _seat_lanes(self, caches, small, row, length):
+        """Row ``row``'s lane of every layer's cache rewritten from the
+        scratch prefix ``small``: the prefix itself at slot 0, or a
+        window layer's ring of its last positions
+        (``KVCacheManager.lane``)."""
+        lane = getattr(self.kv, "lane", lambda i, x, n: x)
+        new_caches = []
+        for i, ((ck, cv), (sk, sv)) in enumerate(zip(caches, small)):
+            ck = jax.lax.dynamic_update_slice(
+                ck, lane(i, sk, length), (row, 0, 0, 0))
+            cv = jax.lax.dynamic_update_slice(
+                cv, lane(i, sv, length), (row, 0, 0, 0))
+            new_caches.append((ck, cv))
+        return new_caches
 
     def _build_stream_step(self):
         """One decode step with PER-ROW write offsets: each live row
@@ -662,6 +727,8 @@ class Engine:
         ``where(done, token, nxt)`` discards: the pad-slot argument of
         :meth:`_build_admit`, applied to the read."""
         model, mode = self.model, self.decode_mode
+        if self.count_names:
+            return self._build_stream_step_counted()
 
         @jit_rewriting_caches
         def step(params, caches, token, offsets, key, done, table):
@@ -673,6 +740,30 @@ class Engine:
                                self.top_k, self.top_p)
             nxt = jnp.where(done, token, nxt)
             return nxt, caches, jnp.where(done, offsets, offsets + 1)
+        return step
+
+    def _build_stream_step_counted(self):
+        """:meth:`_build_stream_step` for a model that counts inside its
+        programs (``model.count_names``). The step's counts ride behind
+        the tokens in the ONE vector the session reads back each step,
+        so they cost no dispatch and no transfer of their own:
+        ``token`` is (batch + counts,) on both sides, its tail ignored
+        on the way in. Frozen rows are told apart (``live``) so that
+        they reach no expert and no counter."""
+        model, mode = self.model, self.decode_mode
+
+        @jit_rewriting_caches
+        def step(params, caches, token, offsets, key, done, table):
+            token = token[:offsets.shape[0]]
+            logits, caches, counts = model.forward(
+                params, token[:, None], caches, offsets, mode=mode,
+                kv_need=jnp.max(jnp.where(done, 0, offsets)) + 1,
+                live=~done[:, None], counted=True)
+            nxt = sample_token(logits[:, -1], key, self.temperature,
+                               self.top_k, self.top_p)
+            nxt = jnp.where(done, token, nxt)
+            return (jnp.concatenate([nxt, counts]), caches,
+                    jnp.where(done, offsets, offsets + 1))
         return step
 
     def _build_stream_step_mega(self):
@@ -708,22 +799,18 @@ class Engine:
         scattered K/V slots are overwritten by the row's own decode
         steps before the per-row mask ever exposes them — the same
         argument that makes stale-lane reuse safe."""
-        model, mode = self.model, self.prefill_mode
-
         @jit_rewriting_caches
         def admit(params, caches, ids, length, row, token, offsets, key):
             lb = ids.shape[1]                       # bucketed length
             small = [(jnp.zeros((1, lb) + ck.shape[2:], ck.dtype),
                       jnp.zeros((1, lb) + cv.shape[2:], cv.dtype))
                      for ck, cv in caches]
-            logits, small = model.forward(params, ids, small, 0, mode=mode)
-            new_caches = []
-            for (ck, cv), (sk, sv) in zip(caches, small):
-                ck = jax.lax.dynamic_update_slice(ck, sk, (row, 0, 0, 0))
-                cv = jax.lax.dynamic_update_slice(cv, sv, (row, 0, 0, 0))
-                new_caches.append((ck, cv))
-            return self._first_token(logits, length - 1, new_caches, token,
-                                     offsets, key, row, length)
+            logits, small, counts = self._prompt_forward(
+                params, ids, small, 0, length)
+            new_caches = self._seat_lanes(caches, small, row, length)
+            return self._first_token(
+                logits, length - 1 if counts is None else 0, new_caches,
+                token, offsets, key, row, length, counts)
         return admit
 
     def _build_admit_paged(self):
@@ -777,6 +864,17 @@ class Engine:
         these between shared decode steps so a long prompt's admission
         never stalls the rows already decoding (docs/serving.md)."""
         model, mode = self.model, self.prefill_mode
+        if self.count_names:
+            # A counting model's chunk also takes the prompt's length
+            # and the counts of the chunks before it, and hands back
+            # their sum: the admission's counts reach the host once,
+            # with its first token (_build_admit_finish).
+            @jit_rewriting_caches
+            def counted_chunk(params, small, ids, offset, length, counts):
+                logits, small, more = self._prompt_forward(
+                    params, ids, small, offset, length)
+                return logits, small, counts + more
+            return counted_chunk
 
         @jit_rewriting_caches
         def chunk_step(params, small, ids, offset):
@@ -791,14 +889,21 @@ class Engine:
         causally invisible and overwritten before any mask exposes
         them)."""
 
+        if self.count_names:
+            # The last chunk of a counting model computed the one logit
+            # row that is read (index 0), and its counts come along.
+            @jit_rewriting_caches
+            def counted_finish(small, caches, logits, length, row, counts,
+                               token, offsets, key):
+                new_caches = self._seat_lanes(caches, small, row, length)
+                return self._first_token(logits, 0, new_caches, token,
+                                         offsets, key, row, length, counts)
+            return counted_finish
+
         @jit_rewriting_caches
         def finish(small, caches, logits, idx, length, row, token, offsets,
                    key):
-            new_caches = []
-            for (ck, cv), (sk, sv) in zip(caches, small):
-                ck = jax.lax.dynamic_update_slice(ck, sk, (row, 0, 0, 0))
-                cv = jax.lax.dynamic_update_slice(cv, sv, (row, 0, 0, 0))
-                new_caches.append((ck, cv))
+            new_caches = self._seat_lanes(caches, small, row, length)
             return self._first_token(logits, idx, new_caches, token,
                                      offsets, key, row, length)
         return finish
@@ -1025,7 +1130,9 @@ class StreamSession:
         # Last token and write offset per row: handed to every stream
         # program and rebound to what it returns, never touched by an
         # eager op in between (host values until the first program).
-        self.token = np.zeros((b,), np.int32)
+        # A counting model's programs append their counts to it
+        # (Engine._build_stream_step_counted).
+        self.token = np.zeros((b + len(engine.count_names),), np.int32)
         self.offsets = np.zeros((b,), np.int32)
         self.live = [False] * b
         self._decode_kind: str | None = None  # decided path, unconsumed
@@ -1169,10 +1276,22 @@ class StreamSession:
         eng = self.engine
         first, caches, token, offsets, key = program(
             head, self.caches, *inputs, self.token, self.offsets, eng.key)
-        first = int(first)
+        if eng.count_names:
+            first = np.asarray(first)
+            self._note_counts(first[1:])
+            first = int(first[0])
+        else:
+            first = int(first)
         self.caches, self.token, self.offsets, eng.key = (
             caches, token, offsets, key)
         return first
+
+    def _note_counts(self, counts) -> None:
+        """A counting model's in-program counts, as they came back
+        behind a first token or a step's tokens, into the registry."""
+        for name, n in zip(self.engine.count_names, counts.tolist()):
+            if n:
+                obs.counter(name).inc(n)
 
     def _admit_whole(self, row: int, prompt: list, lb: int, args: dict,
                      gen_budget: int | None = None) -> int:
@@ -1312,17 +1431,28 @@ class StreamSession:
         eng = self.engine
         st = self._pending[row]
         c = st["chunk"]
-        logits, st["small"] = eng._admit_chunk(
-            self.params, st["small"], st["ids"][:, st["pos"]:st["pos"] + c],
-            np.int32(st["pos"]))
+        ids = st["ids"][:, st["pos"]:st["pos"] + c]
+        if eng.count_names:
+            logits, st["small"], st["counts"] = eng._admit_chunk(
+                self.params, st["small"], ids, np.int32(st["pos"]),
+                np.int32(st["len"]), st.get("counts", np.zeros(
+                    (len(eng.count_names),), np.int32)))
+        else:
+            logits, st["small"] = eng._admit_chunk(
+                self.params, st["small"], ids, np.int32(st["pos"]))
         st["pos"] += c
         if st["pos"] < st["ids"].shape[1]:
             return None
         del self._pending[row]
-        idx = st["len"] - 1 - (st["pos"] - c)   # last real token's index
-        first = self._run_admission(            # in the final chunk
-            eng._admit_finish, st["small"], logits, np.int32(idx),
-            np.int32(st["len"]), np.int32(row))
+        if eng.count_names:
+            first = self._run_admission(
+                eng._admit_finish, st["small"], logits, np.int32(st["len"]),
+                np.int32(row), st["counts"])
+        else:
+            idx = st["len"] - 1 - (st["pos"] - c)   # last real token's
+            first = self._run_admission(            # index in the final
+                eng._admit_finish, st["small"], logits, np.int32(idx),
+                np.int32(st["len"]), np.int32(row))     # chunk
         self.admit_info = {"cached": 0}
         self._count_admitted(st["len"], st["ids"].shape[1])
         self._mark_admitted(row, st["len"])
@@ -1576,7 +1706,11 @@ class StreamSession:
         for r in range(len(self.live)):
             if self.live[r]:
                 self._host_off[r] += 1
-        return np.asarray(self.token)
+        tokens = np.asarray(self.token)
+        if eng.count_names:
+            self._note_counts(tokens[len(self.live):])
+            tokens = tokens[:len(self.live)]
+        return tokens
 
     def _spec_burst(self) -> dict:
         """Draft → widened verify → atomic commit (ISSUE 13).

@@ -70,11 +70,31 @@ def _zero_leaves(shape, dtype, sharding, num_layers: int):
             for _ in range(num_layers)]
 
 
+def ring_lane(prefix: jax.Array, length, window: int) -> jax.Array:
+    """The ring a sliding-window layer keeps of one row, from the K (or
+    V) of the row's prompt: ``prefix`` (1, S, Hkv, D) holds positions
+    [0, S), of which the first ``length`` (traced) are the prompt's.
+    Slot ``p % window`` of the result (1, window, Hkv, D) holds position
+    ``p`` for the last ``window`` positions of the prompt; a slot the
+    prompt has not reached holds an arbitrary position's K/V, which the
+    decode step's mask hides until the row's own step overwrites it
+    (layers/tp_attn._attend_ring)."""
+    from triton_dist_tpu.layers.tp_attn import ring_positions
+    pos = ring_positions(jnp.asarray(length, jnp.int32) - 1, window)
+    return jnp.take(prefix, jnp.clip(pos, 0, prefix.shape[1] - 1), axis=1)
+
+
 class KVCacheManager:
+    """Per-layer (B, T, Hkv, D) caches. ``windows`` (one entry per layer:
+    a sliding window, or None) gives a window layer a RING of ``window``
+    positions per row in place of ``max_seq``: its cache does not grow
+    with the context."""
+
     def __init__(self, num_layers: int, batch: int, max_seq: int,
                  num_kv_heads: int, head_dim: int,
                  mesh: Mesh | None = None, axis: str = "tp",
-                 dtype=jnp.bfloat16, seq_shard: bool = False):
+                 dtype=jnp.bfloat16, seq_shard: bool = False,
+                 windows=None):
         if mesh is None:
             from triton_dist_tpu.runtime.dist import get_mesh
             mesh = get_mesh()
@@ -87,12 +107,28 @@ class KVCacheManager:
         spec = P(None, axis) if seq_shard else P(None, None, axis)
         self.sharding = NamedSharding(mesh, spec)
         self.offset = 0  # host-side write position (reference kv_offset)
+        windows = tuple(windows or ())
+        assert not any(windows) or (len(windows) == num_layers
+                                    and not seq_shard), windows
+        self.windows = windows if any(windows) else ()
 
     def init(self):
         """Allocate the cache pytree: [(k, v)] * L."""
         shape = (self.batch, self.max_seq, self.num_kv_heads, self.head_dim)
-        return _zero_leaves(shape, self.dtype, self.sharding,
-                            self.num_layers)
+        if not self.windows:
+            return _zero_leaves(shape, self.dtype, self.sharding,
+                                self.num_layers)
+        return [_zero_leaves((self.batch, w or self.max_seq) + shape[2:],
+                             self.dtype, self.sharding, 1)[0]
+                for w in self.windows]
+
+    def lane(self, layer: int, prefix: jax.Array, length) -> jax.Array:
+        """What layer ``layer`` keeps of an admitted row whose prompt's
+        K (or V) is ``prefix`` (1, S, Hkv, D): the prefix itself, or a
+        window layer's ring of its last positions."""
+        if not self.windows or not self.windows[layer]:
+            return prefix
+        return ring_lane(prefix, length, self.windows[layer])
 
     def inc_offset(self, n: int) -> int:
         """Advance the write position (reference ``inc_offset``)."""

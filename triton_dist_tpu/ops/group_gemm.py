@@ -87,6 +87,75 @@ def grouped_expert_ffn(tokens: jax.Array, w_gate: jax.Array, w_up: jax.Array,
     return down.astype(tokens.dtype)[unsort]
 
 
+def held_expert_ffn(x: jax.Array, w_gate: jax.Array, w_up: jax.Array,
+                    w_down: jax.Array, local_ids: jax.Array,
+                    weights: jax.Array, expected_share: float):
+    """The routed part of a MoE layer that ONE holder of ``n`` experts
+    computes: the weighted sum, per token, of the held experts among the
+    token's selected ones. Dropless, and only held pairs are gathered:
+    a (token, expert) pair whose expert lives elsewhere passes through no
+    expert's weights here.
+
+    x: (T, H). w_gate/w_up: (n, H, I), w_down: (n, I, H): the held
+    experts. local_ids: (T, K) int32, a pair's expert as its index among
+    the held ones, ``n`` for a pair that is not held (or whose token is
+    not live). weights: (T, K) float32. ``expected_share``: the share of
+    all pairs a balanced router sends here (held / routed experts).
+
+    The held pairs are sorted by expert in front of the rest and the
+    first ``rows`` pairs run through ``ragged_dot``. ``rows`` is static,
+    so it is chosen in-graph between two sizes: twice the expected
+    number of held pairs, or all T*K (a ``lax.cond``; a router that
+    overloads this holder costs time, never a pair). Rows past the held
+    pairs ride in the last expert's group (``ragged_dot`` wants the
+    groups to cover its rows) and are zeroed.
+
+    Returns (out (T, H) float32, pairs per held expert (n,) int32, the
+    rows the expert matmuls ran () int32)."""
+    t, k = local_ids.shape
+    n = w_gate.shape[0]
+    flat = local_ids.reshape(-1)
+    order = jnp.argsort(flat, stable=True).astype(jnp.int32)
+    sizes = jnp.zeros((n,), jnp.int32).at[flat].add(1, mode="drop")
+    held = jnp.sum(sizes)
+    flat_w = weights.reshape(-1)
+    # Each pair's place in the sorted order; a token GATHERS its k pairs'
+    # rows (the zero row for a pair past ``rows``). A scatter-add of the
+    # rows to their tokens reads the same on paper, and on the v5e XLA
+    # fuses it with the weighting into a program that drops rows (PR 34's
+    # chip runs); the gather also sums in one fixed order.
+    place = jnp.zeros((t * k,), jnp.int32).at[order].set(
+        jnp.arange(t * k, dtype=jnp.int32)).reshape(t, k)
+
+    def run(rows: int):
+        def f():
+            pair = order[:rows]
+            tok = pair // k
+            xs = x[tok]
+            groups = sizes.at[n - 1].add(rows - held)
+            gate = lax.ragged_dot(xs, w_gate, groups,
+                                  preferred_element_type=jnp.float32)
+            up = lax.ragged_dot(xs, w_up, groups,
+                                preferred_element_type=jnp.float32)
+            act = (jax.nn.silu(gate) * up).astype(x.dtype)
+            down = lax.ragged_dot(act, w_down, groups,
+                                  preferred_element_type=jnp.float32)
+            w = jnp.where(jnp.arange(rows) < held, flat_w[pair], 0.0)
+            y = jnp.concatenate([down * w[:, None],
+                                 jnp.zeros((1, x.shape[1]), jnp.float32)])
+            return (jnp.sum(y[jnp.minimum(place, rows)], axis=1),
+                    jnp.int32(rows))
+        return f
+
+    full = t * k
+    small = min(round_up(max(int(2 * full * expected_share), 8), 8), full)
+    if small == full:
+        out, rows = run(full)()
+    else:
+        out, rows = lax.cond(held <= small, run(small), run(full))
+    return out, sizes, rows
+
+
 def align_tokens_for_tiles(tokens: jax.Array, ids: jax.Array,
                            num_experts: int, m_blk: int):
     """Tile-align tokens by expert (traced; static shapes).

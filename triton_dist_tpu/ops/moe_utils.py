@@ -45,6 +45,30 @@ def topk_routing(router_logits: jax.Array, topk: int,
     return weights, indices.astype(jnp.int32)
 
 
+def sigmoid_topk_routing(router_logits: jax.Array, bias: jax.Array,
+                         topk: int, norm_topk_prob: bool = True,
+                         scale: float = 1.0):
+    """Sigmoid scores, selection by ``score + bias``, weights from the
+    scores alone (the aux-loss-free recipe of DeepSeek-V3 / K-EXAONE,
+    one group: no group limit).
+
+    Args:
+      router_logits: (T, E) float logits over ALL routed experts.
+      bias: (E,) the per-expert selection bias: it moves which experts
+        are chosen, never their weights.
+      scale: ``routed_scaling_factor``.
+
+    Returns:
+      (weights (T, topk) float32, indices (T, topk) int32)
+    """
+    scores = jax.nn.sigmoid(router_logits.astype(jnp.float32))
+    _, indices = lax.top_k(scores + bias.astype(jnp.float32), topk)
+    weights = jnp.take_along_axis(scores, indices, axis=-1)
+    if norm_topk_prob:
+        weights = weights / jnp.sum(weights, axis=-1, keepdims=True)
+    return weights * scale, indices.astype(jnp.int32)
+
+
 def live_slot_mask(counts: jax.Array, world: int,
                    capacity: int) -> jax.Array:
     """(world, capacity) bool: slot s of slab p is live iff

@@ -23,7 +23,7 @@ Design:
   per-request done-events, so no generation lock exists at all.
 - **Fair FIFO admission with backpressure.** :meth:`Scheduler.submit`
   appends to a bounded queue (``max_waiting`` / ``TDT_MAX_WAITING``,
-  default 64); a full queue raises :class:`QueueFull`, which the
+  default 64, or four times the engine's rows if that is more); a full queue raises :class:`QueueFull`, which the
   server answers with a structured ``queue_full`` reply instead of
   stalling the connection. Admission order is strictly
   first-come-first-served.
@@ -229,8 +229,12 @@ class Scheduler:
         self.replica_id = replica_id
         self._registry = registry
         if max_waiting is None:
-            max_waiting = obs.env_int("TDT_MAX_WAITING",
-                                      DEFAULT_MAX_WAITING)
+            # The default grows with the decode window: a queue shorter
+            # than twice the rows refuses a burst the engine would seat
+            # within two turns (64 rows: 256 waiting).
+            rows = getattr(getattr(engine, "kv", None), "batch", 0)
+            max_waiting = obs.env_int(
+                "TDT_MAX_WAITING", max(DEFAULT_MAX_WAITING, 4 * rows))
         if max_waiting <= 0:
             raise ValueError(f"max_waiting must be positive: {max_waiting}")
         self.max_waiting = max_waiting
