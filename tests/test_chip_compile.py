@@ -227,6 +227,11 @@ def test_decode_step_and_admission_write_the_caches_in_place(
             None),
         "admit": eng._build_admit().lower(
             params, caches, sds((1, 128), i32), sds((), i32),
+            sds((), i32), token, offsets, key),
+        # A bucket whose float32 scores pass the budget of the chip's
+        # fast memory is read in query blocks (ISSUE 35).
+        "admit_2048": eng._build_admit().lower(
+            params, caches, sds((1, 2048), i32), sds((), i32),
             sds((), i32), token, offsets, key)}
     for name, lowered in programs.items():
         compiled = lowered.compile()
@@ -244,6 +249,16 @@ def test_decode_step_and_admission_write_the_caches_in_place(
         # The real step: its gemm_ar kernels (xla_ar prefill has none).
         assert ("tpu_custom_call" in text) == (name == "step")
         assert not re.findall(r"\[8,4096,8,128\]\S* copy\(", entry), name
+        if name == "admit_2048":
+            # No (S, S) score tensor: four blocks of 512 rows against the
+            # keys up to their own end, every one assigned to the fast
+            # memory (``S(1)``), where the whole square went to HBM.
+            assert not re.search(r"f32\[1,8,2,2048,\d+\]", text)
+            for keys in (512, 1024, 1536, 2048):
+                layouts = re.findall(
+                    rf"f32\[1,8,2,512,{keys}\](\{{[^}}]*\}})", entry)
+                assert layouts and all(
+                    lay.endswith("S(1)}") for lay in layouts), (keys, layouts)
         if name != "step":
             continue
         # The step's attention picks its window in the graph (ISSUE 33): a
@@ -271,6 +286,7 @@ def test_exaone_share_programs(meshes, monkeypatch, program):
     held experts' grouped matmuls as XLA's ragged dot."""
     import json
     import os
+    import re
     from benchmark.harness.builders import exaone
     from triton_dist_tpu.models import AutoLLM, Engine, ModelConfig
     monkeypatch.setenv("TDT_FORCE_COMPILED", "1")
@@ -320,3 +336,10 @@ def test_exaone_share_programs(meshes, monkeypatch, program):
     text = compiled.as_text()
     assert "ragged-dot" in text
     assert "threefry" not in lowered.as_text()
+    if program == "admit":
+        # The admission's attention is read in query blocks (ISSUE 35): 64
+        # heads x 2048 keys leave a full layer 128 rows a block, a window
+        # layer 256 rows against at most 384 keys; no S x S scores.
+        assert not re.search(r"f32\[1,8,8,2048,\d+\]", text)
+        assert re.search(r"f32\[1,8,8,128,2048\]\{[^}]*S\(1\)\}", text)
+        assert re.search(r"f32\[1,8,8,256,384\]\{[^}]*S\(1\)\}", text)

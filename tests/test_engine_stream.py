@@ -244,3 +244,55 @@ def test_stream_moe_model(mesh8, key, moe_parallel):
         want = np.asarray(solo_eng.serve(
             params, jnp.asarray([prompt], jnp.int32), 3))[0].tolist()
         assert row == want, (prompt, row, want)
+
+
+def test_admission_read_in_query_blocks_seats_the_same_row(
+        key, monkeypatch):
+    """A 300-token prompt lands in the 512 bucket; with the score budget
+    shrunk the admission's attention is read in query blocks
+    (layers/tp_attn._attention_core: each block against the keys its
+    mask leaves it), at the default budget the same admission is one
+    block, the masked read. Same first token, same lane of every
+    layer's cache; fewer query-key pairs counted."""
+    from jax.sharding import Mesh
+    from triton_dist_tpu import obs
+    from triton_dist_tpu.layers import tp_attn
+    mesh = Mesh(np.array(jax.devices()[:1]), ("tp",))
+    cfg = ModelConfig(hidden_size=64, intermediate_size=128,
+                      num_hidden_layers=2, num_attention_heads=4,
+                      num_key_value_heads=2, head_dim=16, vocab_size=64,
+                      max_position_embeddings=1024, dtype=jnp.float32)
+    model = DenseLLM(cfg, mesh=mesh, axis="tp", impl="xla")
+    params = model.init(key)
+    prompt = np.random.default_rng(0).integers(1, 64, 300).tolist()
+
+    def admit():
+        eng = Engine(model, batch=2, max_seq=1024, prefill_mode="xla_ar",
+                     decode_mode="gemm_ar")
+        sess = eng.stream_session(params)
+        c0 = dict(obs.snapshot().get("counters", {}))
+        first = sess.prefill_into_row(1, prompt)
+        c1 = obs.snapshot()["counters"]
+        lanes = [np.asarray(leaf)[1, :300] for pair in sess.caches
+                 for leaf in pair]
+        return (first, lanes, [c1[k] - c0.get(k, 0) for k in (
+            "attn.prefill_positions_scored",
+            "attn.prefill_positions_square")])
+
+    was = obs.enabled()
+    obs.enable()
+    try:
+        first0, lanes0, (scored0, square0) = admit()
+        monkeypatch.setattr(tp_attn, "_SCORE_BYTES", 1 << 20)
+        blocks = tp_attn.prefill_blocks(1, 4, 512, None)
+        first, lanes, (scored, square) = admit()
+    finally:
+        if not was:
+            obs.disable()
+    assert blocks == ((0, 0, 128), (128, 0, 256), (256, 0, 384),
+                      (384, 0, 512))
+    assert first == first0
+    for got, want in zip(lanes, lanes0):
+        np.testing.assert_allclose(got, want, rtol=1e-4, atol=1e-4)
+    assert square == square0 == scored0 == 2 * 512 * 512
+    assert scored == 2 * 128 * (128 + 256 + 384 + 512)

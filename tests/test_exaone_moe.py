@@ -418,6 +418,51 @@ def test_per_row_burst_on_a_ring_is_refused():
                         jnp.zeros((2,), jnp.int32), groups=2, window=WINDOW)
 
 
+def test_admission_in_query_blocks_seats_the_same_rings_and_lanes(
+        served, monkeypatch):
+    """A 40-token prompt in the 64 bucket with the score budget shrunk:
+    window and full layers alike are read in query blocks
+    (layers/tp_attn._attention_core), a window layer's blocks against
+    their band only. Same first token, same ring of every window layer
+    and lane of every full layer as the one-block read, fewer query-key
+    pairs counted."""
+    from triton_dist_tpu.layers import tp_attn
+    llm, params, _ = served
+    prompt = prompts_of([40], seed=2)[0]
+
+    def admit():
+        sess = engine(llm).stream_session(params)
+        c0 = dict(obs.snapshot().get("counters", {}))
+        first = sess.prefill_into_row(1, prompt)
+        c1 = obs.snapshot()["counters"]
+        lanes = [np.asarray(leaf)[1, :40] for pair in sess.caches
+                 for leaf in pair]
+        return (first, lanes, [c1[k] - c0.get(k, 0) for k in (
+            "attn.prefill_positions_scored",
+            "attn.prefill_positions_square")])
+
+    was = obs.enabled()
+    obs.enable()
+    try:
+        first0, lanes0, (scored0, square0) = admit()
+        monkeypatch.setattr(tp_attn, "_SCORE_BYTES", 16 << 10)
+        first, lanes, (scored, square) = admit()
+    finally:
+        if not was:
+            obs.disable()
+    full = tp_attn.prefill_blocks(1, 4, 64, None)
+    band = tp_attn.prefill_blocks(1, 4, 64, WINDOW)
+    assert full == band == ((0, 0, 16), (16, 0, 32), (32, 0, 48),
+                            (48, 0, 64))
+    assert first == first0
+    assert [lane.shape[0] for lane in lanes[::2]] == [
+        WINDOW if w else 40 for w in llm.windows]
+    for got, want in zip(lanes, lanes0):
+        np.testing.assert_allclose(got, want, rtol=1e-4, atol=1e-4)
+    assert square == square0 == scored0 == LAYERS * 64 * 64
+    assert scored == LAYERS * 16 * (16 + 32 + 48 + 64)
+
+
 # -- the programs of the configuration the benchmark already has ----------
 
 # sha256 of the jaxpr text (addresses blanked) of the stream step and the

@@ -249,3 +249,37 @@ def test_fused_mode_trains(mesh8):
                                rtol=2e-4, atol=2e-5)
     np.testing.assert_allclose(losses["gemm_ar"], losses["xla"],
                                rtol=2e-4, atol=2e-5)
+
+
+def test_training_differentiates_through_the_query_blocks(
+        devices, monkeypatch):
+    """A training forward is a whole-bucket prefill (caches sized exactly
+    (B, S), offset 0, no left padding): where its scores pass the
+    budget its attention is read in query blocks
+    (layers/tp_attn._attention_core), plain XLA that differentiates as
+    it stands. Same loss, same updated parameters as the one-block
+    read, with and without remat."""
+    from triton_dist_tpu.layers import tp_attn
+    mesh = Mesh(np.array(devices[:1]), ("tp",))
+    model = DenseLLM(_tiny_cfg(2), mesh=mesh, axis="tp", impl="xla",
+                     fwd_mode="xla_ar")
+    params = model.init(jax.random.PRNGKey(2))
+    batch = _batch(2, 64, model.config.vocab_size, seed=2)
+
+    def one_step(remat):
+        step, init_opt = make_train_step(model, mode="xla_ar", remat=remat,
+                                         donate=False)
+        p2, _, m = step(params, init_opt(params), dict(batch))
+        return float(m["loss"]), float(m["grad_norm"]), jax.tree.map(
+            np.asarray, p2)
+
+    want = one_step(False)
+    monkeypatch.setattr(tp_attn, "_SCORE_BYTES", 16 << 10)
+    assert len(tp_attn.prefill_blocks(2, 2, 64, None)) == 4
+    for remat in (False, True):
+        loss, gn, new = one_step(remat)
+        assert np.isfinite(loss) and gn > 0
+        np.testing.assert_allclose(loss, want[0], rtol=1e-5)
+        np.testing.assert_allclose(gn, want[1], rtol=1e-4)
+        for a, b in zip(jax.tree.leaves(new), jax.tree.leaves(want[2])):
+            np.testing.assert_allclose(a, b, rtol=1e-4, atol=1e-5)

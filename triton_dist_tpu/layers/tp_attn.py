@@ -57,6 +57,47 @@ def decode_window(need: int, t: int) -> int:
     return int(window_chunks(need, t)) * _WINDOW_CHUNK or t
 
 
+# The whole-bucket prefill. XLA keeps an attention's float32 scores in
+# the chip's fast memory when they fit there beside the rest: on the
+# v5e (128 MiB) a 64 MiB score tensor stays, a 128 MiB one is written to
+# HBM, read back by the softmax's passes and read again as probabilities
+# (one layer of 16 heads: 0.07 ms at S = T = 1024, 1.15 ms at 2048;
+# PERF.md, PR 35). A call whose keys are exactly its own S positions is
+# therefore read in query blocks whose scores stay under this budget,
+# each against the keys its mask leaves it. The largest that was seen
+# to fit: blocks of 16, 32 and 64 MiB read the same on the chip, and
+# fewer blocks make a smaller program. A module constant, no knob.
+_SCORE_BYTES = 64 << 20
+
+
+def prefill_blocks(b: int, hq: int, s: int, window: int | None):
+    """The static (first query, first key, end) triples a whole-bucket
+    prefill of ``s`` positions is read in (host ints): query rows
+    [first, end) against keys [first key, end), the keys a block's
+    causal mask, and a window layer's band, leave to it. A block is the
+    largest power-of-two share of ``s`` whose float32 scores (b x hq x
+    rows x keys) fit ``_SCORE_BYTES``; one block, the whole square,
+    where the bucket is short enough."""
+    rows = s
+    while rows > 8 and 4 * b * hq * rows * (
+            s if window is None else min(s, rows + window + 127)
+            ) > _SCORE_BYTES:
+        rows //= 2
+    blocks = []
+    for first in range(0, s, rows):
+        lo = 0 if window is None else max(first - window + 1, 0) // 128 * 128
+        blocks.append((first, lo, min(first + rows, s)))
+    return tuple(blocks)
+
+
+def prefill_positions_scored(hq: int, s: int, window: int | None) -> int:
+    """Query-key pairs one head of one layer scores in a whole-bucket
+    admission of ``s`` positions (host ints; ``hq``: the query heads a
+    device holds): its blocks' rows times their keys."""
+    return sum((end - first) * (end - lo)
+               for first, lo, end in prefill_blocks(1, hq, s, window))
+
+
 class TPAttn:
     """GQA attention under TP. No QKV bias (Qwen3 dropped it)."""
 
@@ -200,6 +241,7 @@ class TPAttn:
             core = functools.partial(core, window=window)
         spec = P(None, None, axis, None)
         if kv_start is None:
+            core = functools.partial(core, left_pad=False)
             kv_start = jnp.zeros((q.shape[0],), jnp.int32)
         # kv_need rides along as one more replicated scalar, and only
         # when given: without it the traced program is unchanged.
@@ -216,7 +258,8 @@ class TPAttn:
 
 
 def _attention_core(q, k, v, cache_k, cache_v, offset, kv_start,
-                    kv_need=None, *, groups: int, window: int | None = None):
+                    kv_need=None, *, groups: int, window: int | None = None,
+                    left_pad: bool = True):
     """Single-device cached causal GQA (fp32 softmax).
 
     q: (B, S, hq, D); k/v: (B, S, hkv, D); cache: (B, T, hkv, D).
@@ -261,7 +304,26 @@ def _attention_core(q, k, v, cache_k, cache_v, offset, kv_start,
     IS the row's ring of ``window`` slots, position p at slot
     ``p % window``: the step overwrites slot ``offset % window`` and
     reads the ring whole (:func:`_attend_ring`); ``kv_need`` has nothing
-    to bound there. A per-row burst (S > 1) on a ring is refused."""
+    to bound there. A per-row burst (S > 1) on a ring is refused.
+
+    THE WHOLE-BUCKET PREFILL. A call with a scalar offset whose cache
+    is exactly its S positions (S == T, so the offset is 0: an
+    admission into its bucket-sized scratch cache, a training forward)
+    and whose caller says there is no left padding (``left_pad=False``,
+    static: ``TPAttn._attention`` was given no ``kv_start``) reads its
+    own k and v — the values it has just written — in static QUERY
+    BLOCKS (:func:`prefill_blocks`, :func:`_attend_blocks`): each block
+    of rows against keys [lo, end) only, ``end`` its own last row and
+    ``lo`` the first key a window layer's band leaves it, so what lies
+    above the diagonal of later rows, or before the band, is never
+    scored, and a block's float32 scores are small enough
+    (``_SCORE_BYTES``) to stay in the chip's fast memory instead of
+    going through HBM four times. Same operands, precision and mask as
+    :func:`_attend`, which each block is; the bucket's pad queries get
+    finite values nobody reads. A bucket whose whole square fits the
+    budget is one block and traces the program it always did. Chosen
+    from the static shapes, the offset's rank, ``left_pad`` and
+    ``kv_need`` alone; every other call takes the paths above."""
     b, s = q.shape[:2]
     t = cache_k.shape[1]
     if window is not None and offset.ndim:
@@ -296,7 +358,12 @@ def _attention_core(q, k, v, cache_k, cache_v, offset, kv_start,
         off_b = offset
 
     max_chunks = t // (2 * _WINDOW_CHUNK)
-    if window is not None or kv_need is None or max_chunks == 0:
+    blocks = ()
+    if not left_pad and offset.ndim == 0 and kv_need is None and s == t:
+        blocks = prefill_blocks(b, q.shape[2], s, window)
+    if len(blocks) > 1:
+        out = _attend_blocks(q, cache_k, cache_v, groups, window, blocks)
+    elif window is not None or kv_need is None or max_chunks == 0:
         out = _attend(q, cache_k, cache_v, off_b, kv_start, groups, window)
     else:
         n = window_chunks(kv_need, t)
@@ -348,6 +415,22 @@ def _attend(q, cache_k, cache_v, off_b, kv_start, groups: int,
                      cache_v.astype(dt),
                      preferred_element_type=jnp.float32)
     return out.reshape(b, s, hq, d).astype(q.dtype)
+
+
+# Jitted on its own so that the layers of one program, whose shapes and
+# blocks are the same, trace and lower the blocks once and call them.
+@functools.partial(jax.jit, static_argnums=(3, 4, 5))
+def _attend_blocks(q, k, v, groups: int, window: int | None, blocks):
+    """:func:`_attend` for a whole-bucket prefill (q, k, v:
+    (B, S, heads, D), the keys being the queries' own positions), one
+    block of :func:`prefill_blocks` at a time: what lies above the
+    diagonal of a later block's rows, or before a window layer's band,
+    is not scored, and no block's scores leave the fast memory."""
+    zero = jnp.zeros((q.shape[0],), jnp.int32)
+    return jnp.concatenate([
+        _attend(q[:, first:end], k[:, lo:end], v[:, lo:end],
+                zero + (first - lo), zero, groups, window)
+        for first, lo, end in blocks], axis=1)
 
 
 def ring_positions(last, window: int):

@@ -15,6 +15,7 @@ triton_dist_gemm_ar (replicated small-batch decode).
 
 from __future__ import annotations
 
+import collections
 import time
 
 import jax
@@ -23,7 +24,8 @@ import numpy as np
 
 from triton_dist_tpu import obs
 from triton_dist_tpu.obs import trace as _trace
-from triton_dist_tpu.layers.tp_attn import decode_window
+from triton_dist_tpu.layers.tp_attn import (
+    decode_window, prefill_positions_scored)
 from triton_dist_tpu.models.kv_cache import (
     KVCacheLost, KVCacheManager, jit_rewriting_caches)
 
@@ -1302,7 +1304,7 @@ class StreamSession:
             eng._admit, self.params, self._padded_ids(prompt, lb),
             np.int32(len(prompt)), np.int32(row))
         self.admit_info = {"cached": 0}
-        self._count_admitted(len(prompt), lb)
+        self._count_admitted(len(prompt), lb, whole=True)
         self._mark_admitted(row, len(prompt))
         self._spec_start(row, prompt, first, gen_budget)
         return first
@@ -1577,13 +1579,30 @@ class StreamSession:
         self._spec_start(row, prompt, int(first), gen_budget)
         return int(first)
 
-    @staticmethod
-    def _count_admitted(ran: int, padded: int) -> None:
+    def _count_admitted(self, ran: int, padded: int,
+                        whole: bool = False) -> None:
         """One admission's work: the tokens the request needed run (the
         uncached suffix on the paged path) and the padded length the
-        program(s) ran; their ratio is the work the buckets waste."""
+        program(s) ran; their ratio is the work the buckets waste. And
+        its attention's: the query-key pairs a head scored, summed over
+        the layers, beside the bucket's square — less than it where a
+        ``whole``-bucket admission was read in query blocks
+        (layers/tp_attn.prefill_positions_scored: what lies above a
+        block's diagonal or before a window layer's band is not
+        scored); every other admission path counts the square."""
         obs.counter("engine.admit_prompt_tokens").inc(ran)
         obs.counter("engine.admit_bucket_tokens").inc(padded)
+        model = self.engine.model
+        windows = (getattr(model, "windows", None)
+                   or (None,) * model.config.num_hidden_layers)
+        square = len(windows) * padded * padded
+        scored = square
+        if whole and self.engine.prefill_mode != "sp":
+            heads = model.attn.num_heads // model.mesh.shape[model.axis]
+            scored = sum(n * prefill_positions_scored(heads, padded, w)
+                         for w, n in collections.Counter(windows).items())
+        obs.counter("attn.prefill_positions_scored").inc(scored)
+        obs.counter("attn.prefill_positions_square").inc(square)
 
     def _mark_admitted(self, row: int, prompt_len: int) -> None:
         """Host bookkeeping of an admission; the device's copy of the
