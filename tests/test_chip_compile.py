@@ -249,6 +249,12 @@ def test_decode_step_and_admission_write_the_caches_in_place(
         # The real step: its gemm_ar kernels (xla_ar prefill has none).
         assert ("tpu_custom_call" in text) == (name == "step")
         assert not re.findall(r"\[8,4096,8,128\]\S* copy\(", entry), name
+        if name != "step":
+            # The head multiplies the one row the admission reads: no
+            # float32 logits of bucket x vocabulary (ISSUE 38; 1.24 GB
+            # and 3.5 ms of a 2048 admission before).
+            assert not re.search(r"f32\[(?:1,)?(?:128|2048),151936\]", text)
+            assert re.search(r"f32\[1,151936\]", text), name
         if name == "admit_2048":
             # No (S, S) score tensor: four blocks of 512 rows against the
             # keys up to their own end, every one assigned to the fast

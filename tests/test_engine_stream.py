@@ -296,3 +296,144 @@ def test_admission_read_in_query_blocks_seats_the_same_row(
         np.testing.assert_allclose(got, want, rtol=1e-4, atol=1e-4)
     assert square == square0 == scored0 == 2 * 512 * 512
     assert scored == 2 * 128 * (128 + 256 + 384 + 512)
+
+
+# -- an admission computes the one logit row it reads (ISSUE 38) -----------
+
+_VOCAB = 96     # no other dimension of these engines' programs
+
+
+def _one_row_engine(paged: bool, key):
+    """A dense engine on a small mesh, XLA paths: the scratch-prefill
+    family (whole and chunked admissions) or the paged sp family (cold
+    and prefix-hit admissions)."""
+    from jax.sharding import Mesh
+    devs = jax.devices()[:2]
+    mesh = (Mesh(np.array(devs).reshape(1, 2), ("tp", "sp")) if paged
+            else Mesh(np.array(devs[:1]), ("tp",)))
+    cfg = ModelConfig(hidden_size=32, intermediate_size=64,
+                      num_hidden_layers=2, num_attention_heads=4,
+                      num_key_value_heads=2, head_dim=16, vocab_size=_VOCAB,
+                      max_position_embeddings=64, dtype=jnp.float32)
+    if paged:
+        model = DenseLLM(cfg, mesh=mesh, axis="tp", sp_axis="sp",
+                         impl="xla", fwd_mode="sp")
+        eng = Engine(model, batch=3, max_seq=64, prefill_mode="sp",
+                     decode_mode="sp", paged=True, page_size=4)
+    else:
+        model = DenseLLM(cfg, mesh=mesh, axis="tp", impl="xla")
+        eng = Engine(model, batch=3, max_seq=64, prefill_mode="xla_ar",
+                     decode_mode="gemm_ar")
+    return eng, model.init(key)
+
+
+def _all_row_reference(eng, params, prompt, gen_len):
+    """What the all-position contract gives: every row of the prompt's
+    logits from a plain forward (no ``logits_at``), its row ``len - 1``
+    read on the host, the K/V it wrote; and ``Engine.serve``'s greedy
+    continuation of the same prompt."""
+    model = eng.model
+    mode = "xla" if eng.paged else "xla_ar"
+    solo_eng = Engine(model, batch=1, max_seq=64, prefill_mode=mode,
+                      decode_mode="xla_ar")
+    logits, kv = jax.jit(lambda p, i, k: model.forward(
+        p, i, k, 0, mode=mode))(params, jnp.asarray([prompt], jnp.int32),
+                                solo_eng.kv.init())
+    assert logits.shape == (1, len(prompt), _VOCAB)
+    first = int(np.argmax(np.asarray(logits)[0, -1]))
+    served = np.asarray(solo_eng.serve(
+        params, jnp.asarray([prompt], jnp.int32), gen_len))[0].tolist()
+    assert served[len(prompt)] == first
+    return first, kv, served[len(prompt):]
+
+
+@pytest.mark.parametrize("length", [11, 16], ids=["short", "bucket"])
+@pytest.mark.parametrize("case",
+                         ["whole", "chunked", "paged", "paged_prefix"])
+def test_admission_reads_one_row_and_seats_what_all_rows_gave(
+        key, case, length):
+    """Every admission path tells the model which row it reads; first
+    token, seated lanes, ``token`` / ``offsets`` / key and the decode
+    that follows equal the all-position contract's, for a prompt
+    shorter than its bucket and one exactly a bucket long, and
+    ``engine.admit_head_rows`` counts one row per admission program."""
+    from triton_dist_tpu import obs
+    paged = case.startswith("paged")
+    eng, params = _one_row_engine(paged, key)
+    prompt = np.random.default_rng(length).integers(
+        1, _VOCAB, length).tolist()
+    gen_len = 4
+    first_ref, kv_ref, gen_ref = _all_row_reference(eng, params, prompt,
+                                                    gen_len)
+    was = obs.enabled()
+    obs.enable()
+    try:
+        sess = eng.stream_session(params)
+        if case == "paged_prefix":
+            # Leave the prompt's first two pages in the prefix cache.
+            sess.prefill_into_row(0, prompt[:8] + [7, 7, 7], gen_budget=4)
+            sess.retire_row(0)
+        key0 = np.asarray(eng.key).copy()
+        c0 = dict(obs.snapshot().get("counters", {}))
+        row = 1
+        first = sess.prefill_into_row(
+            row, prompt, gen_budget=gen_len,
+            chunk=4 if case == "chunked" else None)
+        while first is None:
+            first = sess.prefill_step(row)
+        c1 = obs.snapshot()["counters"]
+    finally:
+        if not was:
+            obs.disable()
+    delta = {k: c1[k] - c0.get(k, 0) for k in (
+        "engine.admit_head_rows", "engine.admit_bucket_tokens")}
+    cached = 8 if case == "paged_prefix" else 0
+    assert sess.admit_info["cached"] == cached
+    programs = -(-length // 4) if case == "chunked" else 1
+    assert delta["engine.admit_head_rows"] == programs
+    assert delta["engine.admit_bucket_tokens"] == (
+        programs * 4 if case == "chunked" else 16 if not cached else 8)
+    assert first == first_ref
+    assert np.asarray(sess.token)[row] == first
+    assert np.asarray(sess.offsets)[row] == length
+    np.testing.assert_array_equal(np.asarray(eng.key), key0)   # greedy
+    if not paged:
+        for (ck, cv), (rk, rv) in zip(sess.caches, kv_ref):
+            for got, want in ((ck, rk), (cv, rv)):
+                np.testing.assert_allclose(
+                    np.asarray(got)[row, :length],
+                    np.asarray(want)[0, :length], rtol=1e-5, atol=1e-5)
+    got = [first]
+    while len(got) < gen_len:
+        got += sess.decode_burst()[row]
+    assert got == gen_ref
+    sess.close()
+
+
+@pytest.mark.parametrize("program", ["admit", "chunk", "paged"])
+def test_admission_program_holds_no_all_position_logits(key, program):
+    """The lowered admission of a dense engine multiplies ONE row by the
+    head: no float32 value of bucket x vocabulary anywhere in it."""
+    eng, params = _one_row_engine(program == "paged", key)
+    sess = eng.stream_session(params)
+    lb = 16
+    ids = sess._padded_ids([3] * 11, lb)
+    state = (sess.token, sess.offsets, eng.key)
+    if program == "admit":
+        text = eng._admit.lower(params, sess.caches, ids, np.int32(11),
+                                np.int32(1), *state).as_text()
+    elif program == "paged":
+        text = eng._admit.lower(params, sess.caches, ids, np.int32(11),
+                                np.int32(1), sess.cur_table,
+                                *state).as_text()
+    else:
+        small = [(jnp.zeros((1, lb) + ck.shape[2:], ck.dtype),
+                  jnp.zeros((1, lb) + cv.shape[2:], cv.dtype))
+                 for ck, cv in sess.caches]
+        text = eng._build_admit_chunk().lower(
+            params, small, ids, np.int32(0), np.int32(11), None).as_text()
+    sess.close()
+    assert f"x{_VOCAB}xf32>" in text
+    for rows in (f"{lb}x{_VOCAB}xf32", f"1x{lb}x{_VOCAB}xf32"):
+        assert rows not in text, rows
+    assert f"tensor<1x1x{_VOCAB}xf32>" in text

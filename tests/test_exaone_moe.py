@@ -466,10 +466,13 @@ def test_admission_in_query_blocks_seats_the_same_rings_and_lanes(
 # -- the programs of the configuration the benchmark already has ----------
 
 # sha256 of the jaxpr text (addresses blanked) of the stream step and the
-# admission of a small qwen3 engine, as the parent commit traces them.
+# admission of a small qwen3 engine: the step as PR 34's parent traced it
+# (nothing since has touched it: PR 38's ``logits_at=None`` is that
+# program letter for letter), the admission as PR 38 left it (the head
+# multiplies the one row that is read; 0e19a6ba... before).
 PARENT_JAXPRS = {
     "step": "1fa7b1ae50ec1c1bc33fc1808ff1f81e153688085df61e2b795d4ef2714900ed",
-    "admit": "0e19a6baa5724b4cd96c2b9e9b0daadb365a4e0c5923b0a3a5ef32bb91453942",
+    "admit": "f08c3aa8ea70d40574e039001160dd113d69d9f0e0ef66e466b0d16927033981",
 }
 
 
@@ -498,6 +501,38 @@ def test_qwen_stream_programs_trace_as_the_parent_did(mesh1, program):
     text = re.sub(r"0x[0-9a-f]+", "0x", str(jaxpr))
     assert hashlib.sha256(text.encode()).hexdigest() \
         == PARENT_JAXPRS[program]
+
+
+# The same for this file's K-EXAONE share, as PR 38's parent traced them:
+# the model already computed one logit row, so giving every model
+# ``logits_at`` (ISSUE 38) left its admission and its step eqn for eqn
+# what they were: the benchmark's control cell by construction.
+EXAONE_PARENT_JAXPRS = {
+    "step": "26045bbc793ec9052d8f804cd5217c89401e152de53a191a419927773321a16d",
+    "admit": "037fc8d9a7729e73d52c1ecd58cd8976b259d0b08f7b243246f487cee72d46c0",
+}
+
+
+@pytest.mark.parametrize("program", sorted(EXAONE_PARENT_JAXPRS))
+def test_exaone_stream_programs_trace_as_the_parent_did(mesh1, program):
+    cfg = dataclasses.replace(ModelConfig.from_hf_config(HF),
+                              dtype=jnp.float32)
+    eng = engine(AutoLLM.build(cfg, mesh=mesh1, axis="tp", impl="xla"))
+    params = jax.eval_shape(eng.model.init, jax.random.PRNGKey(0))
+    caches = jax.eval_shape(eng.kv.init)
+    zeros = np.zeros((4,), np.int32)
+    token = np.zeros((4 + len(eng.count_names),), np.int32)
+    if program == "step":
+        jaxpr = jax.make_jaxpr(eng._build_stream_step())(
+            params, caches, token, zeros, jax.random.PRNGKey(0),
+            np.zeros((4,), bool), None)
+    else:
+        jaxpr = jax.make_jaxpr(eng._build_admit())(
+            params, caches, np.zeros((1, 16), np.int32), np.int32(5),
+            np.int32(1), token, zeros, eng.key)
+    text = re.sub(r"0x[0-9a-f]+", "0x", str(jaxpr))
+    assert hashlib.sha256(text.encode()).hexdigest() \
+        == EXAONE_PARENT_JAXPRS[program]
 
 
 @pytest.mark.parametrize("rows,bound", [(4, 64), (64, 256)])

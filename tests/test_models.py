@@ -649,3 +649,82 @@ def test_hf_transformers_generation_parity(devices):
     ours = np.asarray(Engine(model, batch=1, max_seq=32).serve(
         params, jnp.asarray(ids), 5, stop_tokens=()))
     np.testing.assert_array_equal(ours, ref)
+
+
+# -- one logit row (ISSUE 38) -----------------------------------------------
+
+def _one_row_case(case, devices):
+    """(model, mode, cache manager kwargs) on a small mesh, XLA paths."""
+    from jax.sharding import Mesh
+    if case == "sp":
+        mesh = Mesh(np.array(devices[:2]).reshape(1, 2), ("tp", "sp"))
+        cfg = ModelConfig(hidden_size=32, intermediate_size=64,
+                          num_hidden_layers=2, num_attention_heads=4,
+                          num_key_value_heads=2, head_dim=16, vocab_size=96,
+                          max_position_embeddings=64, dtype=jnp.float32)
+        model = DenseLLM(cfg, mesh=mesh, axis="tp", sp_axis="sp",
+                         impl="xla", fwd_mode="sp")
+        return model, "sp", {"seq_shard": True, "axis": "sp"}
+    mesh = Mesh(np.array(devices[:2]), ("tp",))
+    if case == "moe":
+        model = Qwen3MoE(tiny_moe_cfg(), mesh=mesh, axis="tp", impl="xla")
+        return model, "xla", {"axis": "tp"}
+    model = DenseLLM(tiny_dense_cfg(), mesh=mesh, axis="tp", impl="xla")
+    return model, "xla_ar", {"axis": "tp"}
+
+
+@pytest.mark.parametrize("case", ["dense", "sp", "moe"])
+def test_logits_at_is_that_row_of_all_rows(devices, key, case):
+    """``forward(..., logits_at=i)`` is row ``i`` of ``forward(...)``:
+    the same operands through the same head, one row of them, and the
+    caches written are the same (the layers run on all S positions)."""
+    model, mode, kvkw = _one_row_case(case, devices)
+    c = model.config
+    params = model.init(key)
+    b, s, t = 2, 8, 16
+    ids = jax.random.randint(jax.random.PRNGKey(5), (b, s), 0,
+                             c.vocab_size, jnp.int32)
+
+    def caches():
+        return KVCacheManager(c.num_hidden_layers, b, t,
+                              c.num_key_value_heads, c.head_dim,
+                              mesh=model.mesh, dtype=c.dtype, **kvkw).init()
+
+    full, want_caches = jax.jit(lambda p, i, kv: model.forward(
+        p, i, kv, 0, mode=mode))(params, ids, caches())
+    assert full.shape == (b, s, c.vocab_size)
+    one_row = jax.jit(lambda p, i, kv, at: model.forward(
+        p, i, kv, 0, mode=mode, logits_at=at))
+    for i in (0, 5, s - 1):
+        row, got_caches = one_row(params, ids, caches(), jnp.int32(i))
+        assert row.shape == (b, 1, c.vocab_size)
+        np.testing.assert_allclose(np.asarray(row[:, 0]),
+                                   np.asarray(full[:, i]), rtol=1e-5,
+                                   atol=1e-5)
+        for got, want in zip(jax.tree.leaves(got_caches),
+                             jax.tree.leaves(want_caches)):
+            np.testing.assert_array_equal(np.asarray(got),
+                                          np.asarray(want))
+
+
+@pytest.mark.parametrize("reader", ["verify_burst", "decode_step", "train"])
+def test_all_position_readers_still_get_every_row(devices, key, reader):
+    """Who passes no ``logits_at`` traces what it did: the speculative
+    verify burst (per-row offsets, all k + 1 positions read), the decode
+    step and the training forward get (B, S, V)."""
+    model, mode, kvkw = _one_row_case("dense", devices)
+    c = model.config
+    b, s = 2, 1 if reader == "decode_step" else 3
+    params = jax.eval_shape(model.init, key)
+    kv = jax.eval_shape(KVCacheManager(
+        c.num_hidden_layers, b, 16, c.num_key_value_heads, c.head_dim,
+        mesh=model.mesh, dtype=c.dtype, **kvkw).init)
+    ids = jax.ShapeDtypeStruct((b, s), jnp.int32)
+    if reader == "train":
+        fwd = lambda p, i, k: model.forward(p, i, k, 0, mode=mode,
+                                            remat=True)
+    else:
+        fwd = lambda p, i, k: model.forward(
+            p, i, k, jnp.zeros((b,), jnp.int32), mode=mode)
+    logits, _ = jax.eval_shape(fwd, params, ids, kv)
+    assert logits.shape == (b, s, c.vocab_size)

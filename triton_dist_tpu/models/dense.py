@@ -126,7 +126,8 @@ class DenseLLM:
     # -- forward -----------------------------------------------------------
     def forward(self, params: dict, input_ids: jax.Array, kv_caches,
                 offset, mode: str | None = None, kv_start=None,
-                remat: bool = False, block_table=None, kv_need=None):
+                remat: bool = False, block_table=None, kv_need=None,
+                logits_at=None):
         """input_ids: (B, S) int32; kv_caches: [(k, v)] * L; offset: scalar
         write position. Returns (logits (B, S, V), new_caches).
 
@@ -147,13 +148,20 @@ class DenseLLM:
         attention then reads only the window that covers them
         (layers/tp_attn._attention_core). The stream decode step passes
         it; ``mode="sp"`` has ``kv_len`` of its own and ignores it.
+
+        ``logits_at`` (traced int, a position inside ``[0, S)``): compute
+        the logits of that one position only, (B, 1, V). The caller that
+        reads one row of a prompt's logits (an admission, ``serve``'s
+        prefill) says which; the layers still run on all S positions,
+        whose K/V are the product.
         """
         c = self.config
         mode = mode or self.fwd_mode
         if mode == "sp":
             assert kv_start is None, "mode='sp' has no ragged support yet"
             return self.forward_sp(params, input_ids, kv_caches, offset,
-                                   remat=remat, block_table=block_table)
+                                   remat=remat, block_table=block_table,
+                                   logits_at=logits_at)
         assert block_table is None, "paged caches need mode='sp'"
         b, s = input_ids.shape
         offset = jnp.asarray(offset, jnp.int32)
@@ -188,13 +196,17 @@ class DenseLLM:
             new_caches.append(cache)
 
         x = rms_norm(x, params["final_norm"], c.rms_norm_eps)
+        if logits_at is not None:
+            x = jax.lax.dynamic_slice_in_dim(
+                x.reshape(b, s, c.hidden_size), logits_at, 1, axis=1)[:, 0]
         logits = jnp.dot(x.astype(jnp.float32),
                          params["lm_head"].T.astype(jnp.float32))
-        return logits.reshape(b, s, c.vocab_size), new_caches
+        return logits.reshape(b, -1, c.vocab_size), new_caches
 
     # -- sequence-parallel forward (long-context path) ---------------------
     def forward_sp(self, params: dict, input_ids: jax.Array, kv_caches,
-                   offset, remat: bool = False, block_table=None):
+                   offset, remat: bool = False, block_table=None,
+                   logits_at=None):
         """Sequence-parallel forward: the long-context path the reference
         serves with ``SpFlashDecodeLayer`` + AG-attention
         (sp_ag_attention_inter_node.py:504, sp_flash_decode_layer.py),
@@ -224,6 +236,10 @@ class DenseLLM:
         into the allocated pages, decode writes one position and runs
         the paged distributed flash decode. vLLM-style slot reuse at
         the whole-model level (Engine(paged=True)).
+
+        ``logits_at`` (traced int, a position inside ``[0, S)``): compute
+        the logits of that one position only, (B, 1, V), as in
+        :meth:`forward`.
         """
         from jax.sharding import NamedSharding
         from triton_dist_tpu.ops.flash_decode import (
@@ -470,6 +486,8 @@ class DenseLLM:
             new_caches.append(cache)
 
         x = rms_norm(x, params["final_norm"], eps)
+        if logits_at is not None:
+            x = jax.lax.dynamic_slice_in_dim(x, logits_at, 1, axis=1)
         logits = jnp.einsum("bsh,vh->bsv", x.astype(jnp.float32),
                             params["lm_head"].astype(jnp.float32))
         return logits, new_caches

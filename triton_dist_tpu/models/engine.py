@@ -510,11 +510,12 @@ class Engine:
                 step_s = min(chunk, s - done_pos)
                 logits, caches = self.model.forward(
                     params, input_ids[:, done_pos:done_pos + step_s],
-                    caches, done_pos, mode="sp")
+                    caches, done_pos, mode="sp", logits_at=step_s - 1)
                 done_pos += step_s
         else:
             logits, caches = self.model.forward(
                 params, input_ids, caches, 0, mode=self.prefill_mode,
+                logits_at=s - 1,
                 kv_start=None if self.prefill_mode == "sp" else kv_start,
                 **({"block_table": table} if table is not None else {}))
         self.kv.inc_offset(s)
@@ -671,9 +672,10 @@ class Engine:
     def _first_token(self, logits, idx, caches, token, offsets, key, row,
                      length, counts=None):
         """Tail of the admission programs: sample the first token at
-        position ``idx`` of ``logits`` and seat the row. ``counts`` (a
-        counting model's, else None) ride home behind the first token:
-        what the host reads back is then a vector."""
+        position ``idx`` of ``logits`` (0 in every admission: the
+        forward computed the one row that is read) and seat the row.
+        ``counts`` (a counting model's, else None) ride home behind the
+        first token: what the host reads back is then a vector."""
         last = jax.lax.dynamic_slice_in_dim(logits, idx, 1, axis=1)[:, 0]
         key, sub = self._draw_key(key)
         first = sample_token(last, sub, self.temperature, self.top_k,
@@ -685,19 +687,19 @@ class Engine:
 
     def _prompt_forward(self, params, ids, small, offset, length):
         """An admission's forward over ``ids`` (1, S) at ``offset`` into
-        the scratch caches: ``(logits, small, counts or None)``. A
-        counting model is told which positions are the prompt's (the
-        bucket's pad is routed to no expert) and computes the ONE logit
-        row that is read, the prompt's last position: its logits are
-        (1, 1, V)."""
-        model, mode = self.model, self.prefill_mode
-        if not self.count_names:
-            return (*model.forward(params, ids, small, offset, mode=mode),
-                    None)
-        live = (offset + jnp.arange(ids.shape[1]) < length)[None]
-        at = jnp.clip(length - 1 - offset, 0, ids.shape[1] - 1)
-        return model.forward(params, ids, small, offset, mode=mode,
-                             live=live, logits_at=at, counted=True)
+        the scratch caches: ``(logits, small, counts or None)``. One
+        contract for every model: it computes the ONE logit row that is
+        read, the prompt's last position (clipped into this slice of a
+        chunked admission), so the logits are (1, 1, V). A counting
+        model is also told which positions are the prompt's (the
+        bucket's pad is routed to no expert) and hands its counts back."""
+        s = ids.shape[1]
+        counting = ({"live": (offset + jnp.arange(s) < length)[None],
+                     "counted": True} if self.count_names else {})
+        out = self.model.forward(
+            params, ids, small, offset, mode=self.prefill_mode,
+            logits_at=jnp.clip(length - 1 - offset, 0, s - 1), **counting)
+        return out if counting else (*out, None)
 
     def _seat_lanes(self, caches, small, row, length):
         """Row ``row``'s lane of every layer's cache rewritten from the
@@ -797,7 +799,8 @@ class Engine:
         compiles one program per bucket, not per distinct length (a
         public stream of arbitrary lengths must not compile-storm —
         code-review r3g). The pad suffix is causally invisible to the
-        first token (sampled at traced position ``length``-1), and its
+        first token (the one logit row computed, traced position
+        ``length``-1), and its
         scattered K/V slots are overwritten by the row's own decode
         steps before the per-row mask ever exposes them — the same
         argument that makes stale-lane reuse safe."""
@@ -810,9 +813,8 @@ class Engine:
             logits, small, counts = self._prompt_forward(
                 params, ids, small, 0, length)
             new_caches = self._seat_lanes(caches, small, row, length)
-            return self._first_token(
-                logits, length - 1 if counts is None else 0, new_caches,
-                token, offsets, key, row, length, counts)
+            return self._first_token(logits, 0, new_caches, token, offsets,
+                                     key, row, length, counts)
         return admit
 
     def _build_admit_paged(self):
@@ -826,11 +828,11 @@ class Engine:
         def admit(params, pools, ids, length, row, table, token, offsets,
                   key):
             logits, pools = model.forward(
-                params, ids, pools, 0, mode=mode,
+                params, ids, pools, 0, mode=mode, logits_at=length - 1,
                 block_table=jax.lax.dynamic_slice_in_dim(table, row, 1,
                                                          axis=1))
-            return self._first_token(logits, length - 1, pools, token,
-                                     offsets, key, row, length)
+            return self._first_token(logits, 0, pools, token, offsets,
+                                     key, row, length)
         return admit
 
     def _build_admit_paged_prefix(self):
@@ -851,10 +853,11 @@ class Engine:
                   offsets, key):
             logits, pools = model.forward(
                 params, ids, pools, start, mode=mode,
+                logits_at=length - 1,
                 block_table=jax.lax.dynamic_slice_in_dim(table, row, 1,
                                                          axis=1))
-            return self._first_token(logits, length - 1, pools, token,
-                                     offsets, key, row, start + length)
+            return self._first_token(logits, 0, pools, token, offsets,
+                                     key, row, start + length)
         return admit
 
     def _build_admit_chunk(self):
@@ -864,50 +867,34 @@ class Engine:
         ``_attention_core`` chunk-at-offset path). Compiled once per
         (chunk, scratch-length) pair; the serving scheduler interleaves
         these between shared decode steps so a long prompt's admission
-        never stalls the rows already decoding (docs/serving.md)."""
-        model, mode = self.model, self.prefill_mode
-        if self.count_names:
-            # A counting model's chunk also takes the prompt's length
-            # and the counts of the chunks before it, and hands back
-            # their sum: the admission's counts reach the host once,
-            # with its first token (_build_admit_finish).
-            @jit_rewriting_caches
-            def counted_chunk(params, small, ids, offset, length, counts):
-                logits, small, more = self._prompt_forward(
-                    params, ids, small, offset, length)
-                return logits, small, counts + more
-            return counted_chunk
+        never stalls the rows already decoding (docs/serving.md).
 
+        The slice takes the prompt's ``length`` and hands back the one
+        logit row the admission may read, (1, 1, V): the last slice's is
+        the prompt's last position. ``counts`` are a counting model's
+        from the slices before this one (None otherwise) and come back
+        with this slice's added: the admission's counts reach the host
+        once, with its first token (_build_admit_finish)."""
         @jit_rewriting_caches
-        def chunk_step(params, small, ids, offset):
-            return model.forward(params, ids, small, offset, mode=mode)
+        def chunk_step(params, small, ids, offset, length, counts):
+            logits, small, more = self._prompt_forward(
+                params, ids, small, offset, length)
+            return logits, small, more if counts is None else counts + more
         return chunk_step
 
     def _build_admit_finish(self):
-        """Tail of a chunked admission: sample the first token at the
-        prompt's true last position inside the final chunk's logits,
+        """Tail of a chunked admission: sample the first token from the
+        final chunk's one logit row (the prompt's true last position),
         then scatter the scratch prefix into row ``row``'s lane — the
         same pad-slot safety argument as ``_build_admit`` (pad K/V are
         causally invisible and overwritten before any mask exposes
-        them)."""
-
-        if self.count_names:
-            # The last chunk of a counting model computed the one logit
-            # row that is read (index 0), and its counts come along.
-            @jit_rewriting_caches
-            def counted_finish(small, caches, logits, length, row, counts,
-                               token, offsets, key):
-                new_caches = self._seat_lanes(caches, small, row, length)
-                return self._first_token(logits, 0, new_caches, token,
-                                         offsets, key, row, length, counts)
-            return counted_finish
-
+        them). A counting model's ``counts`` come along (else None)."""
         @jit_rewriting_caches
-        def finish(small, caches, logits, idx, length, row, token, offsets,
-                   key):
+        def finish(small, caches, logits, length, row, counts, token,
+                   offsets, key):
             new_caches = self._seat_lanes(caches, small, row, length)
-            return self._first_token(logits, idx, new_caches, token,
-                                     offsets, key, row, length)
+            return self._first_token(logits, 0, new_caches, token, offsets,
+                                     key, row, length, counts)
         return finish
 
     @staticmethod
@@ -1304,7 +1291,7 @@ class StreamSession:
             eng._admit, self.params, self._padded_ids(prompt, lb),
             np.int32(len(prompt)), np.int32(row))
         self.admit_info = {"cached": 0}
-        self._count_admitted(len(prompt), lb, whole=True)
+        self._count_admitted(len(prompt), lb, head_rows=1, whole=True)
         self._mark_admitted(row, len(prompt))
         self._spec_start(row, prompt, first, gen_budget)
         return first
@@ -1369,7 +1356,7 @@ class StreamSession:
         kv.register_prefix(row, prompt, hashes=hashes)
         self._note_prefix(row, L, cached)
         self.admit_info = {"cached": cached}
-        self._count_admitted(L - cached, lb)
+        self._count_admitted(L - cached, lb, head_rows=1)
         self._mark_admitted(row, L)
         self._spec_start(row, prompt, first, gen_budget)
         return first
@@ -1407,7 +1394,9 @@ class StreamSession:
             eng._admit_finish = eng._build_admit_finish()
         self._pending[row] = {
             "ids": self._padded_ids(prompt, lb), "len": len(prompt),
-            "chunk": chunk, "pos": 0, "budget": gen_budget,
+            "chunk": chunk, "pos": 0, "budget": gen_budget, "head_rows": 0,
+            "counts": (np.zeros((len(eng.count_names),), np.int32)
+                       if eng.count_names else None),
             "small": [(jnp.zeros((1, lb) + ck.shape[2:], ck.dtype),
                        jnp.zeros((1, lb) + cv.shape[2:], cv.dtype))
                       for ck, cv in self.caches]}
@@ -1434,29 +1423,20 @@ class StreamSession:
         st = self._pending[row]
         c = st["chunk"]
         ids = st["ids"][:, st["pos"]:st["pos"] + c]
-        if eng.count_names:
-            logits, st["small"], st["counts"] = eng._admit_chunk(
-                self.params, st["small"], ids, np.int32(st["pos"]),
-                np.int32(st["len"]), st.get("counts", np.zeros(
-                    (len(eng.count_names),), np.int32)))
-        else:
-            logits, st["small"] = eng._admit_chunk(
-                self.params, st["small"], ids, np.int32(st["pos"]))
+        logits, st["small"], st["counts"] = eng._admit_chunk(
+            self.params, st["small"], ids, np.int32(st["pos"]),
+            np.int32(st["len"]), st["counts"])
+        st["head_rows"] += logits.shape[1]      # what the slice computed
         st["pos"] += c
         if st["pos"] < st["ids"].shape[1]:
             return None
         del self._pending[row]
-        if eng.count_names:
-            first = self._run_admission(
-                eng._admit_finish, st["small"], logits, np.int32(st["len"]),
-                np.int32(row), st["counts"])
-        else:
-            idx = st["len"] - 1 - (st["pos"] - c)   # last real token's
-            first = self._run_admission(            # index in the final
-                eng._admit_finish, st["small"], logits, np.int32(idx),
-                np.int32(st["len"]), np.int32(row))     # chunk
+        first = self._run_admission(
+            eng._admit_finish, st["small"], logits, np.int32(st["len"]),
+            np.int32(row), st["counts"])
         self.admit_info = {"cached": 0}
-        self._count_admitted(st["len"], st["ids"].shape[1])
+        self._count_admitted(st["len"], st["ids"].shape[1],
+                             head_rows=st["head_rows"])
         self._mark_admitted(row, st["len"])
         self._spec_start(row, st["ids"][0, :st["len"]].tolist(), first,
                          st.get("budget"))
@@ -1579,11 +1559,15 @@ class StreamSession:
         self._spec_start(row, prompt, int(first), gen_budget)
         return int(first)
 
-    def _count_admitted(self, ran: int, padded: int,
+    def _count_admitted(self, ran: int, padded: int, *, head_rows: int,
                         whole: bool = False) -> None:
         """One admission's work: the tokens the request needed run (the
         uncached suffix on the paged path) and the padded length the
-        program(s) ran; their ratio is the work the buckets waste. And
+        program(s) ran; their ratio is the work the buckets waste.
+        ``head_rows``: the logit rows its programs computed, 1 for each
+        that was told the row it reads (``logits_at``) and its S for one
+        that was not: beside the padded length, the share of the bucket
+        that went through the output head. And
         its attention's: the query-key pairs a head scored, summed over
         the layers, beside the bucket's square — less than it where a
         ``whole``-bucket admission was read in query blocks
@@ -1592,6 +1576,7 @@ class StreamSession:
         scored); every other admission path counts the square."""
         obs.counter("engine.admit_prompt_tokens").inc(ran)
         obs.counter("engine.admit_bucket_tokens").inc(padded)
+        obs.counter("engine.admit_head_rows").inc(head_rows)
         model = self.engine.model
         windows = (getattr(model, "windows", None)
                    or (None,) * model.config.num_hidden_layers)

@@ -135,15 +135,17 @@ class Qwen3MoE:
     # -- forward -----------------------------------------------------------
     def forward(self, params: dict, input_ids: jax.Array, kv_caches,
                 offset, mode: str | None = None, kv_start=None,
-                block_table=None, kv_need=None):
+                block_table=None, kv_need=None, logits_at=None):
         """Same contract as DenseLLM.forward; MoE FFN needs the
-        row-sharded layout (modes xla / ag_rs)."""
+        row-sharded layout (modes xla / ag_rs). ``logits_at`` (traced
+        int): compute the logits of that one position only, (B, 1, V)."""
         c = self.config
         mode = mode or self.fwd_mode
         if mode == "sp":
             assert kv_start is None, "mode='sp' has no ragged support yet"
             return self.forward_sp(params, input_ids, kv_caches, offset,
-                                   block_table=block_table)
+                                   block_table=block_table,
+                                   logits_at=logits_at)
         assert block_table is None, "paged caches need mode='sp'"
         if self.moe_parallel == "ep":
             moe_mode = "ep"
@@ -185,9 +187,12 @@ class Qwen3MoE:
             new_caches.append(cache)
 
         x = rms_norm(x, params["final_norm"], c.rms_norm_eps)
+        if logits_at is not None:
+            x = jax.lax.dynamic_slice_in_dim(
+                x.reshape(b, s, c.hidden_size), logits_at, 1, axis=1)[:, 0]
         logits = jnp.dot(x.astype(jnp.float32),
                          params["lm_head"].T.astype(jnp.float32))
-        return logits.reshape(b, s, c.vocab_size), new_caches
+        return logits.reshape(b, -1, c.vocab_size), new_caches
 
     # -- sequence-parallel forward (REUSED from DenseLLM: the
     # attention/cache/chunk/paged machinery is model-agnostic; only the
