@@ -1,46 +1,44 @@
-"""Telemetry subsystem: metrics, spans, event tracing, exposition.
+"""Telemetry subsystem: what each module records, and who reads it.
 
-Process-local counters / gauges / fixed-bucket histograms
-(``obs.registry``), wall-clock spans that land in a histogram, the
-xprof trace, AND the structured event timeline (``obs.span``),
-per-host snapshot merge mirroring the reference's rank-0
-``gather_object`` trace merge, and a Prometheus text exposition path
-served over the ModelServer protocol (``obs.exposition``).
+- ``registry``: process-local counters, gauges, fixed-bucket histograms
+  and ``obs.span`` (a histogram, an xprof ``TraceAnnotation`` and a
+  timeline begin/end in one). Read by ``{"cmd": "metrics"}``, the
+  benchmark's counter and span readers (``benchmark/layer_metrics/``),
+  ``tools/top.py`` and ``tools/report.py``; ``scoped_registry`` keeps
+  several replicas in one process apart.
+- ``exposition``: Prometheus text of a snapshot and the merge of
+  per-host snapshots. Read by the server's ``metrics`` verb and
+  ``tools/trace_export.py``.
+- ``trace``: the event timeline, per-thread rings plus named tracks,
+  with the request's trace id on every event. Read by ``obs.flight``
+  and ``tools/trace_export.py`` (Chrome / Perfetto JSON).
+- ``flight``: dumps the timeline's trailing window on a watchdog trip,
+  an open breaker, a serve-loop failure, SIGTERM or
+  ``{"cmd": "dump_trace"}``. Read by an operator, in Perfetto.
+- ``compile``: one record per JAX trace, lowering, back-end compile or
+  persistent-cache load, by function (``compile_log``), the
+  ``compile.*`` counters and the ``compile`` track of the timeline.
+  Read by the benchmark's ``setup.*`` metrics, the ``metrics`` verb and
+  every flight dump.
+- ``attrib``: per-request waterfalls (queue wait, prefill, decode).
+  Read by the reply's ``timing`` block, ``{"cmd": "request_stats"}``
+  and ``tools/top.py``.
+- ``slo``: rolling-window percentiles and burn rates that arm the
+  flight recorder on a latency breach. Read by the scheduler's pump
+  and the ``health`` verb.
+- ``fleet``: per-replica health snapshots and their merge across
+  endpoints, with staleness. Read by ``serving/router.py``,
+  ``{"cmd": "health"}`` and ``tools/fleet_top.py``.
+- ``history``: an opt-in sampler (``TDT_HISTORY=1``) of every gauge and
+  counter rate into ring-buffered series, with trend detectors. Read
+  by ``{"cmd": "history"}``, ``tools/top.py`` and flight dumps.
+- ``devprof``: bounded ``jax.profiler`` captures from the pump and
+  their reduction to per-op device time. Read by ``tools/report.py``
+  and ``tools/profile_export.py``.
 
-The timeline side (``obs.trace``) records begin/end + instant events
-into per-thread ring buffers, exports Chrome trace-event / Perfetto
-JSON through ``tools/trace_export.py``, and doubles as a flight
-recorder (``obs.flight``): the most recent event window dumps to disk
-on watchdog trips, breaker opens, serve-loop failures, SIGTERM, or an
-explicit ``{"cmd": "dump_trace"}``.
-
-The serving SLO observatory (ISSUE 8) sits on top: ``obs.slo`` keeps
-rolling-window percentiles + multi-window burn rates that arm the
-flight recorder on a latency-SLO breach, and ``obs.attrib`` keeps
-per-request latency waterfalls (queue → prefill → decode) the server
-returns inline and ``tools/top.py`` renders live.
-
-The fleet plane (ISSUE 14, ``obs.fleet``) lifts all of it across N
-replicas: per-replica ``ReplicaHealth`` snapshots behind the server's
-cheap ``{"cmd": "health"}`` verb, a ``FleetView`` aggregator that
-scrapes endpoints concurrently, tracks staleness (live → stale →
-down), and merges snapshots correctly by metric kind, plus the
-``placement_score`` the multi-replica router will consume
-(docs/observability.md "Fleet view"). Several replicas in one process
-keep distinct metrics via ``obs.scoped_registry``.
-
-The history plane (ISSUE 16, ``obs.history``) retains what everything
-above only reads point-in-time: an opt-in sampler (``TDT_HISTORY=1``)
-records every gauge (value) and counter (rate) into ring-buffered
-series behind the server's ``{"cmd": "history"}`` verb, pure trend
-math (``slope`` / ``ema`` / ``eta_to``) forecasts crossings, and
-early-warning detectors arm the flight recorder BEFORE the SLO breach
-— with the trailing series embedded in every dump as Perfetto counter
-tracks (docs/observability.md "History plane").
-
-Disabled by default at zero hot-path cost; flip metrics on with
-``obs.enable()`` (the ModelServer does this at construction;
-``TDT_TRACE=1`` makes that enable tracing too).
+Disabled by default at zero hot-path cost; ``obs.enable()`` switches
+the registry and the compile log on (the ModelServer does this at
+construction; ``TDT_TRACE=1`` makes that enable tracing too).
 
 See docs/observability.md for the metric name catalog and event
 schema.
@@ -75,7 +73,8 @@ from triton_dist_tpu.obs.exposition import (  # noqa: F401
     render_prometheus,
 )
 from triton_dist_tpu.obs import (  # noqa: F401
-    attrib, devprof, fleet, flight, history, slo, trace)
+    attrib, compile, devprof, fleet, flight, history, slo, trace)
+from triton_dist_tpu.obs.compile import compile_log  # noqa: F401
 from triton_dist_tpu.obs.slo import (  # noqa: F401
     SLOTarget,
     SLOTracker,

@@ -355,7 +355,8 @@ def enable(registry: Registry | None = None) -> Registry:
 
     ``TDT_TRACE=1`` makes this also switch event tracing on
     (``obs.trace``), so bench/smoke runs that enable metrics get the
-    timeline for free."""
+    timeline for free. The compile log's listeners (``obs.compile``)
+    are installed here, once per process."""
     global _REGISTRY
     if registry is not None:
         _REGISTRY = registry
@@ -363,6 +364,8 @@ def enable(registry: Registry | None = None) -> Registry:
         _REGISTRY = Registry()
     if _trace.env_enabled() and not _trace.enabled():
         _trace.enable()
+    from triton_dist_tpu.obs import compile as _compile
+    _compile.install()
     return _REGISTRY
 
 

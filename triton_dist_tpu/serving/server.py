@@ -655,6 +655,9 @@ def main():  # pragma: no cover - manual demo
         configure_compile_cache)
     from triton_dist_tpu.runtime.topology import topology_aware_grid
     configure_compile_cache()
+    # Before the first program is built, not only at ModelServer's
+    # construction: the compile log then holds the start-up's compiles.
+    obs.enable()
     devices = np.array(jax.devices())
     mesh = Mesh(topology_aware_grid(devices, devices.shape), ("tp",))
     if args.model_dir:
