@@ -20,6 +20,7 @@ is NumPy values that upload with the call. Three guards:
 """
 
 import ast
+import dataclasses
 import inspect
 import textwrap
 
@@ -36,7 +37,9 @@ from triton_dist_tpu.serving import Scheduler
 # -- (a) no eager op on the pump's path ------------------------------------
 
 #: The session methods around the one device program of an admission.
-PUMP_PATH = ("_admit_whole", "_admit_paged", "_run_admission",
+PUMP_PATH = ("launch_into_row", "prefill_into_row", "take_first_tokens",
+             "_admit_whole", "_admit_paged", "_launch_admission",
+             "_collect_first", "_collect_deferred", "_run_admission",
              "_padded_ids", "_prefill_slice", "_mark_admitted")
 
 
@@ -176,35 +179,129 @@ def test_admission_seats_its_row_and_no_other(mesh8, key, case):
 # -- (c) the sampling key ---------------------------------------------------
 
 PROMPTS = [[1, 2, 3, 4, 5], [9, 8, 7], [11, 12, 13, 14, 15, 16, 17, 18, 19]]
-#: Recorded from the parent commit (dcaf085: key split eagerly on the
-#: host before every admission and every step) with this file's model,
-#: ``Engine(batch=2, temperature=0.8, top_k=20, seed=7)``, 6 tokens each.
+#: (model, driver, temperature) -> 6 tokens for each of PROMPTS, with
+#: ``Engine(batch=2, temperature=..., top_k=20, seed=7)``. The sampled
+#: dense rows of "scheduler" and "serve_stream" were recorded from
+#: dcaf085 (key split eagerly on the host before every admission and
+#: every step), all of them again from aac321c, the parent of ISSUE 40
+#: (an admission's first token read before the step is launched): the
+#: programs, their order and so the key's draws are the same when the
+#: step is launched behind the admissions and the first tokens are read
+#: after it. "scheduler": one request at a time. "scheduler_batch": the
+#: three enqueued at once, so the first turn launches two admissions
+#: and the step before it reads anything, and the third is admitted
+#: mid-decode. "exaone": the counting model (its counts ride home
+#: behind the first token), window layers and held experts.
 GOLDEN = {
-    "scheduler": [[15, 41, 50, 4, 63, 56], [6, 51, 13, 15, 45, 6],
-                  [37, 38, 45, 0, 5, 45]],
-    "serve_stream": [[15, 16, 4, 63, 56, 6], [62, 61, 44, 10, 59, 63],
-                     [26, 13, 33, 59, 26, 33]],
+    ("dense", "scheduler", 0.8):
+        [[15, 41, 50, 4, 63, 56],
+         [6, 51, 13, 15, 45, 6],
+         [37, 38, 45, 0, 5, 45]],
+    ("dense", "scheduler_batch", 0.8):
+        [[15, 16, 4, 63, 56, 6],
+         [62, 61, 44, 10, 59, 63],
+         [26, 13, 33, 59, 26, 33]],
+    ("dense", "serve_stream", 0.8):
+        [[15, 16, 4, 63, 56, 6],
+         [62, 61, 44, 10, 59, 63],
+         [26, 13, 33, 59, 26, 33]],
+    ("dense", "scheduler", 0.0):
+        [[23, 50, 21, 17, 63, 42],
+         [63, 20, 17, 63, 56, 14],
+         [12, 10, 40, 3, 12, 53]],
+    ("dense", "scheduler_batch", 0.0):
+        [[23, 50, 21, 17, 63, 42],
+         [63, 20, 17, 63, 56, 14],
+         [12, 10, 40, 3, 12, 53]],
+    ("dense", "serve_stream", 0.0):
+        [[23, 50, 21, 17, 63, 42],
+         [63, 20, 17, 63, 56, 14],
+         [12, 10, 40, 3, 12, 53]],
+    ("exaone", "scheduler", 0.8):
+        [[21, 34, 25, 4, 21, 14],
+         [59, 61, 30, 19, 59, 40],
+         [27, 50, 7, 1, 29, 20]],
+    ("exaone", "scheduler_batch", 0.8):
+        [[21, 50, 15, 21, 14, 6],
+         [5, 61, 12, 46, 59, 3],
+         [22, 57, 35, 22, 15, 37]],
+    ("exaone", "serve_stream", 0.8):
+        [[21, 50, 15, 21, 14, 6],
+         [5, 61, 12, 46, 59, 3],
+         [22, 57, 35, 22, 15, 37]],
+    ("exaone", "scheduler", 0.0):
+        [[37, 13, 34, 19, 62, 43],
+         [61, 24, 19, 16, 5, 59],
+         [0, 1, 59, 48, 48, 60]],
+    ("exaone", "scheduler_batch", 0.0):
+        [[37, 13, 34, 19, 62, 43],
+         [61, 24, 19, 16, 5, 59],
+         [0, 1, 59, 48, 48, 60]],
+    ("exaone", "serve_stream", 0.0):
+        [[37, 13, 34, 19, 62, 43],
+         [61, 24, 19, 16, 5, 59],
+         [0, 1, 59, 48, 48, 60]],
 }
 
 
-@pytest.mark.parametrize("driver", sorted(GOLDEN))
-def test_sampled_run_reproduces_the_parents_tokens(mesh8, key, driver):
-    model, params = _model(mesh8, key, 8, 8, 4)
-    eng = Engine(model, batch=2, max_seq=64, prefill_mode="xla_ar",
-                 decode_mode="gemm_ar", temperature=0.8, top_k=20, seed=7)
-    if driver == "scheduler":
-        # One request at a time: the order of admissions and steps, and
-        # so of the key's splits, is then the same in every run.
-        sched = Scheduler(eng, params).start()
-        try:
-            out = [sched.submit(p, 6).result(timeout=300) for p in PROMPTS]
-        finally:
-            sched.stop()
-    else:
+def _exaone():
+    """A small K-EXAONE share (tests/test_exaone_moe.py has the full
+    preset): dense, then S S F, 16 experts top-2 of which 4 are held."""
+    from benchmark.harness.builders import exaone as builder
+    from triton_dist_tpu.models import AutoLLM
+    hf = dict(
+        hidden_size=64, intermediate_size=128, num_hidden_layers=4,
+        num_attention_heads=4, num_key_value_heads=2, head_dim=16,
+        vocab_size=64, max_position_embeddings=4096, rms_norm_eps=1e-5,
+        rope_parameters={"rope_theta": 1e6, "rope_type": "default"},
+        sliding_window=8, model_type="exaone_moe",
+        layer_types=["sliding_attention"] * 3 + ["full_attention"],
+        mlp_layer_types=["dense"] + ["sparse"] * 3, first_k_dense_replace=1,
+        num_experts=16, num_experts_per_tok=2, moe_intermediate_size=32,
+        num_shared_experts=1, scoring_func="sigmoid", norm_topk_prob=True,
+        routed_scaling_factor=2.5, expert_parallel={"world": 4, "rank": 0})
+    mesh = Mesh(np.array(jax.devices()[:1]), ("tp",))
+    cfg = dataclasses.replace(ModelConfig.from_hf_config(hf),
+                              dtype=jnp.float32)
+    llm = AutoLLM.build(cfg, mesh=mesh, axis="tp", impl="xla")
+    ref = dict(hf, expert_parallel=(4, 0), rope_theta=1e6,
+               balance_shape=(4, 64))
+    params = jax.tree.map(lambda a: a.astype(jnp.float32),
+                          builder.make_params(ref, mesh, 11))
+    return llm, llm.shard_params(params)
+
+
+@pytest.mark.parametrize(
+    "model,driver,temperature", sorted(GOLDEN),
+    ids=["-".join(map(str, k)) for k in sorted(GOLDEN)])
+def test_sampled_run_reproduces_the_parents_tokens(mesh8, key, model,
+                                                   driver, temperature):
+    llm, params = (_exaone() if model == "exaone"
+                   else _model(mesh8, key, 8, 8, 4))
+    eng = Engine(llm, batch=2, max_seq=64, prefill_mode="xla_ar",
+                 decode_mode="gemm_ar", temperature=temperature, top_k=20,
+                 seed=7)
+    if driver == "serve_stream":
         # Three prompts through two rows: an admission mid-decode.
         out = [o[len(p):] for o, p in zip(
             eng.serve_stream(params, PROMPTS, 6, stop_tokens=()), PROMPTS)]
-    assert [[int(t) for t in o] for o in out] == GOLDEN[driver]
+    else:
+        sched = Scheduler(eng, params).start()
+        try:
+            if driver == "scheduler":
+                # One request at a time: the order of admissions and
+                # steps, and so of the key's splits, is then the same
+                # in every run.
+                out = [sched.submit(p, 6).result(timeout=300)
+                       for p in PROMPTS]
+            else:
+                # One atomic enqueue: the same order in every run too.
+                out = [r.result(timeout=300)
+                       for r in sched.submit_many(PROMPTS, 6)]
+        finally:
+            sched.stop()
+    assert [[int(t) for t in o] for o in out] == GOLDEN[
+        model, driver, temperature]
 
 
 @pytest.mark.parametrize("temperature", [0.0, 0.8])

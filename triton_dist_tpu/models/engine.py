@@ -72,6 +72,18 @@ def _seat_row(token, offsets, row, first, length):
 _seat_row = jax.jit(_seat_row, inline=True)
 
 
+#: What ``StreamSession.launch_into_row`` returns for an admission whose
+#: first token is still on the device.
+DEFERRED = object()
+
+
+def _cache_lost(cause: BaseException) -> KVCacheLost:
+    return KVCacheLost(
+        "the session's KV cache was lost: an admission program failed "
+        f"after its caches were donated ({type(cause).__name__}: {cause}); "
+        "every row's K/V went with it, the session must be reopened")
+
+
 #: The auto policy's prior when no measurement exists: the only silicon
 #: evidence on record has the mega one-program step 1.49x the plain
 #: jitted step (docs/perf.md "First chip contact").
@@ -1065,7 +1077,9 @@ class StreamSession:
       whole prompt in one admission program, or (``chunk=N``) the
       first N tokens with the rest advanced by :meth:`prefill_step`
       between decode steps, so a long prompt's admission never stalls
-      the rows already decoding;
+      the rows already decoding; :meth:`launch_into_row` is the same
+      verb for a driver that can take the first token later, with the
+      next :meth:`decode_burst`'s (:meth:`take_first_tokens`);
     * :meth:`decode_step` — ONE shared decode step for every live row
       (frozen rows re-emit their token and do not advance);
     * :meth:`retire_row` — free a finished row for the next admission.
@@ -1127,6 +1141,12 @@ class StreamSession:
         self._decode_kind: str | None = None  # decided path, unconsumed
         self._host_off = [0] * b     # host shadow of per-row offsets
         self._pending: dict[int, dict] = {}   # row → chunked-prefill state
+        # Admissions launched and not read yet, (row, un-read first
+        # token) in admission order, and what was read of them behind a
+        # step, (row, token, perf_counter() at the read), until
+        # take_first_tokens() hands it over.
+        self._deferred: list[tuple] = []
+        self._first_tokens: list[tuple] = []
         # Speculative decoding (ISSUE 13): drafter + per-row budget
         # clamps; decode_burst() runs draft → widened verify → atomic
         # multi-token commit when this is set (docs/serving.md
@@ -1193,6 +1213,28 @@ class StreamSession:
         mid-decode. Both shipped drivers (serve_stream, the serving
         scheduler) pass it; omitting it risks a mid-decode pool
         exhaustion on a tight pool.
+
+        This is :meth:`launch_into_row` with the first token read at
+        once, whatever the admission is.
+        """
+        first = self.launch_into_row(row, prompt, chunk, gen_budget)
+        if first is DEFERRED:
+            first = self._collect_first(self._deferred.pop()[1])
+        return first
+
+    def launch_into_row(self, row: int, prompt, chunk: int | None = None,
+                        gen_budget: int | None = None):
+        """:meth:`prefill_into_row` for a driver that runs the shared
+        step next and can take the first token after launching it.
+
+        Returns what :meth:`prefill_into_row` returns, or ``DEFERRED``:
+        the admission program is dispatched, the row is live and the
+        session's state is the program's outputs, but the first token
+        has not been read. The next :meth:`decode_burst` dispatches its
+        step BEHIND the admission and only then reads it, so the chip
+        goes from one program to the next while the host catches up;
+        :meth:`take_first_tokens` hands it over. Which admissions are
+        deferred the session decides from what it is (``_admit_whole``).
         """
         assert not self.live[row] and row not in self._pending, \
             f"row {row} is occupied"
@@ -1220,21 +1262,28 @@ class StreamSession:
                 self._check_caches(e)
                 raise
 
+    def take_first_tokens(self) -> list:
+        """``[(row, token, t), ...]`` in admission order: the first
+        tokens of the ``DEFERRED`` admissions that a step has been
+        launched behind since the last call, ``t`` the
+        ``time.perf_counter()`` at which each reached the host (while
+        that step ran, before its own tokens)."""
+        taken, self._first_tokens = self._first_tokens, []
+        return taken
+
     def _check_caches(self, cause: BaseException) -> None:
-        """Called on a failed admission. The admission programs donate
-        the session's caches, so one that fails after dispatch (device
-        OOM, a runtime error surfacing at the first token) has deleted
-        every row's K/V, not only the admitted row's: say so, and name
-        the culprit, instead of letting the next shared step die on
-        "Array has been deleted". A failure before dispatch (tracing,
-        a host-side upload, a shape error) consumed nothing and
-        degrades its one request as before."""
+        """Called on an exception out of an admission's CALL. A program
+        call that raises (tracing, a host-side upload, a shape error)
+        has consumed nothing and degrades its one request; should the
+        caches it was given be gone all the same, that is every row's
+        K/V, not only the admitted row's: say so, and name the culprit,
+        instead of letting the next shared step die on "Array has been
+        deleted". (A call that raised rebound nothing, so the leaves
+        are the ones it was given. The other kind of failure, a
+        program that dies on the device, surfaces where its first
+        token is read: ``_collect_first``.)"""
         if any(leaf.is_deleted() for leaf in jax.tree.leaves(self.caches)):
-            raise KVCacheLost(
-                "the session's KV cache was lost: an admission program "
-                "failed after its caches were donated "
-                f"({type(cause).__name__}: {cause}); every row's K/V "
-                "went with it, the session must be reopened") from cause
+            raise _cache_lost(cause) from cause
 
     def _bucket(self, n: int) -> int:
         """Power-of-two prompt bucket rounded up to a multiple of the
@@ -1250,30 +1299,49 @@ class StreamSession:
         ids[0, :len(tokens)] = tokens
         return ids
 
-    def _run_admission(self, program, head, *inputs) -> int:
+    def _launch_admission(self, program, head, *inputs):
         """THE dispatch of an admission: ``program(head, caches,
         *inputs, token, offsets, key)`` seats the row in-graph (its
         first token, its write offset, the next sampling key), so what
         the host adds is NumPy values that upload with the call.
-        Returns the first token.
+        Returns the first token UNREAD, for ``_collect_first``.
 
-        Everything is rebound only once the first token materializes: a
-        program that fails after dispatch then leaves ``self.caches`` on
-        the donated (deleted) leaves, which is how ``_check_caches``
-        tells it from a failure that consumed nothing, and a paged
-        caller's rollback still sees the row un-admitted."""
+        The session's state is rebound to the program's outputs at
+        once: the next program, another admission or the shared step,
+        takes them without anything having been read. A call that
+        raises rebinds nothing."""
         eng = self.engine
-        first, caches, token, offsets, key = program(
+        first, self.caches, self.token, self.offsets, eng.key = program(
             head, self.caches, *inputs, self.token, self.offsets, eng.key)
-        if eng.count_names:
-            first = np.asarray(first)
-            self._note_counts(first[1:])
-            first = int(first[0])
-        else:
-            first = int(first)
-        self.caches, self.token, self.offsets, eng.key = (
-            caches, token, offsets, key)
         return first
+
+    def _collect_first(self, first) -> int:
+        """The blocking read of a launched admission's first token (a
+        counting model's counts come home behind it). An error HERE is
+        the program's, after it was given the caches: they are lost
+        whatever the leaves say, and the session with them."""
+        try:
+            first = np.asarray(first).reshape(-1)
+        except Exception as e:
+            raise _cache_lost(e) from e
+        self._note_counts(first[1:])
+        return int(first[0])
+
+    def _run_admission(self, program, head, *inputs) -> int:
+        """Launch and collect with nothing between them: every
+        admission whose first token the host needs at once."""
+        return self._collect_first(
+            self._launch_admission(program, head, *inputs))
+
+    def _collect_deferred(self) -> None:
+        """Behind a step's dispatch: read the first tokens of the
+        launched admissions, in admission order, each stamped as it
+        reaches the host."""
+        pending, self._deferred = self._deferred, []
+        for row, first in pending:
+            self._first_tokens.append(
+                (row, self._collect_first(first), time.perf_counter()))
+        obs.counter("engine.admit_deferred").inc(len(pending))
 
     def _note_counts(self, counts) -> None:
         """A counting model's in-program counts, as they came back
@@ -1283,16 +1351,25 @@ class StreamSession:
                 obs.counter(name).inc(n)
 
     def _admit_whole(self, row: int, prompt: list, lb: int, args: dict,
-                     gen_budget: int | None = None) -> int:
+                     gen_budget: int | None = None):
         eng = self.engine
         if eng.paged:
             return self._admit_paged(row, prompt, lb, args, gen_budget)
-        first = self._run_admission(
+        first = self._launch_admission(
             eng._admit, self.params, self._padded_ids(prompt, lb),
             np.int32(len(prompt)), np.int32(row))
         self.admit_info = {"cached": 0}
         self._count_admitted(len(prompt), lb, head_rows=1, whole=True)
         self._mark_admitted(row, len(prompt))
+        if self.spec is None and (gen_budget or 0) > 1:
+            # Nothing on the host needs the token's VALUE before the
+            # next step is launched: the program seated it on the
+            # device, and the step's done mask is the host's own. (A
+            # drafter is seeded with it, and a request's only token
+            # retires its row before any step: those read it now.)
+            self._deferred.append((row, first))
+            return DEFERRED
+        first = self._collect_first(first)
         self._spec_start(row, prompt, first, gen_budget)
         return first
 
@@ -1703,6 +1780,12 @@ class StreamSession:
             self.token, self.caches, self.offsets = step_fn(
                 self.params, self.caches, self.token, self.offsets, sub,
                 done, self.cur_table)
+            if self._deferred:
+                # The step is queued behind this turn's admissions:
+                # their first tokens are read only now, while the
+                # device runs on, and the step's own after them.
+                with obs.span("engine.first_token_wait"):
+                    self._collect_deferred()
             if obs.enabled() or _trace.enabled():
                 # Real step latency, not the async enqueue (same
                 # observer cost as the serve() decode span).
@@ -1864,6 +1947,7 @@ class StreamSession:
         active for a retired row after close() is a leak the
         quick-tier audit flags (tests/test_scheduler.py)."""
         self._pending.clear()
+        self._deferred.clear()
         for r in range(self.batch):
             if self.live[r]:
                 self.retire_row(r)

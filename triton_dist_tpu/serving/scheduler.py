@@ -21,6 +21,13 @@ Design:
 - **One engine thread.** Only the pump thread touches the Engine's
   ``StreamSession``; handler threads interact through the queue and
   per-request done-events, so no generation lock exists at all.
+- **A turn launches before it reads.** The admissions a turn was
+  given are dispatched back to back (``StreamSession.launch_into_row``),
+  the shared step behind them, and only then are the admissions' first
+  tokens read, each stamped as it reaches the host (``Request.t_first``),
+  and the step's tokens after them: the chip goes from program to
+  program while the host catches up (docs/serving.md "The order of a
+  turn").
 - **Fair FIFO admission with backpressure.** :meth:`Scheduler.submit`
   appends to a bounded queue (``max_waiting`` / ``TDT_MAX_WAITING``,
   default 64, or four times the engine's rows if that is more); a full queue raises :class:`QueueFull`, which the
@@ -103,6 +110,7 @@ import time
 import warnings
 
 from triton_dist_tpu import obs
+from triton_dist_tpu.models.engine import DEFERRED
 from triton_dist_tpu.models.kv_cache import KVCacheLost
 from triton_dist_tpu.obs import attrib, devprof, history, slo, trace
 
@@ -637,10 +645,13 @@ class Scheduler:
             self._session = sess
             occupancy.set(0)
 
-        def record(row: int, req: Request, tok: int) -> None:
+        def record(row: int, req: Request, tok: int,
+                   t: float | None = None) -> None:
+            """Book one token; ``t``: when it reached the host, where
+            that was before now (a deferred first token's stamp)."""
             req.tokens.append(tok)
             if req.t_first is None:
-                req.t_first = time.perf_counter()
+                req.t_first = time.perf_counter() if t is None else t
                 ttft_ms = (req.t_first - req.t_submit) * 1e3
                 obs.histogram("serving.ttft_ms").observe(ttft_ms)
                 if self.slo is not None:
@@ -718,7 +729,10 @@ class Scheduler:
                             req.preloaded["first"],
                             req.gen_len, req.preloaded["blocks"])
                     else:
-                        first = sess.prefill_into_row(
+                        # Launched, not waited for: where the session
+                        # can, the first token is read behind this
+                        # turn's step (DEFERRED).
+                        first = sess.launch_into_row(
                             row, req.prompt, chunk=self.prefill_chunk,
                             gen_budget=req.gen_len)
             except KVCacheLost as e:
@@ -736,9 +750,10 @@ class Scheduler:
             budgets[row] = req.gen_len
             if first is None:
                 prefilling.add(row)
-            else:
-                req.cached = (getattr(sess, "admit_info", None)
-                              or {}).get("cached", 0)
+                return
+            req.cached = (getattr(sess, "admit_info", None)
+                          or {}).get("cached", 0)
+            if first is not DEFERRED:
                 record(row, req, first)
 
         while True:
@@ -857,10 +872,18 @@ class Scheduler:
                             # 1..k+1 from a speculative verify step.
                             bursts = sess.decode_burst()
                     except Exception as e:  # noqa: BLE001
-                        # The SHARED step died: the cache state is
-                        # suspect (and donated away).
+                        # The SHARED step died, or an admission
+                        # launched before it did and its first token
+                        # said so: the cache state is suspect (and
+                        # donated away).
                         restart(e)
                         continue
+                    # First tokens that came home while the step ran,
+                    # with the instant they did; one that ends its
+                    # request retires the row, and the loop below
+                    # drops what the step made for it.
+                    for row, tok, t in sess.take_first_tokens():
+                        record(row, rows[row], tok, t)
                     bt = sess.last_burst_timing
                     for row, req in live:
                         if rows.get(row) is not req:   # failed above
@@ -873,7 +896,7 @@ class Scheduler:
                             req.verify_ms += bt["verify_ms"]
                         for tok in bursts.get(row, ()):
                             if rows.get(row) is not req:
-                                break   # retired mid-burst (stop/EOS)
+                                break   # retired (stop/EOS/first token)
                             record(row, req, int(tok))
             occupancy.set(len(rows))
             if work and self.slo is not None:
