@@ -158,7 +158,8 @@ class EPShareMoE:
                  num_held: int, shared_intermediate_size: int,
                  mesh: Mesh | None = None, axis: str = "tp",
                  dtype=jnp.bfloat16, impl: str = "pallas",
-                 norm_topk_prob: bool = True, scale: float = 1.0):
+                 norm_topk_prob: bool = True, scale: float = 1.0,
+                 n_group: int = 1, topk_group: int = 1):
         from triton_dist_tpu.layers.tp_mlp import TPMLP
         if mesh is None:
             from triton_dist_tpu.runtime.dist import get_mesh
@@ -171,6 +172,7 @@ class EPShareMoE:
         self.first_held, self.num_held = first_held, num_held
         self.dtype = dtype
         self.norm_topk_prob, self.scale = norm_topk_prob, scale
+        self.n_group, self.topk_group = n_group, topk_group
         self.shared = TPMLP(hidden_size, shared_intermediate_size,
                             mesh=mesh, axis=axis, dtype=dtype, impl=impl)
 
@@ -210,7 +212,7 @@ class EPShareMoE:
                          precision=jax.lax.Precision.HIGHEST)
         weights, idx = sigmoid_topk_routing(
             logits, params["e_bias"], self.topk, self.norm_topk_prob,
-            self.scale)
+            self.scale, self.n_group, self.topk_group)
         local = idx - self.first_held
         mine = (local >= 0) & (local < self.num_held)
         if live is not None:

@@ -2,7 +2,7 @@
 
 ``AutoLLM.from_pretrained`` (reference models/__init__.py:33) dispatches
 on the HF config's ``model_type``/MoE fields to ``DenseLLM``,
-``Qwen3MoE`` or ``ExaoneMoE`` and loads safetensors weights when present.
+``Qwen3MoE``, ``ExaoneMoE`` or ``LingHybrid`` and loads safetensors weights when present.
 """
 
 from __future__ import annotations
@@ -14,13 +14,14 @@ from triton_dist_tpu.models.config import ModelConfig, known_model_type
 from triton_dist_tpu.models.dense import DenseLLM
 from triton_dist_tpu.models.qwen_moe import Qwen3MoE
 from triton_dist_tpu.models.exaone_moe import ExaoneMoE
+from triton_dist_tpu.models.ling_hybrid import LingHybrid
 from triton_dist_tpu.models.kv_cache import KVCacheManager
 from triton_dist_tpu.models.engine import Engine, StreamSession, sample_token
 from triton_dist_tpu.models.spec import SpecConfig
 from triton_dist_tpu.models.train import make_train_step, cross_entropy_loss
 from triton_dist_tpu.models import presets
 
-__all__ = ["ModelConfig", "DenseLLM", "Qwen3MoE", "ExaoneMoE",
+__all__ = ["ModelConfig", "DenseLLM", "Qwen3MoE", "ExaoneMoE", "LingHybrid",
            "KVCacheManager",
            "Engine", "StreamSession", "sample_token", "AutoLLM", "make_train_step", "presets",
            "cross_entropy_loss", "SpecConfig"]
@@ -57,7 +58,7 @@ class AutoLLM:
         if name == "DenseLLM" and config.is_moe:
             name = "Qwen3MoE"
         cls = {"DenseLLM": DenseLLM, "Qwen3MoE": Qwen3MoE,
-               "ExaoneMoE": ExaoneMoE}[name]
+               "ExaoneMoE": ExaoneMoE, "LingHybrid": LingHybrid}[name]
         return cls(config, mesh=mesh, axis=axis, fwd_mode=fwd_mode,
                    impl=impl)
 

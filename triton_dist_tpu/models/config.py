@@ -31,6 +31,7 @@ MODEL_TYPES = {
     "llama": ("DenseLLM", False),
     "seed_oss": ("DenseLLM", False),
     "exaone_moe": ("ExaoneMoE", True),
+    "bailing_hybrid": ("LingHybrid", True),
 }
 
 
@@ -83,6 +84,26 @@ class ModelConfig:
     # router scores and selects over.
     ep_world: int = 1
     ep_rank: int = 0
+    # Group-limited routing: experts in ``n_group`` groups, the selection
+    # made among the best ``topk_group`` of them (1, 1: no limit).
+    n_group: int = 1
+    topk_group: int = 1
+    # bailing_hybrid (models/ling_hybrid.py): every ``layer_group_size``-th
+    # layer is latent attention (MLA: ``kv_lora_rank`` latent, query and
+    # key heads of ``qk_nope_head_dim + qk_rope_head_dim``, value heads of
+    # ``v_head_dim``), the others KDA linear attention (a conv of
+    # ``conv_size``, log-decay bounded below by ``kda_lower_bound``).
+    layer_group_size: int = 0
+    kv_lora_rank: int = 0
+    qk_nope_head_dim: int = 0
+    qk_rope_head_dim: int = 0
+    v_head_dim: int = 0
+    conv_size: int = 0
+    kda_lower_bound: float = 0.0
+    # Per layer: the SwiGLU clamps of the experts and the shared expert
+    # (0: none); and the multi-token-prediction layers after the last.
+    swiglu_limits: tuple = ()
+    num_nextn_predict_layers: int = 0
 
     @property
     def is_moe(self) -> bool:
@@ -145,7 +166,20 @@ class ModelConfig:
             sparse = ()
         rope = cfg.get("rope_parameters") or {}
         ep = cfg.get("expert_parallel") or {}
+        limits = tuple(zip(cfg.get("expert_swiglu_limit_list", [0] * n)[:n],
+                           cfg.get("share_expert_swiglu_limit_list",
+                                   [0] * n)[:n]))
         return cls(
+            n_group=cfg.get("n_group", 1), topk_group=cfg.get("topk_group", 1),
+            layer_group_size=cfg.get("layer_group_size", 0),
+            kv_lora_rank=cfg.get("kv_lora_rank") or 0,
+            qk_nope_head_dim=cfg.get("qk_nope_head_dim", 0),
+            qk_rope_head_dim=cfg.get("qk_rope_head_dim", 0),
+            v_head_dim=cfg.get("v_head_dim", 0),
+            conv_size=cfg.get("short_conv_kernel_size", 0),
+            kda_lower_bound=cfg.get("kda_lower_bound", 0.0),
+            swiglu_limits=limits if any(map(any, limits)) else (),
+            num_nextn_predict_layers=cfg.get("num_nextn_predict_layers", 0),
             layer_windows=windows, sparse_layers=sparse,
             num_shared_experts=cfg.get("num_shared_experts", 0),
             scoring_func=cfg.get("scoring_func", "softmax"),

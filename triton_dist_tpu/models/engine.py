@@ -24,6 +24,7 @@ import numpy as np
 
 from triton_dist_tpu import obs
 from triton_dist_tpu.obs import trace as _trace
+from triton_dist_tpu.layers.kda import scan_chunks
 from triton_dist_tpu.layers.tp_attn import (
     decode_window, prefill_positions_scored)
 from triton_dist_tpu.models.kv_cache import (
@@ -279,20 +280,27 @@ class Engine:
         # stream session's admission and step programs write and read.
         # The paths that know no rings refuse such a model here: none
         # of them may compute full attention in a window layer's place.
+        # So are latent lanes and recurrent state (a model's
+        # ``cache_kinds``, models/kv_cache.LatentLane / StateLanes): only
+        # the stream session's programs seat and step them.
         windows = tuple(getattr(model, "windows", ()) or ())
-        if any(windows):
+        kinds = tuple(getattr(model, "cache_kinds", ()) or ())
+        if any(windows) or any(kinds):
             cannot = [what for what, on in (
-                ("paged KV pools", paged),
+                ("paged KV pools (and with them the prefix cache and "
+                 "adopt_row)", paged),
                 ("the sequence-parallel modes (forward_sp)",
                  "sp" in (prefill_mode, decode_mode)),
                 ("the mega decode step", decode_path != "plain"),
                 ("the speculative verify step", self.spec is not None),
             ) if on]
+            has = ("sliding-window layers (ROADMAP R3)" if any(windows)
+                   else "latent-cache and recurrent-state layers "
+                        "(ROADMAP R2, R8)")
             if cannot:
                 raise NotImplementedError(
-                    f"{type(model).__name__} has sliding-window layers; "
-                    f"{' and '.join(cannot)} cannot serve them yet "
-                    f"(ROADMAP R3)")
+                    f"{type(model).__name__} has {has}; "
+                    f"{' and '.join(cannot)} cannot serve them yet")
         #: Names of the counters the model makes inside its programs
         #: (``forward(counted=True)``), or () — see
         #: :meth:`_build_stream_step_counted`.
@@ -335,7 +343,7 @@ class Engine:
             self.kv = KVCacheManager(
                 c.num_hidden_layers, batch, max_seq, c.num_key_value_heads,
                 c.head_dim, mesh=model.mesh, axis=model.axis, dtype=c.dtype,
-                windows=windows)
+                windows=windows, kinds=kinds)
         self.prefill_mode = prefill_mode
         self.decode_mode = decode_mode
         self.temperature = temperature
@@ -451,12 +459,13 @@ class Engine:
         rectangle — static shapes); the loop exits early once every row
         has stopped.
         """
-        if getattr(self.kv, "windows", ()):
+        if getattr(self.kv, "windows", ()) or getattr(self.kv, "kinds", ()):
             raise NotImplementedError(
                 "Engine.serve prefills a whole batch into the row caches, "
-                "where a sliding-window layer keeps rings: serve this "
-                "model through a stream session (serve_stream, the "
-                "scheduler, ModelServer)")
+                "where a sliding-window layer keeps rings and a latent or "
+                "recurrent layer its own lanes: serve this model through "
+                "a stream session (serve_stream, the scheduler, "
+                "ModelServer)")
         if self.spec is not None:
             # Explicit refusal, not a silent ignore (the PR-10 config-
             # check discipline): serve()'s rectangular decode loop has
@@ -717,16 +726,14 @@ class Engine:
         """Row ``row``'s lane of every layer's cache rewritten from the
         scratch prefix ``small``: the prefix itself at slot 0, or a
         window layer's ring of its last positions
-        (``KVCacheManager.lane``)."""
+        (``KVCacheManager.lane``); a recurrent layer's state whole, as
+        the prefill left it at the prompt's true length."""
         lane = getattr(self.kv, "lane", lambda i, x, n: x)
-        new_caches = []
-        for i, ((ck, cv), (sk, sv)) in enumerate(zip(caches, small)):
-            ck = jax.lax.dynamic_update_slice(
-                ck, lane(i, sk, length), (row, 0, 0, 0))
-            cv = jax.lax.dynamic_update_slice(
-                cv, lane(i, sv, length), (row, 0, 0, 0))
-            new_caches.append((ck, cv))
-        return new_caches
+        return [tuple(
+            jax.lax.dynamic_update_slice(
+                c, lane(i, sc, length), (row,) + (0,) * (c.ndim - 1))
+            for c, sc in zip(layer, scratch))
+            for i, (layer, scratch) in enumerate(zip(caches, small))]
 
     def _build_stream_step(self):
         """One decode step with PER-ROW write offsets: each live row
@@ -818,10 +825,7 @@ class Engine:
         argument that makes stale-lane reuse safe."""
         @jit_rewriting_caches
         def admit(params, caches, ids, length, row, token, offsets, key):
-            lb = ids.shape[1]                       # bucketed length
-            small = [(jnp.zeros((1, lb) + ck.shape[2:], ck.dtype),
-                      jnp.zeros((1, lb) + cv.shape[2:], cv.dtype))
-                     for ck, cv in caches]
+            small = self.kv.scratch(caches, ids.shape[1])  # bucket long
             logits, small, counts = self._prompt_forward(
                 params, ids, small, 0, length)
             new_caches = self._seat_lanes(caches, small, row, length)
@@ -1249,6 +1253,11 @@ class StreamSession:
         chunked = bool(
             chunk and not eng.paged and eng.prefill_mode != "sp"
             and n > chunk and -(-n // chunk) * chunk <= eng.kv.max_seq)
+        if chunked and eng.kv.recurrent:
+            raise NotImplementedError(
+                f"{type(eng.model).__name__} has recurrent-state layers: "
+                f"a chunked admission cannot carry their state from one "
+                f"slice to the next yet (ROADMAP R8)")
         # The padded length the admission program will run: whole
         # chunks, else the prompt's bucket (a prefix-cache hit on the
         # paged path shrinks it to the suffix's, see _admit_paged).
@@ -1479,9 +1488,7 @@ class StreamSession:
             "chunk": chunk, "pos": 0, "budget": gen_budget, "head_rows": 0,
             "counts": (np.zeros((len(eng.count_names),), np.int32)
                        if eng.count_names else None),
-            "small": [(jnp.zeros((1, lb) + ck.shape[2:], ck.dtype),
-                       jnp.zeros((1, lb) + cv.shape[2:], cv.dtype))
-                      for ck, cv in self.caches]}
+            "small": eng.kv.scratch(self.caches, lb)}
         return self._prefill_slice(row)
 
     def prefill_step(self, row: int):
@@ -1660,7 +1667,10 @@ class StreamSession:
         obs.counter("engine.admit_bucket_tokens").inc(padded)
         obs.counter("engine.admit_head_rows").inc(head_rows)
         model = self.engine.model
-        windows = (getattr(model, "windows", None)
+        # The softmax-attention layers: a model with other kinds names
+        # them (``attn_windows``), the others are all of them.
+        windows = (getattr(model, "attn_windows", None)
+                   or getattr(model, "windows", None)
                    or (None,) * model.config.num_hidden_layers)
         square = len(windows) * padded * padded
         scored = square
@@ -1670,6 +1680,15 @@ class StreamSession:
                          for w, n in collections.Counter(windows).items())
         obs.counter("attn.prefill_positions_scored").inc(scored)
         obs.counter("attn.prefill_positions_square").inc(square)
+        kda = getattr(model, "kda_layers", 0)
+        if kda:
+            # The sequential chunks of the recurrent layers' scan: those
+            # that hold a prompt position, of the bucket's
+            # (layers/kda.scan_chunks).
+            obs.counter("kda.scan_chunks_run").inc(
+                kda * scan_chunks(ran, padded))
+            obs.counter("kda.scan_chunks_bucket").inc(
+                kda * scan_chunks(padded, padded))
 
     def _mark_admitted(self, row: int, prompt_len: int) -> None:
         """Host bookkeeping of an admission; the device's copy of the

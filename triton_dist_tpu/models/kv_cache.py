@@ -8,12 +8,15 @@ sharding, and the offset bookkeeping the reference keeps on the module.
 Head-sharded over TP by default (each rank caches its local heads — same
 as the reference, which caches after the column-parallel KV projection);
 ``seq_shard=True`` shards the T dim instead for SP decode
-(ops/flash_decode.py).
+(ops/flash_decode.py). A layer may keep another kind of cache instead
+(``kinds``): a latent lane (:class:`LatentLane`) or recurrent state
+(:class:`StateLanes`), replicated.
 """
 
 from __future__ import annotations
 
 import collections
+import dataclasses
 import functools
 
 import jax
@@ -84,17 +87,38 @@ def ring_lane(prefix: jax.Array, length, window: int) -> jax.Array:
     return jnp.take(prefix, jnp.clip(pos, 0, prefix.shape[1] - 1), axis=1)
 
 
+@dataclasses.dataclass(frozen=True)
+class LatentLane:
+    """A latent-attention layer's cache: ONE (B, T, ``width``) leaf, a
+    row's compressed K/V per position (``layers/mla``). Seated at an
+    admission like K and V: the prompt's positions at slot 0."""
+    width: int
+
+
+@dataclasses.dataclass(frozen=True)
+class StateLanes:
+    """A recurrent layer's cache: leaves (B,) + shape, one per
+    ``(shape, dtype)`` of ``shapes``, that do not grow with the context
+    (``layers/kda``: the state matrices and the conv's tail). An
+    admission computes them at the prompt's true length and REPLACES the
+    row's whole; a row's decode step rewrites them in place."""
+    shapes: tuple
+
+
 class KVCacheManager:
     """Per-layer (B, T, Hkv, D) caches. ``windows`` (one entry per layer:
     a sliding window, or None) gives a window layer a RING of ``window``
     positions per row in place of ``max_seq``: its cache does not grow
-    with the context."""
+    with the context. ``kinds`` (one entry per layer: a
+    :class:`LatentLane` or :class:`StateLanes`) gives the layers other
+    kinds of cache; every layer's cache is a tuple of leaves whose first
+    axis is the row."""
 
     def __init__(self, num_layers: int, batch: int, max_seq: int,
                  num_kv_heads: int, head_dim: int,
                  mesh: Mesh | None = None, axis: str = "tp",
                  dtype=jnp.bfloat16, seq_shard: bool = False,
-                 windows=None):
+                 windows=None, kinds=None):
         if mesh is None:
             from triton_dist_tpu.runtime.dist import get_mesh
             mesh = get_mesh()
@@ -111,10 +135,29 @@ class KVCacheManager:
         assert not any(windows) or (len(windows) == num_layers
                                     and not seq_shard), windows
         self.windows = windows if any(windows) else ()
+        self.kinds = tuple(kinds or ())
+        assert not self.kinds or (
+            len(self.kinds) == num_layers and not seq_shard
+            and not self.windows and all(isinstance(
+                k, (LatentLane, StateLanes)) for k in self.kinds)), kinds
+
+    @property
+    def recurrent(self) -> bool:
+        """Whether some layer keeps recurrent state (:class:`StateLanes`)."""
+        return any(isinstance(k, StateLanes) for k in self.kinds)
 
     def init(self):
-        """Allocate the cache pytree: [(k, v)] * L."""
+        """Allocate the cache pytree: [(k, v)] * L, a layer of another
+        kind its own leaves."""
         shape = (self.batch, self.max_seq, self.num_kv_heads, self.head_dim)
+        if self.kinds:
+            whole = NamedSharding(self.mesh, P())
+            return [(jnp.zeros((self.batch, self.max_seq, kind.width),
+                               self.dtype, device=whole),)
+                    if isinstance(kind, LatentLane) else
+                    tuple(jnp.zeros((self.batch,) + tuple(s), dt,
+                                    device=whole) for s, dt in kind.shapes)
+                    for kind in self.kinds]
         if not self.windows:
             return _zero_leaves(shape, self.dtype, self.sharding,
                                 self.num_layers)
@@ -122,10 +165,20 @@ class KVCacheManager:
                              self.dtype, self.sharding, 1)[0]
                 for w in self.windows]
 
+    def scratch(self, caches, lb: int) -> list:
+        """What an admission's prefill starts from, traced: per layer
+        a batch-1 copy of its leaves, zeros, a positional leaf (K, V, a
+        latent lane) ``lb`` positions long."""
+        kinds = self.kinds or (None,) * len(caches)
+        return [tuple(jnp.zeros((1,) + c.shape[1:] if isinstance(
+            kind, StateLanes) else (1, lb) + c.shape[2:], c.dtype)
+            for c in layer) for kind, layer in zip(kinds, caches)]
+
     def lane(self, layer: int, prefix: jax.Array, length) -> jax.Array:
         """What layer ``layer`` keeps of an admitted row whose prompt's
         K (or V) is ``prefix`` (1, S, Hkv, D): the prefix itself, or a
-        window layer's ring of its last positions."""
+        window layer's ring of its last positions. A latent lane's or a
+        state's leaf is kept as the admission left it."""
         if not self.windows or not self.windows[layer]:
             return prefix
         return ring_lane(prefix, length, self.windows[layer])

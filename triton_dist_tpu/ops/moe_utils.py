@@ -47,10 +47,17 @@ def topk_routing(router_logits: jax.Array, topk: int,
 
 def sigmoid_topk_routing(router_logits: jax.Array, bias: jax.Array,
                          topk: int, norm_topk_prob: bool = True,
-                         scale: float = 1.0):
+                         scale: float = 1.0, n_group: int = 1,
+                         topk_group: int = 1):
     """Sigmoid scores, selection by ``score + bias``, weights from the
-    scores alone (the aux-loss-free recipe of DeepSeek-V3 / K-EXAONE,
-    one group: no group limit).
+    scores alone (the aux-loss-free recipe of DeepSeek-V3 / K-EXAONE /
+    Ling).
+
+    With ``n_group > 1`` the selection is group-limited: the experts
+    fall in ``n_group`` groups of consecutive ids, a group's score is
+    the sum of its two best biased scores, and the ``topk`` experts are
+    taken among the ``topk_group`` best groups only. ``n_group == 1``
+    traces the one-group program and nothing else.
 
     Args:
       router_logits: (T, E) float logits over ALL routed experts.
@@ -62,7 +69,16 @@ def sigmoid_topk_routing(router_logits: jax.Array, bias: jax.Array,
       (weights (T, topk) float32, indices (T, topk) int32)
     """
     scores = jax.nn.sigmoid(router_logits.astype(jnp.float32))
-    _, indices = lax.top_k(scores + bias.astype(jnp.float32), topk)
+    choice = scores + bias.astype(jnp.float32)
+    if n_group > 1:
+        t, e = choice.shape
+        grouped = choice.reshape(t, n_group, e // n_group)
+        best = jnp.sum(lax.top_k(grouped, 2)[0], axis=-1)      # (T, G)
+        _, keep = lax.top_k(best, topk_group)
+        kept = jnp.any(jnp.arange(n_group)[None, None] == keep[..., None],
+                       axis=1)                                 # (T, G)
+        choice = jnp.where(kept[..., None], grouped, -jnp.inf).reshape(t, e)
+    _, indices = lax.top_k(choice, topk)
     weights = jnp.take_along_axis(scores, indices, axis=-1)
     if norm_topk_prob:
         weights = weights / jnp.sum(weights, axis=-1, keepdims=True)

@@ -258,6 +258,12 @@ class Scheduler:
         if prefill_chunk is not None and prefill_chunk <= 0:
             raise ValueError(
                 f"prefill_chunk must be positive: {prefill_chunk}")
+        if prefill_chunk and getattr(getattr(engine, "kv", None),
+                                     "recurrent", False):
+            raise NotImplementedError(
+                f"{type(engine.model).__name__} has recurrent-state "
+                f"layers: chunked admissions cannot carry their state "
+                f"yet (ROADMAP R8); serve it without prefill_chunk")
         self.prefill_chunk = prefill_chunk
         # The SLO observatory for this scheduler: rolling TTFT / TPOT /
         # queue-wait / pump-time windows + burn-rate targets. Targets
