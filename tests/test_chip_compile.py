@@ -349,3 +349,74 @@ def test_exaone_share_programs(meshes, monkeypatch, program):
         assert not re.search(r"f32\[1,8,8,2048,\d+\]", text)
         assert re.search(r"f32\[1,8,8,128,2048\]\{[^}]*S\(1\)\}", text)
         assert re.search(r"f32\[1,8,8,256,384\]\{[^}]*S\(1\)\}", text)
+
+
+@pytest.mark.parametrize("program", ["step", "admit"])
+def test_ling_programs_keep_the_latent_lane_as_stored(
+        meshes, monkeypatch, program):
+    """Ling-3.0-flash's published latent (``kv_lora_rank`` 512 +
+    ``qk_rope_head_dim`` 64) at a small cut (one KDA and one MLA layer,
+    both dense; 8 rows x 2048 positions, so the step's bounded read has
+    its chunked branch): the lane is stored 640 wide, the chip's compiler
+    lays it out positions-major (``{2,1,0}``) and neither the stream step
+    nor an admission holds a ``copy`` of a lane-shaped array. Stored 576
+    wide, the step transposed the whole lane three times a call (in, out,
+    and again before the chunked read)."""
+    import json
+    import os
+    import re
+    from benchmark.harness.builders import ling
+    from triton_dist_tpu.models import AutoLLM, Engine, ModelConfig
+    from triton_dist_tpu.models.kv_cache import LatentLane
+    monkeypatch.setenv("TDT_FORCE_COMPILED", "1")
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    with open(os.path.join(root, "benchmark", "configs",
+                           "ling-3.0-flash.json")) as f:
+        cfg = json.load(f)
+    mesh, layers, rows, max_seq = meshes[1], 2, 8, 2048
+    hf = dict(ling.model_dict(cfg), eos_token_id=None,
+              expert_parallel=cfg["expert_parallel"],
+              num_hidden_layers=layers, layer_group_size=layers,
+              first_k_dense_replace=layers,
+              expert_swiglu_limit_list=[0] * layers,
+              share_expert_swiglu_limit_list=[0] * layers)
+    llm = AutoLLM.build(ModelConfig.from_hf_config(hf), mesh=mesh,
+                        axis="tp", impl="pallas")
+    eng = Engine(llm, batch=rows, max_seq=max_seq, prefill_mode="xla_ar",
+                 decode_mode="xla_ar")
+    written = cfg["kv_lora_rank"] + cfg["qk_rope_head_dim"]
+    assert llm.kinds == ("kda", "mla") and llm.mla.latent_width == 640
+
+    def sds(shape, dtype=BF16):
+        return _sds(mesh, shape, P(), dtype)
+
+    params = jax.tree.map(
+        lambda a: sds(a.shape, a.dtype),
+        jax.eval_shape(llm.init, jax.random.PRNGKey(0)))
+    caches = [(sds((rows, max_seq, kind.width)),)
+              if isinstance(kind, LatentLane) else
+              tuple(sds((rows,) + tuple(s), dt) for s, dt in kind.shapes)
+              for kind in llm.cache_kinds]
+    cache_bytes = sum(int(np.prod(c.shape)) * c.dtype.itemsize
+                      for c in jax.tree.leaves(caches))
+    i32, counts = jnp.int32, len(eng.count_names)
+    key = sds((2,), jnp.uint32)
+    token, offsets = sds((rows + counts,), i32), sds((rows,), i32)
+    if program == "step":
+        lowered = eng._build_stream_step().lower(
+            params, caches, token, offsets, key, sds((rows,), jnp.bool_),
+            None)
+    else:
+        lowered = eng._build_admit().lower(
+            params, caches, sds((1, 128), i32), sds((), i32), sds((), i32),
+            token, offsets, key)
+    compiled = lowered.compile()
+    assert compiled.memory_analysis().alias_size_in_bytes == cache_bytes
+    text = compiled.as_text()
+    entry = text[text.rindex("ENTRY"):]
+    lane = rf"bf16\[{rows},{max_seq},640\]"
+    params_in = re.findall(lane + r"(\{[^}]*\}) parameter\(", entry)
+    assert params_in and all(lay.startswith("{2,1,0") for lay in params_in)
+    copies = re.findall(
+        rf"\[{rows},{max_seq},(?:{written}|640)\]\S* copy\(", text)
+    assert not copies, copies

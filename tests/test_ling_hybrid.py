@@ -141,11 +141,12 @@ def test_cache_kinds_hold_state_and_latent_lanes(mesh1):
             assert layer[0].dtype == jnp.float32
         else:
             assert isinstance(kind, LatentLane)
-            assert [c.shape for c in layer] == [(3, 64, 40)]
+            # rank 32 + rope 8, stored a whole 128-wide lane tile wide.
+            assert [c.shape for c in layer] == [(3, 64, 128)]
     small = kv.scratch(caches, 16)
     assert [tuple(c.shape for c in layer) for layer in small][:3] == [
         ((1, 4, 16, 16), (1, 3, 192)), ((1, 4, 16, 16), (1, 3, 192)),
-        ((1, 16, 40),)]
+        ((1, 16, 128),)]
     plain = KVCacheManager(2, 4, 64, 2, 16, mesh=mesh1)
     assert not plain.recurrent and not plain.kinds
 
@@ -248,15 +249,15 @@ def test_mla_absorbed_decode_matches_expanded(mesh1, t, need):
     against the expanded prefill's output at the same positions."""
     layer = MLAttn(32, 2, 8, 4, 8, 16, 6e6, mesh=mesh1, dtype=jnp.float32)
     params = layer.init(jax.random.PRNGKey(7))
-    s = 12
+    s, w = 12, layer.latent_width
     x = jax.random.normal(jax.random.PRNGKey(8), (2, s, 32))
     pos = jnp.tile(jnp.arange(s)[None], (2, 1))
     with jax.default_matmul_precision("highest"):
-        want, (lane,) = layer(params, x, pos, (jnp.zeros((2, s, 20)),),
+        want, (lane,) = layer(params, x, pos, (jnp.zeros((2, s, w)),),
                               jnp.int32(0))
     want = np.asarray(want).reshape(2, s, 32)
     offsets = jnp.asarray([9, 4], jnp.int32)
-    big = jnp.full((2, t, 20), 7.0).at[:, :s].set(lane)   # stale past s
+    big = jnp.full((2, t, w), 7.0).at[:, :s].set(lane)    # stale past s
     kv_need = None if need is None else jnp.int32(need)
     with jax.default_matmul_precision("highest"):
         got, (after,) = jax.jit(lambda big, xs: layer(
@@ -267,6 +268,52 @@ def test_mla_absorbed_decode_matches_expanded(mesh1, t, need):
     np.testing.assert_allclose(got[1], want[1, 4], rtol=1e-4, atol=1e-5)
     np.testing.assert_allclose(np.asarray(after)[0, 9], np.asarray(lane)[0, 9],
                                rtol=1e-5, atol=1e-6)
+
+
+@pytest.mark.parametrize("rank,rope,stored", [
+    (512, 64, 640), (32, 8, 128), (16, 4, 128), (448, 64, 512)])
+def test_latent_lane_is_stored_in_whole_lane_tiles(mesh1, rank, rope,
+                                                   stored):
+    """The stored width is ``rank + rope`` rounded up to 128-wide lane
+    tiles (Ling's 576 -> 640); the projection still writes ``rank +
+    rope``."""
+    layer = MLAttn(64, 2, 8, rope, 8, rank, 6e6, mesh=mesh1)
+    assert layer.latent_width == stored
+    assert stored % 128 == 0 and 0 <= stored - (rank + rope) < 128
+    shapes = jax.eval_shape(layer.init, jax.random.PRNGKey(0))
+    assert shapes["w_kva"].shape == (64, rank + rope)
+
+
+def test_stored_width_changes_no_output(mesh1):
+    """A prompt's expanded read and a decode step's absorbed read (whole
+    and chunked) give the same outputs from the stored lane as from one
+    exactly ``rank + rope`` wide, and the columns past ``rank + rope``
+    are written as zeros."""
+    layer = MLAttn(32, 2, 8, 4, 8, 16, 6e6, mesh=mesh1, dtype=jnp.float32)
+    params = layer.init(jax.random.PRNGKey(9))
+    written, t, s = 20, 2048, 12
+    x = jax.random.normal(jax.random.PRNGKey(10), (2, s, 32))
+    pos = jnp.tile(jnp.arange(s)[None], (2, 1))
+    offsets = jnp.asarray([s, 5], jnp.int32)
+    step_x = jax.random.normal(jax.random.PRNGKey(11), (2, 1, 32))
+
+    def run(width, kv_need):
+        with jax.default_matmul_precision("highest"):
+            out, (lane,) = layer(params, x, pos,
+                                 (jnp.zeros((2, s, width)),), jnp.int32(0))
+            big = jnp.zeros((2, t, width)).at[:, :s].set(lane)
+            got, (after,) = jax.jit(lambda big, xs: layer(
+                params, xs, offsets[:, None], (big,), offsets,
+                kv_need=kv_need))(big, step_x)
+        return [np.asarray(a) for a in (out, got, after)]
+
+    for kv_need in (None, jnp.int32(700)):
+        narrow = run(written, kv_need)
+        stored = run(layer.latent_width, kv_need)
+        for a, b in zip(narrow[:2], stored[:2]):
+            np.testing.assert_allclose(b, a, rtol=1e-6, atol=1e-6)
+        np.testing.assert_array_equal(stored[2][..., :written], narrow[2])
+        assert not stored[2][..., written:].any()
 
 
 # -- the router and the share ---------------------------------------------
@@ -379,6 +426,27 @@ def test_admission_then_decode_through_the_caches_matches_reference(served):
         got = np.stack([lg[row] for lg in step_logits])
         np.testing.assert_allclose(got, want, rtol=2e-3, atol=2e-4)
         assert gaps(model, p, s[len(p):]).max() < 1e-3
+
+
+def test_lane_pad_columns_stay_zero_through_the_session(served):
+    """Admissions in two buckets and a few decode steps: every MLA
+    layer's lane holds zeros past ``rank + rope`` in every row, and the
+    latents the rows wrote are there."""
+    llm, params, _ = served
+    sess = engine(llm).stream_session(params)
+    for row, p in enumerate(prompts_of([33, 6], seed=4)):
+        sess.prefill_into_row(row, p)
+    for _ in range(5):
+        sess.decode_step()
+    written = llm.mla.rank + llm.mla.rope_dim
+    lanes = [np.asarray(layer[0]) for kind, layer in zip(
+        llm.cache_kinds, sess.caches) if isinstance(kind, LatentLane)]
+    assert len(lanes) == 2
+    for lane in lanes:
+        assert lane.shape[-1] == llm.mla.latent_width > written
+        assert not lane[..., written:].any()
+        assert np.abs(lane[0, :33 + 5, :written]).max(-1).all()
+        assert np.abs(lane[1, :6 + 5, :written]).max(-1).all()
 
 
 def test_a_readmitted_row_starts_from_a_zero_state(served):

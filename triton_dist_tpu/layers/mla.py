@@ -11,7 +11,12 @@ low-rank, with a head-wise output gate.
 
 A row's cache is its LATENT lane (``models/kv_cache.LatentLane``): per
 position ``c || k_pe``, ``rank + rope`` values, written where K and V
-would be. Two reads of it:
+would be, then zeros up to the stored width (:attr:`MLAttn.latent_width`,
+a whole number of the TPU's 128-wide lane tiles: 576 -> 640). A width
+the tiles do not divide gets the positions as the minor dimension of
+the cache's default device layout, and the decode step's one-position
+write and its reads then copy the whole lane into another layout and
+back, two or three times a step. Two reads of it:
 
 * EXPANDED (a prompt: scalar ``offset``): ``k_nope`` and ``v`` of every
   cache position from the latents, then softmax attention; a call whose
@@ -39,6 +44,12 @@ from jax.sharding import Mesh, PartitionSpec as P
 from triton_dist_tpu.layers.common import rms_norm, shard_param
 from triton_dist_tpu.layers.tp_attn import (
     _WINDOW_CHUNK, prefill_blocks, window_chunks)
+
+# The TPU's lane tile: the minor dimension of an array is laid out in
+# 128-wide tiles. The latent lane is stored a whole number of them wide,
+# so that its default layout keeps the positions major (module
+# docstring); the tile pads a narrower lane to the same bytes anyway.
+_LANE_TILE = 128
 
 
 def rope_interleaved(x, position_ids, theta: float):
@@ -73,7 +84,9 @@ class MLAttn:
 
     @property
     def latent_width(self) -> int:
-        return self.rank + self.rope_dim
+        """The lane's stored width: ``rank + rope`` rounded up to whole
+        lane tiles; the columns past ``rank + rope`` hold zeros."""
+        return -(-(self.rank + self.rope_dim) // _LANE_TILE) * _LANE_TILE
 
     def init(self, key: jax.Array) -> dict:
         h, n = self.hidden_size, self.num_heads
@@ -84,7 +97,7 @@ class MLAttn:
             ).astype(self.dtype)
         return self.shard_params({
             "w_q": normal(ks[0], (h, n * qd), h),
-            "w_kva": normal(ks[1], (h, self.latent_width), h),
+            "w_kva": normal(ks[1], (h, self.rank + self.rope_dim), h),
             "w_kvb": normal(ks[2], (self.rank, n * (self.nope_dim
                                                     + self.v_dim)),
                             self.rank),
@@ -98,10 +111,10 @@ class MLAttn:
 
     def __call__(self, params: dict, x: jax.Array, position_ids: jax.Array,
                  cache: tuple, offset: jax.Array, kv_need=None):
-        """x: (B, S, hidden) replicated; cache ``(lane,)`` (B, T, rank +
-        rope); ``offset`` scalar (a prompt at ``offset``) or (B,) with
-        S == 1 (the decode step). Returns (out (B*S, hidden), new
-        cache)."""
+        """x: (B, S, hidden) replicated; cache ``(lane,)`` (B, T,
+        :attr:`latent_width`); ``offset`` scalar (a prompt at
+        ``offset``) or (B,) with S == 1 (the decode step). Returns (out
+        (B*S, hidden), new cache)."""
         b, s, _ = x.shape
         n, nope, rd = self.num_heads, self.nope_dim, self.rope_dim
         mm = lambda w: jnp.dot(x, w, preferred_element_type=jnp.float32)
@@ -113,7 +126,8 @@ class MLAttn:
         k_pe = rope_interleaved(kva[..., None, self.rank:], position_ids,
                                 self.rope_theta)[:, :, 0]
         (lane,) = cache
-        new = jnp.concatenate([c, k_pe], axis=-1).astype(lane.dtype)
+        new = _widen(jnp.concatenate([c, k_pe], axis=-1).astype(lane.dtype),
+                     lane.shape[-1])
         wkvb = params["w_kvb"].reshape(self.rank, n, nope + self.v_dim)
         if offset.ndim:
             assert s == 1, "a per-row call is the one-position decode step"
@@ -144,8 +158,9 @@ class MLAttn:
         t, nope = lane.shape[1], self.nope_dim
         kv = jnp.einsum("btc,chd->bthd", lane[..., :self.rank], wkvb,
                         preferred_element_type=jnp.float32).astype(lane.dtype)
+        k_pe = lane[:, :, None, self.rank:self.rank + self.rope_dim]
         k = jnp.concatenate([kv[..., :nope], jnp.broadcast_to(
-            lane[:, :, None, self.rank:], (b, t, n, self.rope_dim))], -1)
+            k_pe, (b, t, n, self.rope_dim))], -1)
         v = kv[..., nope:]
         q = q.astype(lane.dtype)
         if s == t:          # keys are the call's own positions: offset 0
@@ -156,10 +171,11 @@ class MLAttn:
     def _absorbed(self, qq, lane, offset, kv_need):
         """qq (B, H, rank + rope) f32 against row b's positions
         [0, offset[b]] of ``lane``: (B, H, rank) f32, the probabilities'
-        sum of latents."""
+        sum of latents. ``qq`` is widened with zeros to the lane's
+        stored width, so the pad columns add nothing to a score."""
         t = lane.shape[1]
         scale = (self.nope_dim + self.rope_dim) ** -0.5
-        qq = qq.astype(lane.dtype)
+        qq = _widen(qq.astype(lane.dtype), lane.shape[-1])
         max_chunks = t // (2 * _WINDOW_CHUNK)
         whole = functools.partial(_absorbed_read, rank=self.rank,
                                   scale=scale)
@@ -170,6 +186,12 @@ class MLAttn:
             n > 0,
             lambda *a: _absorbed_chunks(*a, n, self.rank, scale, max_chunks),
             whole, qq, lane, offset)
+
+
+def _widen(x, width: int):
+    """``x`` with zeros appended to its last axis up to ``width``."""
+    pad = [(0, 0)] * (x.ndim - 1) + [(0, width - x.shape[-1])]
+    return jnp.pad(x, pad)
 
 
 def _attend(q, k, v, offset):

@@ -90,8 +90,12 @@ def ring_lane(prefix: jax.Array, length, window: int) -> jax.Array:
 @dataclasses.dataclass(frozen=True)
 class LatentLane:
     """A latent-attention layer's cache: ONE (B, T, ``width``) leaf, a
-    row's compressed K/V per position (``layers/mla``). Seated at an
-    admission like K and V: the prompt's positions at slot 0."""
+    row's compressed K/V per position (``layers/mla``). ``width`` is the
+    stored width, the latent's rounded up to whole 128-wide lane tiles
+    (``MLAttn.latent_width``): the TPU then keeps the positions major
+    and the decode step writes and reads the leaf as stored, with no
+    transposed copy. Seated at an admission like K and V: the prompt's
+    positions at slot 0."""
     width: int
 
 
